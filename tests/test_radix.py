@@ -167,34 +167,3 @@ def test_group_order_multi_key_adjacency():
             assert key not in seen, f"group {key} fragmented"
             seen.add(key)
             prev = key
-
-
-@pytest.mark.parametrize("n", [5, 2048, 10_000])
-def test_pallas_engine_matches(n):
-    """Pallas counting-pass engine (interpret mode off-TPU) matches the
-    oracle bit-for-bit."""
-    rng = np.random.default_rng(n)
-    keys = rng.integers(0, 1 << 63, n, dtype=np.uint64)
-    hi = jnp.asarray((keys >> 32).astype(np.uint32))
-    lo = jnp.asarray(keys.astype(np.uint32))
-    got = np.asarray(radix_argsort_u32([hi, lo], engine="pallas"))
-    expect = _np_stable_argsort([hi, lo])
-    np.testing.assert_array_equal(got, expect)
-
-
-def test_pallas_hist_rank_direct():
-    from ytsaurus_tpu.ops.pallas_radix import hist_rank
-    rng = np.random.default_rng(2)
-    n, bits = 8192, 6
-    d = rng.integers(0, 1 << bits, n, dtype=np.int32)
-    counts, rank = hist_rank(jnp.asarray(d), bits=bits, tile=2048)
-    counts, rank = np.asarray(counts), np.asarray(rank)
-    nt = n // 2048
-    for t in range(nt):
-        seg = d[t * 2048:(t + 1) * 2048]
-        np.testing.assert_array_equal(counts[t],
-                                      np.bincount(seg, minlength=1 << bits))
-        seen = {}
-        for i, b in enumerate(seg):
-            assert rank[t * 2048 + i] == seen.get(b, 0)
-            seen[b] = seen.get(b, 0) + 1

@@ -1,0 +1,221 @@
+"""Chip compiles without the chip: main-path programs lowered and compiled
+for a DESCRIBED TPU v5e (the installed libtpu compiles for a device that is
+not attached).  Nothing runs — a pass says only that the chip's compiler
+takes the program; `chip_smoke.py` is what runs them.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process may hold libtpu, and every xdist worker imports this
+file), compiles happen in the test's own process, and JAX's persistent
+compile cache is off around them (an entry written for a described device
+cannot be read back without one).
+"""
+
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture
+def as_on_chip(monkeypatch):
+    """Repo code that asks `jax.default_backend()` takes its TPU branch
+    (segments.segment_engine -> "scan", segments.prefix_scan -> shifted
+    form), as it does in a process that holds the chip; sorts ride the
+    radix engine, as chip_smoke.py runs them (the network engine `auto`
+    picks below 8M rows costs ~40 s of chip compile PER KEY WORD)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("YT_TPU_SORT_ENGINE", "radix")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — any failure to describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def compile_for(fn, args, sharding):
+    """jit(fn) lowered on `args`' shapes placed on the described chip."""
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape if hasattr(a, "shape") else (),
+            a.dtype if hasattr(a, "dtype") else jnp.result_type(a),
+            sharding=sharding), args)
+    t0 = time.perf_counter()
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    return compiled, time.perf_counter() - t0
+
+
+def compile_query(query, schemas, chunk, sharding):
+    """The program select_rows dispatches for `query` over `chunk`
+    (evaluator: jit(prepare(plan, chunk).run).lower(...).compile())."""
+    from ytsaurus_tpu.query.builder import build_query
+    from ytsaurus_tpu.query.engine.lowering import prepare
+    plan = build_query(query, schemas)
+    prepared = prepare(plan, chunk)
+    columns = {c.name: (chunk.columns[c.name].data,
+                        chunk.columns[c.name].valid) for c in plan.schema}
+    args = (columns, chunk.row_valid, tuple(prepared.bindings))
+    return compile_for(prepared.run, args, sharding)
+
+
+def lineitem(rows):
+    from ytsaurus_tpu.models import tpch
+    return tpch.generate_lineitem(rows), \
+        {"//tpch/lineitem": tpch.LINEITEM_SCHEMA}
+
+
+def dyn_merged_chunk(rows):
+    """A flushed dynamic store's versioned chunk (k, v, $timestamp, ...)
+    as Tablet.flush / the scan path hand it to the MVCC programs."""
+    from ytsaurus_tpu.chunks.columnar import ColumnarChunk
+    from ytsaurus_tpu.schema import EValueType, TableSchema
+    from ytsaurus_tpu.tablet.tablet import versioned_schema
+    schema = TableSchema.make(
+        [("k", "int64", "ascending"), ("v", "int64")], unique_keys=True)
+    vschema = versioned_schema(schema)
+    arrays = {}
+    for c in vschema:
+        if c.name == "k":
+            arrays[c.name] = np.arange(rows)
+        elif c.type is EValueType.boolean:
+            arrays[c.name] = np.full(rows, c.name != "$tombstone")
+        else:
+            arrays[c.name] = np.arange(rows) * 3
+    return schema, ColumnarChunk.from_arrays(vschema, arrays)
+
+
+def compile_mvcc_visible(rows, sharding):
+    """tablet/mvcc.py's read-snapshot merge over a `rows`-row versioned
+    chunk."""
+    from ytsaurus_tpu.tablet import mvcc
+    schema, merged = dyn_merged_chunk(rows)
+    key_names = tuple(schema.key_column_names)
+    value_names = tuple(c.name for c in schema if c.sort_order is None)
+    builder = mvcc._build_visible(key_names, value_names, merged.capacity)
+    args = (mvcc._planes(merged), np.int64(merged.row_count),
+            np.int64(1 << 60))
+    return compile_for(builder, args, sharding)
+
+
+def compile_sort_chunk(rows, sharding):
+    """sort_chunk's device work for the smoke's sort table (k, v int64)."""
+    from ytsaurus_tpu.chunks.columnar import ColumnarChunk
+    from ytsaurus_tpu.operations.sort_op import sort_chunk
+    from ytsaurus_tpu.schema import TableSchema
+    schema = TableSchema.make([("k", "int64"), ("v", "int64")])
+    chunk = ColumnarChunk.from_arrays(
+        schema, {"k": np.arange(rows)[::-1], "v": np.arange(rows)})
+
+    def run(planes):
+        import dataclasses
+        cols = {n: dataclasses.replace(chunk.columns[n], data=d, valid=v)
+                for n, (d, v) in planes.items()}
+        out = sort_chunk(dataclasses.replace(chunk, columns=cols), ["k"])
+        return {n: (c.data, c.valid) for n, c in out.columns.items()}
+
+    planes = {n: (c.data, c.valid) for n, c in chunk.columns.items()}
+    return compile_for(run, (planes,), sharding)
+
+
+HIGH_CARD = (
+    "l_orderkey, sum(l_quantity) AS q FROM [//tpch/lineitem] "
+    "GROUP BY l_orderkey ORDER BY sum(l_quantity) DESC, l_orderkey LIMIT 10")
+
+
+def test_q1_at_sf1_capacity(one_chip, as_on_chip):
+    from ytsaurus_tpu.models import tpch
+    chunk, schemas = lineitem(6_001_215)
+    assert chunk.capacity == 8_388_608
+    compiled, _ = compile_query(tpch.Q1, schemas, chunk, one_chip)
+    mem = compiled.memory_analysis()
+    # Arguments + temporaries must fit one v5e chip's 16 GB beside the
+    # resident table.
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
+
+
+def test_double_order_by_key(one_chip, as_on_chip):
+    """ORDER BY on a `double` expression: the packed-key encoding of an
+    f64 plane (ops/segments.f64_bits_u32) must compile for the chip."""
+    chunk, schemas = lineitem(131_072)
+    compile_query(
+        "l_orderkey, l_extendedprice FROM [//tpch/lineitem] "
+        "ORDER BY l_extendedprice * (1 - l_discount) DESC, l_orderkey "
+        "LIMIT 10", schemas, chunk, one_chip)
+
+
+def test_high_cardinality_group_order(one_chip, as_on_chip):
+    chunk, schemas = lineitem(131_072)
+    compile_query(HIGH_CARD, schemas, chunk, one_chip)
+
+
+def test_double_hash_mix(one_chip, as_on_chip):
+    """farm_hash over a double column (expr._mix_u64's f64 bit pattern)."""
+    chunk, schemas = lineitem(131_072)
+    compile_query("farm_hash(l_quantity) AS h FROM [//tpch/lineitem]",
+                  schemas, chunk, one_chip)
+
+
+def test_radix_argsort_above_threshold(one_chip, as_on_chip):
+    """What `auto` picks one row above LSD_SORT_THRESHOLD: the tiled
+    radix engine, two u32 words at 8,388,608 rows."""
+    from ytsaurus_tpu.ops.segments import stable_argsort_u32
+    words = [jax.ShapeDtypeStruct((8_388_608,), jnp.uint32)] * 2
+    compile_for(lambda a, b: stable_argsort_u32([a, b]), tuple(words),
+                one_chip)
+
+
+def test_sort_chunk_program(one_chip, as_on_chip):
+    compile_sort_chunk(200_000, one_chip)
+
+
+def test_dynamic_table_mvcc_program(one_chip, as_on_chip):
+    """The dynamic table's device program at the smoke's 1,000,000 rows:
+    the MVCC visibility merge every scan runs — the flush program is its
+    first stage, the (key, -ts) version sort.  (Point lookups probe host
+    planes — tablet.lookup_rows — and compile nothing.)"""
+    compile_mvcc_visible(1_000_000, one_chip)
+
+
+def _segment_end_reference(starts):
+    n = len(starts)
+    out = np.empty(n, dtype=np.int64)
+    end = n - 1
+    for i in range(n - 1, -1, -1):
+        out[i] = end
+        if starts[i]:
+            end = i - 1
+    return out
+
+
+@pytest.mark.parametrize("n", [1, 2, 7, 300])
+@pytest.mark.parametrize("function", ["sum", "min", "max"])
+def test_shifted_prefix_scan_matches_associative_scan(as_on_chip, function,
+                                                      n):
+    """The chip's scan form, EXECUTED here on the CPU, against the CPU's
+    lax.associative_scan form (which every other test runs)."""
+    from ytsaurus_tpu.ops import segments
+    rng = np.random.default_rng(n)
+    data = jnp.asarray(rng.integers(-50, 50, n))
+    starts = jnp.asarray(rng.random(n) < 0.1).at[0].set(True)
+    got = segments.segment_scan(function, data, starts)
+    combine = segments._scan_combine(
+        {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}[function])
+    want, _ = jax.lax.associative_scan(combine, (data, starts))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(segments.segment_end_index(starts)),
+        _segment_end_reference(np.asarray(starts)))

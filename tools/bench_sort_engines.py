@@ -1,7 +1,7 @@
 """Compare sort-engine wall times on the current backend.
 
 Usage: python tools/bench_sort_engines.py [--rows N] [--words W]
-       [--engines network,lsd32,radix,radix_scatter,radix_pallas]
+       [--engines network,lsd32,radix,radix_scatter]
 
 Times stable_argsort_u32 per engine at the given scale and prints one
 line per engine; used to pick LSD_SORT_THRESHOLD / engine defaults on
@@ -29,12 +29,16 @@ def main():
     parser.add_argument("--words", type=int, default=2)
     parser.add_argument("--iters", type=int, default=3)
     parser.add_argument("--engines", default="radix,radix_scatter,"
-                                             "radix_pallas,lsd32,network")
+                                             "lsd32,network")
     parser.add_argument("--timeout", type=float, default=240.0,
                         help="skip remaining iters past this many seconds")
     args = parser.parse_args()
 
-    from ytsaurus_tpu.utils.backend import ensure_backend
+    from ytsaurus_tpu.utils.backend import (
+        ensure_backend,
+        place_compile_cache,
+    )
+    place_compile_cache()
     jax = ensure_backend()
     import jax.numpy as jnp
 

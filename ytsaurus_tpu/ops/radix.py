@@ -135,8 +135,7 @@ def radix_argsort_u32(words: list[jax.Array],
     word k (higher bits must be zero) — digit passes above the bound are
     skipped, so a packed 12-bit key costs 2 byte passes, not 4.
 
-    engine: "gather" | "scatter" (ops above) | "pallas" (counting pass
-    as a Pallas TPU kernel + permutation scatter, ops/pallas_radix.py).
+    engine: "gather" | "scatter" (ops above).
 
     Pad rows (to the tile multiple) carry all-ones keys and sort last;
     ties against real all-ones rows resolve to the real rows first by
@@ -148,19 +147,9 @@ def radix_argsort_u32(words: list[jax.Array],
         return jnp.arange(0, dtype=jnp.uint32)
     if word_bits is None:
         word_bits = [32] * len(words)
-    if engine == "pallas":
-        from ytsaurus_tpu.ops.pallas_radix import (
-            PALLAS_BITS,
-            PALLAS_TILE,
-            radix_pass_pallas,
-        )
-        pass_bits = PALLAS_BITS
-        tile = PALLAS_TILE
-        pass_fn = lambda d, p: radix_pass_pallas(d, p, PALLAS_BITS)  # noqa: E731
-    else:
-        pass_bits = RADIX_BITS
-        tile = min(RADIX_TILE, 1 << max(n - 1, 1).bit_length())
-        pass_fn = lambda d, p: radix_pass(d, p, engine=engine)  # noqa: E731
+    pass_bits = RADIX_BITS
+    tile = min(RADIX_TILE, 1 << max(n - 1, 1).bit_length())
+    pass_fn = lambda d, p: radix_pass(d, p, engine=engine)  # noqa: E731
     padded = ((n + tile - 1) // tile) * tile
     n_pad = padded - n
     perm = jnp.arange(padded, dtype=jnp.uint32)

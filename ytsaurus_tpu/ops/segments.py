@@ -51,6 +51,35 @@ def lexsort_indices(key_planes: list[jax.Array]) -> jax.Array:
     return jnp.lexsort(key_planes)
 
 
+def prefix_scan(combine, elems):
+    """Inclusive prefix scan of 1-D planes (a pytree) under an associative
+    `combine` — the one scan primitive every segmented scan here rides.
+
+    Off the CPU it is the log-step shifted form (Hillis-Steele: step d
+    combines each row with the row d before it): n log n work, but every
+    step is one fused elementwise pass over whole planes.  libtpu takes
+    MINUTES to compile `lax.associative_scan`'s strided slice/interleave
+    ladder (compiled for a described v5e, libtpu 0.0.34: one 1M-row
+    segmented sum 161 s and 41 MB of code, `jnp.cumsum` 96 s; the shifted
+    form 6 s), and a served query carries several.  The CPU keeps the
+    work-efficient associative_scan (tools/kernel_floors.json gates it)."""
+    if jax.default_backend() == "cpu":
+        return jax.lax.associative_scan(combine, elems)
+    n = jax.tree_util.tree_leaves(elems)[0].shape[0]
+    iota = jnp.arange(n, dtype=jnp.int32)
+    d = 1
+    while d < n:
+        shifted = jax.tree_util.tree_map(
+            lambda x: jnp.roll(x, d), elems)
+        combined = combine(shifted, elems)
+        keep = iota >= d
+        elems = jax.tree_util.tree_map(
+            lambda c, x: jnp.where(keep, c, x),
+            combined, elems)
+        d *= 2
+    return elems
+
+
 def segment_boundaries(sorted_keys: list[tuple[jax.Array, jax.Array]],
                        in_mask: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Given key (data, valid) planes already in sorted order plus the row
@@ -67,7 +96,7 @@ def segment_boundaries(sorted_keys: list[tuple[jax.Array, jax.Array]],
     change = change.at[0].set(False)
     # New segment whenever keys change, restricted to in-mask rows.
     boundary = change & in_mask
-    seg = jnp.cumsum(boundary.astype(jnp.int64))
+    seg = prefix_scan(jnp.add, boundary.astype(jnp.int64))
     num_segments = jnp.where(jnp.any(in_mask), seg[-1] + 1, 0)
     # Rows outside the mask go to a trailing segment.
     seg = jnp.where(in_mask, seg, num_segments)
@@ -172,7 +201,7 @@ def _sorted_segment_reduce(function: str, data: jax.Array,
         yv, yf = y
         return jnp.where(yf, yv, combine_val(xv, yv)), xf | yf
 
-    scanned, _ = jax.lax.associative_scan(combine, (data, starts))
+    scanned, _ = prefix_scan(combine, (data, starts))
     sids = jnp.arange(num_segments, dtype=seg_ids.dtype)
     left = jnp.searchsorted(seg_ids, sids, side="left")
     right = jnp.searchsorted(seg_ids, sids, side="right")
@@ -377,7 +406,7 @@ def _scan_combine(combine_val):
 def segment_scan(function: str, data: jax.Array,
                  starts: jax.Array) -> jax.Array:
     """Segmented INCLUSIVE prefix scan (sum/min/max), log-depth via
-    associative_scan — no scatters, the TPU-native window primitive."""
+    prefix_scan — no scatters, the window primitive."""
     if function == "sum":
         combine_val = lambda a, b: a + b
     elif function == "min":
@@ -386,8 +415,7 @@ def segment_scan(function: str, data: jax.Array,
         combine_val = jnp.maximum
     else:
         raise ValueError(f"Unknown scan function {function!r}")
-    scanned, _ = jax.lax.associative_scan(
-        _scan_combine(combine_val), (data, starts))
+    scanned, _ = prefix_scan(_scan_combine(combine_val), (data, starts))
     return scanned
 
 
@@ -406,7 +434,7 @@ def segment_start_index(starts: jax.Array) -> jax.Array:
     (starts ? i : 0) — segment starts arrive in increasing index order,
     so no reset is needed."""
     iota = jnp.arange(starts.shape[0], dtype=jnp.int32)
-    return jax.lax.associative_scan(
+    return prefix_scan(
         jnp.maximum, jnp.where(starts, iota, jnp.zeros_like(iota)))
 
 
@@ -416,7 +444,7 @@ def segment_end_index(starts: jax.Array) -> jax.Array:
     n = starts.shape[0]
     ends = jnp.concatenate([starts[1:], jnp.ones(1, dtype=bool)])
     iota = jnp.arange(n, dtype=jnp.int32)
-    rev_start = jax.lax.associative_scan(
+    rev_start = prefix_scan(
         jnp.maximum, jnp.where(ends[::-1], iota, jnp.zeros_like(iota)))
     return (n - 1) - rev_start[::-1]
 
@@ -507,15 +535,46 @@ _SIGN64 = np.uint64(1 << 63)
 _SIGN32 = np.uint32(1 << 31)
 
 
-def _f64_bits_u32(data: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """(hi, lo) u32 words of an f64 plane.  TPUs have no 64-bit lanes:
-    the X64 rewriter stores f64 as u32 pairs, and a same-width
-    bitcast f64→u64 is UNIMPLEMENTED there (measured on v5e: the AOT
-    compile fails) — but the 64→32 split bitcast is exactly its native
-    representation."""
-    words = jax.lax.bitcast_convert_type(data.astype(jnp.float64),
-                                         jnp.uint32)
-    return words[..., 1], words[..., 0]        # little-endian
+_POW2_STEPS = tuple(1 << i for i in range(9, -1, -1))      # 512 .. 1
+
+
+def f64_bits_u32(data: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(hi, lo) u32 words of an f64 plane's IEEE-754 bit pattern, by
+    ARITHMETIC — no bitcast.  The TPU compiler stores f64 as 32-bit
+    pairs and refuses every 64-bit float bitcast (f64→u64 and the
+    f64→u32[N,2] split alike: "UNIMPLEMENTED: While rewriting computation
+    to not contain X64 element types", libtpu 0.0.34, v5e), so the
+    exponent is found by exact power-of-two normalization (20 fused
+    compare/select/multiply steps) and the mantissa by one exact
+    float→int conversion.  Same form on every backend.
+
+    Two value classes are canonicalized, matching how the engine's own
+    float arithmetic sees them: every NaN becomes the one quiet +NaN
+    (sorts after +inf), and subnormals become a zero of their sign (XLA
+    flushes them in arithmetic)."""
+    x = data.astype(jnp.float64)
+    a = jnp.abs(x)
+    normal = (a >= 2.0 ** -1022) & (a < jnp.inf)
+    neg = (x < 0) | ((x == 0) & (1.0 / x < 0))
+    m = jnp.where(normal, a, 1.0)
+    e = jnp.full(x.shape, 1023, dtype=jnp.int32)
+    for k in _POW2_STEPS:
+        big = m >= 2.0 ** k
+        m = jnp.where(big, m * 2.0 ** -k, m)
+        e = e + jnp.where(big, k, 0)
+    for k in _POW2_STEPS:
+        small = m < 2.0 ** (1 - k)
+        m = jnp.where(small, m * 2.0 ** k, m)
+        e = e - jnp.where(small, k, 0)
+    # m is now in [1, 2): (m - 1) * 2^52 is the exact 52-bit mantissa.
+    bits = (e.astype(jnp.int64) << 52) | \
+        ((m - 1.0) * 2.0 ** 52).astype(jnp.int64)
+    special = jnp.where(a == jnp.inf, 0x7FF0 << 48,
+                        jnp.where(a != a, 0x7FF8 << 48, 0))
+    bits = jnp.where(normal, bits, special)
+    hi = (bits >> 32).astype(jnp.uint32) | \
+        (neg.astype(jnp.uint32) << np.uint32(31))
+    return hi, bits.astype(jnp.uint32)
 
 
 def monotone_u32_words(data: jax.Array,
@@ -534,7 +593,7 @@ def monotone_u32_words(data: jax.Array,
         sign = (bits >> np.uint32(31)).astype(bool)
         words = [jnp.where(sign, ~bits, bits | _SIGN32)]
     elif jnp.issubdtype(data.dtype, jnp.floating):
-        hi, lo = _f64_bits_u32(data)
+        hi, lo = f64_bits_u32(data)
         sign = (hi >> np.uint32(31)).astype(bool)
         words = [jnp.where(sign, ~hi, hi | _SIGN32),
                  jnp.where(sign, ~lo, lo)]
@@ -632,8 +691,6 @@ def stable_argsort_u32(words: list[jax.Array],
                 sort networks + histogram rank movement; depth never
                 grows with n.  Default past LSD_SORT_THRESHOLD.
       radix_scatter — radix with the permutation-scatter write path.
-      radix_pallas (alias: pallas) — counting pass as a Pallas TPU
-                kernel + permutation scatter (ops/pallas_radix.py).
 
     Unknown engine names raise (a typo must not silently run the
     one-pass network into the very cliff the engines exist to avoid).
@@ -649,11 +706,9 @@ def stable_argsort_u32(words: list[jax.Array],
         effective = min(LSD_SORT_THRESHOLD,
                         2 * LSD_SORT_THRESHOLD // max(len(words), 1))
         engine = "network" if n <= effective else "radix"
-    if engine in ("radix", "radix_scatter", "radix_pallas", "pallas"):
+    if engine in ("radix", "radix_scatter"):
         from ytsaurus_tpu.ops.radix import radix_argsort_u32
-        sub_engine = {"radix": "gather", "radix_scatter": "scatter",
-                      "radix_pallas": "pallas",
-                      "pallas": "pallas"}[engine]
+        sub_engine = {"radix": "gather", "radix_scatter": "scatter"}[engine]
         return radix_argsort_u32(words, word_bits, engine=sub_engine)
     if engine not in ("network", "lsd32"):
         raise ValueError(f"unknown YT_TPU_SORT_ENGINE {engine!r}")

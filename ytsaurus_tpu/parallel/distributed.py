@@ -26,9 +26,8 @@ _warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ytsaurus_tpu.parallel.compat import shard_map
 
 from ytsaurus_tpu.chunks.columnar import (
     Column,

@@ -25,9 +25,8 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from ytsaurus_tpu.parallel.compat import shard_map
 
 from ytsaurus_tpu.chunks.columnar import Column, pad_capacity
 from ytsaurus_tpu.errors import EErrorCode, YtError

@@ -1163,8 +1163,14 @@ def _bytes_hash(v: bytes) -> np.uint64:
 
 
 def _mix_u64(data: jax.Array) -> jax.Array:
-    x = data.astype(jnp.uint64) if data.dtype != jnp.float64 else \
-        jax.lax.bitcast_convert_type(data, jnp.uint64)
+    if data.dtype == jnp.float64:
+        # No 64-bit float bitcast compiles for the TPU: take the bit
+        # pattern arithmetically (ops/segments.f64_bits_u32).
+        from ytsaurus_tpu.ops.segments import f64_bits_u32
+        hi, lo = f64_bits_u32(data)
+        x = (hi.astype(jnp.uint64) << np.uint64(32)) | lo.astype(jnp.uint64)
+    else:
+        x = data.astype(jnp.uint64)
     x = x ^ (x >> np.uint64(33))
     x = x * np.uint64(0xFF51AFD7ED558CCD)
     x = x ^ (x >> np.uint64(33))

@@ -164,8 +164,13 @@ def _np_monotone_u64(data: np.ndarray) -> np.ndarray:
     if data.dtype == np.bool_:
         return data.astype(np.uint64)
     if np.issubdtype(data.dtype, np.floating):
-        bits = np.ascontiguousarray(
-            data.astype(np.float64)).view(np.uint64)
+        x = data.astype(np.float64)
+        # Same canonical classes as segments.f64_bits_u32: one quiet
+        # +NaN, subnormals flushed to a zero of their sign.
+        x = np.where(np.isnan(x), np.nan,
+                     np.where(np.abs(x) < 2.0 ** -1022,
+                              np.copysign(0.0, x), x))
+        bits = np.ascontiguousarray(x).view(np.uint64)
         sign = (bits >> np.uint64(63)).astype(bool)
         return np.where(sign, ~bits, bits | _SIGN64)
     if np.issubdtype(data.dtype, np.unsignedinteger):

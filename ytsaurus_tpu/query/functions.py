@@ -312,7 +312,7 @@ def is_aggregate(name: str) -> bool:
 # --- window functions ---------------------------------------------------------
 #
 # Signature registry for `fn(...) OVER (...)` (the reference has no window
-# functions — layer-6 gap in VERDICT.md; the CH dialect spelling is shared).
+# functions — the CH dialect spelling is shared).
 # Lowerings live in query/engine/window.py as segmented prefix scans.
 
 

@@ -96,8 +96,10 @@ class SamplingProfiler:
         with self._lock:
             self._idle += idle
             for stack in stacks:
-                if stack in self._samples or \
-                        len(self._samples) < self.max_entries:
+                # The cap counts the "(other)" bucket: distinct stacks
+                # stop one short of it while the bucket is still unborn.
+                room = self.max_entries - ("(other)" not in self._samples)
+                if stack in self._samples or len(self._samples) < room:
                     self._samples[stack] = \
                         self._samples.get(stack, 0) + 1
                 else:
