@@ -15,9 +15,9 @@ from ytsaurus_tpu.utils.profiling import (
 )
 from ytsaurus_tpu.utils.tracing import (
     TraceContext,
+    child_span,
     current_trace,
     get_collector,
-    start_span,
 )
 
 
@@ -76,7 +76,7 @@ def test_registry_collect_snapshot():
 def test_span_nesting_and_collection():
     with TraceContext("root") as root:
         assert current_trace() is root
-        with start_span("child", table="//t") as child:
+        with child_span("child", table="//t") as child:
             assert child.trace_id == root.trace_id
             assert child.parent_span_id == root.span_id
     assert current_trace() is None

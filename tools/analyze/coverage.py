@@ -11,8 +11,8 @@ Two disciplines established by PR 2 (deterministic failpoints) and PR 5
                        (`# analyze: allow(failpoint): reason`) on its
                        def line.  The chaos soak can only prove recovery
                        for faults it can inject.
-  span-discipline      root-span creation (`start_span`,
-                       `start_query_span`, bare `TraceContext(...)`)
+  span-discipline      root-span creation (`start_query_span`, bare
+                       `TraceContext(...)`)
                        is allowed ONLY at the declared entry points; an
                        interior site that roots a fresh trace orphans
                        itself from the caller's flight recording —
@@ -62,8 +62,7 @@ SPAN_ENTRY_FILES = {
     "ytsaurus_tpu/rpc/server.py",             # wire-context restore
 }
 
-_ROOT_SPAN_CALLS = {"start_span", "start_query_span",
-                    "tracing.start_span", "tracing.start_query_span"}
+_ROOT_SPAN_CALLS = {"start_query_span", "tracing.start_query_span"}
 
 
 def _is_io_call(call: ast.Call) -> "str | None":

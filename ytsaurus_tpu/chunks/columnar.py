@@ -20,6 +20,7 @@ segment_readers.h) re-designed for XLA rather than translated:
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import weakref
 from dataclasses import dataclass, replace
@@ -166,11 +167,14 @@ class ColumnarChunk:
         cap = self.capacity
         return jnp.arange(cap) < self.row_count
 
-    @property
+    @functools.cached_property
     def nbytes(self) -> int:
         """Resident bytes of the column planes (capacity-padded) — the
         bytes-scanned unit per-tenant accounting charges.  `.nbytes` on
-        a device array is metadata; nothing transfers."""
+        a device array is metadata; nothing transfers.  Computed once
+        per (immutable) chunk: every select reads it for its statistics
+        and its `query.stage` span, and 32 plane lookups cost more than
+        a span."""
         total = 0
         for col in self.columns.values():
             total += int(getattr(col.data, "nbytes", 0))

@@ -1257,14 +1257,24 @@ class YtClient:
         (`timeout` seconds, default ServingConfig.default_timeout)
         cooperatively checked between shard programs.
 
-        Every query runs under a root trace span (sampled per
-        config.TracingConfig) covering admission, per-shard staging/
-        execution, evaluator compile-vs-execute, and tablet/chunk reads;
-        finished queries fold into an ExecutionProfile retained by the
-        flight recorder (slow-query log + sampled recent log, monitoring
-        `/traces`).  `explain_analyze=True` forces sampling and returns
-        the ExecutionProfile (with `.rows` carrying the result) instead
-        of the bare row list — EXPLAIN ANALYZE with the compile/execute
+        Every query runs under a root trace span `query.select`
+        (sampled per config.TracingConfig) that stays open until the
+        answer is handed back.  Its children, in order: `serving.
+        admission` (the wait for a pool slot), `query.plan` (parse,
+        build, permissions, pruning intervals), `query.stage` (shards
+        from the chunk cache or tablet snapshots; `chunk.read` /
+        `tablet.read_snapshot` nest there), `coordinator.shard` >
+        `evaluator.run_plan` > `evaluator.prepare` / `evaluator.compile`
+        (misses only) / `evaluator.launch` / `evaluator.sync`,
+        `query.decode` (planes to row dicts) and `query.record` twice
+        (statistics and the query log before the decode; profile, flight
+        recorder, accounting and workload log after the answer).  Each
+        span carries its self time.  Finished queries fold into an
+        ExecutionProfile retained by the flight recorder (slow-query log
+        + sampled recent log, monitoring `/traces`).
+        `explain_analyze=True` forces sampling and returns the
+        ExecutionProfile (with `.rows` carrying the result) instead of
+        the bare row list — EXPLAIN ANALYZE with the compile/execute
         split reported separately.
 
         Per-query statistics land in `self.last_query_statistics` (ref
@@ -1276,7 +1286,7 @@ class YtClient:
             get_flight_recorder,
         )
         from ytsaurus_tpu.query.statistics import QueryStatistics
-        from ytsaurus_tpu.utils.tracing import start_query_span
+        from ytsaurus_tpu.utils.tracing import child_span, start_query_span
         gateway = self.cluster.gateway
         # The admission-resolved pool is the identity every plane shares
         # (admission counters, per-pool sensors, accounting): capturing
@@ -1293,8 +1303,10 @@ class YtClient:
         # impl finishing and the profile capture reading it.
         stats = QueryStatistics()
         t0 = _time.perf_counter()
-        try:
-            with root:
+        # The root stays open over the epilogue: what observability costs
+        # per select is inside `query.select`, under `query.record`.
+        with root:
+            try:
                 if not gateway.enabled:
                     rows = self._select_rows_impl(query, timestamp, None,
                                                   stats=stats,
@@ -1305,41 +1317,46 @@ class YtClient:
                             query, timestamp, token, stats=stats,
                             params=params),
                         pool=pool, timeout=timeout)
-        except YtError as err:
-            # Workload recorder (ISSUE 8): failed queries are part of
-            # the workload too — the record carries the classified
-            # outcome (throttled/deadline/error) so a replayed mix
-            # reproduces the rejection profile, not just the successes.
-            from ytsaurus_tpu.query.workload import (
-                get_workload_log,
-                outcome_of,
-            )
-            get_workload_log().observe_select(
-                query, stats=stats, outcome=outcome_of(err),
-                wall_time=_time.perf_counter() - t0, pool=pool,
-                trace_id=getattr(root, "trace_id", None))
-            raise
-        profile = ExecutionProfile.capture(
-            root, query, stats, _time.perf_counter() - t0, pool=pool)
-        if explain_analyze:
-            # Attach BEFORE observe: the recorder strips rows from what
-            # it retains (without_rows copy), so attaching afterwards
-            # would mutate the stored object and pin the result set.
-            profile.rows = rows
-        get_flight_recorder().observe(profile)
-        # Per-tenant resource accounting (ISSUE 6): the finished query's
-        # counters fold into cumulative (pool, user) usage — the signal
-        # fair-share serving weighs tenants by, served on /accounting
-        # and `yt top`.
-        from ytsaurus_tpu.query.accounting import get_accountant
-        get_accountant().observe_query(profile)
-        # Workload recorder (ISSUE 8): the finished query folds one
-        # compact record (normalized text + hoisted literals + the
-        # wall/compile/execute split + capacity buckets + trace id)
-        # into the bounded workload log — the capture `yt replay` and
-        # `bench.py --config replay` re-run.
-        from ytsaurus_tpu.query.workload import get_workload_log
-        get_workload_log().observe_select(query, profile=profile)
+            except YtError as err:
+                # Workload recorder (ISSUE 8): failed queries are part of
+                # the workload too — the record carries the classified
+                # outcome (throttled/deadline/error) so a replayed mix
+                # reproduces the rejection profile, not just the
+                # successes.
+                from ytsaurus_tpu.query.workload import (
+                    get_workload_log,
+                    outcome_of,
+                )
+                get_workload_log().observe_select(
+                    query, stats=stats, outcome=outcome_of(err),
+                    wall_time=_time.perf_counter() - t0, pool=pool,
+                    trace_id=getattr(root, "trace_id", None))
+                raise
+            root.add_tag("rows", len(rows))
+            with child_span("query.record"):
+                profile = ExecutionProfile.capture(
+                    root, query, stats, _time.perf_counter() - t0,
+                    pool=pool)
+                if explain_analyze:
+                    # Attach BEFORE observe: the recorder strips rows
+                    # from what it retains (without_rows copy), so
+                    # attaching afterwards would mutate the stored object
+                    # and pin the result set.
+                    profile.rows = rows
+                get_flight_recorder().observe(profile)
+                # Per-tenant resource accounting (ISSUE 6): the finished
+                # query's counters fold into cumulative (pool, user)
+                # usage — the signal fair-share serving weighs tenants
+                # by, served on /accounting and `yt top`.
+                from ytsaurus_tpu.query.accounting import get_accountant
+                get_accountant().observe_query(profile)
+                # Workload recorder (ISSUE 8): the finished query folds
+                # one compact record (normalized text + hoisted literals
+                # + the wall/compile/execute split + capacity buckets +
+                # trace id) into the bounded workload log — the capture
+                # `yt replay` re-runs.
+                from ytsaurus_tpu.query.workload import get_workload_log
+                get_workload_log().observe_select(query, profile=profile)
         return profile if explain_analyze else rows
 
     def nearest_rows(self, path: str, column: str,
@@ -1401,85 +1418,102 @@ class YtClient:
 
         from ytsaurus_tpu.query.statistics import QueryStatistics
         from ytsaurus_tpu.utils.logging import get_logger, log_event
+        from ytsaurus_tpu.utils.tracing import child_span
         if stats is None:
             stats = QueryStatistics()
         self.last_query_statistics = stats   # visible even if the query fails
-        plan = build_query(query, _SchemaResolver(self), params=params)
-        # Every source table requires read permission (ref: query agent
-        # checks table read access before executing subqueries).
-        self.cluster.security.validate_permission("read", plan.source)
-        for join in plan.joins:
-            self.cluster.security.validate_permission(
-                "read", join.foreign_table)
-        from ytsaurus_tpu.query.pruning import extract_column_intervals
-        intervals = extract_column_intervals(plan.where)
-        if plan.joins:
-            # Semi-join pushdown (ISSUE 14): a selective INNER side's
-            # key [min, max] — merged off the foreign chunks' sealed
-            # metadata stats, no decode — narrows the scan intervals, so
-            # whole source shards whose key range cannot join anything
-            # prune before staging.
-            from ytsaurus_tpu.chunks.columnar import merge_column_stats
-            from ytsaurus_tpu.query import planner as query_planner
-            from ytsaurus_tpu.query.pruning import Interval
-            foreign_meta_stats = {}
+        # Parse, build, permissions, pruning intervals, join push-down.
+        with child_span("query.plan"):
+            plan = build_query(query, _SchemaResolver(self), params=params)
+            # Every source table requires read permission (ref: query agent
+            # checks table read access before executing subqueries).
+            self.cluster.security.validate_permission("read", plan.source)
             for join in plan.joins:
+                self.cluster.security.validate_permission(
+                    "read", join.foreign_table)
+            from ytsaurus_tpu.query.pruning import extract_column_intervals
+            intervals = extract_column_intervals(plan.where)
+            if plan.joins:
+                # Semi-join pushdown (ISSUE 14): a selective INNER side's
+                # key [min, max] — merged off the foreign chunks' sealed
+                # metadata stats, no decode — narrows the scan intervals, so
+                # whole source shards whose key range cannot join anything
+                # prune before staging.
+                from ytsaurus_tpu.chunks.columnar import merge_column_stats
+                from ytsaurus_tpu.query import planner as query_planner
+                from ytsaurus_tpu.query.pruning import Interval
+                foreign_meta_stats = {}
+                for join in plan.joins:
+                    try:
+                        fnode = self._table_node(join.foreign_table)
+                    except YtError:
+                        continue
+                    per_chunk = fnode.attributes.get("chunk_stats") or []
+                    # A placeholder entry ({} — a chunk sealed before stats
+                    # existed) means that chunk's key range is UNKNOWN:
+                    # merging the OTHER chunks' bounds and pushing them
+                    # would prune source rows that join the legacy chunk.
+                    # Same per column: a column absent from any entry is
+                    # unbounded for this table.
+                    if not per_chunk or not all(isinstance(e, dict) and e
+                                                for e in per_chunk):
+                        continue
+                    merged = merge_column_stats(per_chunk)
+                    for cname in list(merged):
+                        if cname != "$row_count" and \
+                                not all(cname in e for e in per_chunk):
+                            merged.pop(cname)
+                    foreign_meta_stats[join.foreign_table] = merged
+                if foreign_meta_stats:
+                    pushed = query_planner.pushdown_intervals(
+                        plan, foreign_meta_stats)
+                    for name, iv in pushed.items():
+                        intervals[name] = intervals.get(
+                            name, Interval()).narrow(iv)
+        # Shards from the chunk cache or tablet snapshots (lazy LIMIT
+        # scans hand back suppliers: their staging nests under the
+        # coordinator's `coordinator.shard_stage` spans instead).
+        with child_span("query.stage") as stage_span:
+            cache_hits0 = self.cluster.chunk_cache.hits
+            range_ordered_by = None
+            source_chunks = self._indexed_source_chunks(plan, intervals,
+                                                        timestamp)
+            if source_chunks is None:
+                # LIMIT scans stage shards lazily: the coordinator's
+                # adaptive prefetcher fetches only what the early exit
+                # reads, and pipelines staging under evaluation.
+                lazy = plan.limit is not None and plan.group is None
+                source_chunks = self._query_shards(plan.source, timestamp,
+                                                   intervals=intervals,
+                                                   stats=stats, lazy=lazy,
+                                                   token=token)
+                # Tablet shards of a sorted dynamic table arrive in pivot
+                # order: range-ordered by the key columns, which unlocks the
+                # ORDER BY <key prefix> LIMIT early exit.
                 try:
-                    fnode = self._table_node(join.foreign_table)
+                    node = self._table_node(plan.source)
+                    if node.attributes.get("dynamic"):
+                        schema = self._node_schema(node)
+                        if schema is not None and schema.key_column_names:
+                            range_ordered_by = list(schema.key_column_names)
                 except YtError:
-                    continue
-                per_chunk = fnode.attributes.get("chunk_stats") or []
-                # A placeholder entry ({} — a chunk sealed before stats
-                # existed) means that chunk's key range is UNKNOWN:
-                # merging the OTHER chunks' bounds and pushing them
-                # would prune source rows that join the legacy chunk.
-                # Same per column: a column absent from any entry is
-                # unbounded for this table.
-                if not per_chunk or not all(isinstance(e, dict) and e
-                                            for e in per_chunk):
-                    continue
-                merged = merge_column_stats(per_chunk)
-                for cname in list(merged):
-                    if cname != "$row_count" and \
-                            not all(cname in e for e in per_chunk):
-                        merged.pop(cname)
-                foreign_meta_stats[join.foreign_table] = merged
-            if foreign_meta_stats:
-                pushed = query_planner.pushdown_intervals(
-                    plan, foreign_meta_stats)
-                for name, iv in pushed.items():
-                    intervals[name] = intervals.get(
-                        name, Interval()).narrow(iv)
-        range_ordered_by = None
-        source_chunks = self._indexed_source_chunks(plan, intervals,
-                                                    timestamp)
-        if source_chunks is None:
-            # LIMIT scans stage shards lazily: the coordinator's
-            # adaptive prefetcher fetches only what the early exit
-            # reads, and pipelines staging under evaluation.
-            lazy = plan.limit is not None and plan.group is None
-            source_chunks = self._query_shards(plan.source, timestamp,
-                                               intervals=intervals,
-                                               stats=stats, lazy=lazy,
-                                               token=token)
-            # Tablet shards of a sorted dynamic table arrive in pivot
-            # order: range-ordered by the key columns, which unlocks the
-            # ORDER BY <key prefix> LIMIT early exit.
-            try:
-                node = self._table_node(plan.source)
-                if node.attributes.get("dynamic"):
-                    schema = self._node_schema(node)
-                    if schema is not None and schema.key_column_names:
-                        range_ordered_by = list(schema.key_column_names)
-            except YtError:
-                pass
-        foreign = {}
-        for join in plan.joins:
-            if token is not None:
-                token.check()
-            shards = self._query_shards(join.foreign_table, timestamp)
-            foreign[join.foreign_table] = (
-                concat_chunks(shards) if len(shards) > 1 else shards[0])
+                    pass
+            foreign = {}
+            for join in plan.joins:
+                if token is not None:
+                    token.check()
+                shards = self._query_shards(join.foreign_table, timestamp)
+                foreign[join.foreign_table] = (
+                    concat_chunks(shards) if len(shards) > 1 else shards[0])
+            if stage_span.sampled:
+                staged = [c for c in source_chunks if not callable(c)]
+                staged.extend(foreign.values())
+                stage_span.add_tag("chunks", len(source_chunks) + len(foreign))
+                stage_span.add_tag("bytes", sum(c.nbytes for c in staged))
+                # other selects' hits on this cluster count too: a hint
+                stage_span.add_tag(
+                    "cache_hits",
+                    self.cluster.chunk_cache.hits - cache_hits0)
         out = coordinate_and_execute(plan, source_chunks, foreign,
                                      evaluator=self.cluster.evaluator,
                                      merge_shards_below=4_000_000,
@@ -1491,12 +1525,15 @@ class YtClient:
             # which flow to the slow log, EXPLAIN ANALYZE, and drivers.
             stats.degraded_rung = token.rung
             stats.degraded_staleness = round(token.stale_served, 6)
-        if self.cluster._gateway is not None:
-            self.cluster.gateway.record_statistics(
-                stats, self.cluster.evaluator.cache_size())
-        log_event(get_logger("Query"), _logging.INFO, "select_rows",
-                  query=query[:200], **stats.to_dict())
-        return out.to_rows()
+        with child_span("query.record"):
+            if self.cluster._gateway is not None:
+                self.cluster.gateway.record_statistics(
+                    stats, self.cluster.evaluator.cache_size())
+            log_event(get_logger("Query"), _logging.INFO, "select_rows",
+                      query=query[:200], **stats.to_dict())
+        with child_span("query.decode", rows=out.row_count,
+                        columns=len(out.columns)):
+            return out.to_rows()
 
     def _indexed_source_chunks(self, plan, intervals, timestamp):
         """Serve the scan from a secondary index when one applies (WHERE
@@ -1881,9 +1918,11 @@ class YtClient:
                              _read_bounded(t, ts)) for t in tablets]
                 return [_read_bounded(t, timestamp) for t in tablets]
             if lazy:
-                return [(lambda t=t, ts=timestamp: t.read_snapshot(ts))
+                return [(lambda t=t, ts=timestamp:
+                         t.read_snapshot(ts, stats=stats))
                         for t in tablets]
-            return [t.read_snapshot(timestamp) for t in tablets]
+            return [t.read_snapshot(timestamp, stats=stats)
+                    for t in tablets]
         chunk_ids = node.attributes.get("chunk_ids", [])
         col_stats = node.attributes.get("chunk_stats", [])
         # Range-inference analog: skip chunks whose min/max stats cannot

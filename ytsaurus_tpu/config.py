@@ -308,8 +308,8 @@ class TracingConfig(YsonStruct):
     """Query flight recorder knobs (utils/tracing.py + query/profile.py):
 
     - `enabled`: master switch; False turns every span site into the
-      NULL fast path (one contextvar read, ≲1µs — asserted by
-      `bench.py --config trace_overhead`).
+      NULL fast path (one contextvar read and the `NULL_SPAN` singleton:
+      `child_span(...) is NULL_SPAN`, tests/test_flight_recorder.py).
     - `sample_rate`: probability a new ROOT trace records its spans
       (entry points: gateway select/lookup, scheduler operations, HTTP
       proxy).  explain_analyze and X-YT-Trace-Id requests always sample.
@@ -317,7 +317,8 @@ class TracingConfig(YsonStruct):
       are ALWAYS retained in the flight recorder's slow-query log;
       faster queries are retained at `sample_rate`.
     - `slow_log_capacity` / `recent_log_capacity`: bounded profile logs.
-    - `ring_capacity`: finished-span ring buffer size (bounded memory).
+    - `ring_capacity`: finished-span ring buffer size (bounded memory;
+      the default holds one window of the benchmark's Q1 cell, PERF.md).
     """
 
     enabled = param(True, type=bool)
@@ -325,7 +326,7 @@ class TracingConfig(YsonStruct):
     slow_query_threshold = param(0.5, type=float, ge=0.0)
     slow_log_capacity = param(128, type=int, ge=1)
     recent_log_capacity = param(128, type=int, ge=1)
-    ring_capacity = param(4096, type=int, ge=1)
+    ring_capacity = param(16384, type=int, ge=1)
 
 
 _TRACING_CONFIG: "Optional[TracingConfig]" = None
@@ -745,8 +746,8 @@ class SanitizerConfig(YsonStruct):
     edges, lock-order inversions, hold-budget violations, and blocking
     operations under hot-path locks.  Disabled by default — the
     registration helper then hands out PLAIN `threading.Lock`s (zero
-    wrappers, zero per-acquire cost; `bench.py --config
-    sanitizer_overhead` asserts it).  Enablement applies to locks
+    wrappers, zero per-acquire cost; tests/test_sanitizer.py asserts
+    the type).  Enablement applies to locks
     created AFTER `sanitizers.configure(cfg)` runs (or set
     YT_TPU_SANITIZE=1 before the process constructs its daemons, the
     tests/conftest pattern)."""

@@ -48,6 +48,7 @@ def _exclusive(x, axis):
     return jnp.cumsum(x, axis=axis) - x
 
 
+@jax.named_scope("radix.pass")       # the name its ops carry in a trace
 def radix_pass(digit: jax.Array, payloads: list[jax.Array],
                engine: str = "gather") -> list[jax.Array]:
     """One stable ascending partition by `digit` (u32 values < 256).

@@ -213,6 +213,7 @@ def _sorted_segment_reduce(function: str, data: jax.Array,
     return jnp.where(right > left, out, neutral)
 
 
+@jax.named_scope("segments.reduce")  # the name its ops carry in a trace
 def _segment_reduce(function: str, data: jax.Array, seg_ids: jax.Array,
                     num_segments: int, assume_sorted: bool = False):
     if num_segments <= _dense_limit():

@@ -28,6 +28,7 @@ from ytsaurus_tpu.query.statistics import QueryStatistics
 from ytsaurus_tpu.schema import EValueType, TableSchema
 from ytsaurus_tpu.utils.profiling import PoolSensorCache, Profiler
 from ytsaurus_tpu.utils import sanitizers
+from ytsaurus_tpu.utils.tracing import child_span
 
 # Process-wide compile-cache counters, tagged by the admitted query's
 # pool (identity rides the CancellationToken): the steady-state
@@ -325,7 +326,7 @@ class _PendingResult:
     queue on a host read per shard."""
 
     __slots__ = ("planes", "count", "output", "stats", "_t0", "_chunk",
-                 "compile_seconds", "execution_tier")
+                 "compile_seconds", "execution_tier", "fingerprint")
 
     def __init__(self, planes, count, output, stats=None, t0=None):
         self.planes = planes
@@ -335,6 +336,7 @@ class _PendingResult:
         self._t0 = t0
         self.compile_seconds = 0.0
         self.execution_tier = "compiled"
+        self.fingerprint: Optional[str] = None
         self._chunk: Optional[ColumnarChunk] = None
 
     def finish(self, host_count: Optional[int] = None) -> ColumnarChunk:
@@ -347,7 +349,12 @@ class _PendingResult:
                 # With host_count supplied, finish_all already did ONE
                 # stacked transfer for the batch (noted there).
                 sanitizers.note_host_sync("evaluator.finish")
-            n = int(self.count if host_count is None else host_count)
+                # The wait for the device, apart from prepare and launch
+                # (runs on the caller's thread, so under its trace).
+                with child_span("evaluator.sync", pendings=1):
+                    n = int(self.count)
+            else:
+                n = int(host_count)
             out_columns: dict[str, Column] = {}
             out_schema_cols = []
             for out_col, (data, valid) in zip(self.output, self.planes):
@@ -366,12 +373,14 @@ class _PendingResult:
 class _ReadyResult:
     """Already-materialized result (totals plans sync internally)."""
 
-    __slots__ = ("_chunk",)
+    __slots__ = ("_chunk", "fingerprint")
     count = None
     execution_tier = "compiled"
 
-    def __init__(self, chunk: ColumnarChunk):
+    def __init__(self, chunk: ColumnarChunk,
+                 fingerprint: Optional[str] = None):
         self._chunk = chunk
+        self.fingerprint = fingerprint
 
     def finish(self, host_count: Optional[int] = None) -> ColumnarChunk:
         return self._chunk
@@ -389,7 +398,8 @@ def finish_all(pendings: Sequence) -> list[ColumnarChunk]:
         # The one stacked transfer happens HERE; a single open pending
         # falls through to finish(), which notes its own sync.
         sanitizers.note_host_sync("evaluator.finish_all")
-        counts = np.asarray(jnp.stack([p.count for p in open_]))
+        with child_span("evaluator.sync", pendings=len(open_)):
+            counts = np.asarray(jnp.stack([p.count for p in open_]))
         host = {id(p): int(c) for p, c in zip(open_, counts)}
     return [p.finish(host_count=host.get(id(p))) for p in pendings]
 
@@ -701,8 +711,9 @@ class Evaluator:
         `token` (query/serving.CancellationToken) is checked BEFORE any
         device program launches: a query past its deadline stops here
         instead of consuming device time on a result nobody will read."""
-        return self.run_plan_async(plan, chunk, foreign_chunks, stats,
-                                   token).finish()
+        with self._run_plan_span(chunk) as span:
+            return self._start_plan(plan, chunk, foreign_chunks, stats,
+                                    token, span).finish()
 
     def run_plan_async(self, plan: "ir.Query | ir.FrontQuery",
                        chunk: ColumnarChunk,
@@ -712,13 +723,41 @@ class Evaluator:
         """Dispatch a plan's device program WITHOUT synchronizing;
         returns a pending handle whose `.finish()` yields the chunk.
         The coordinator's shard fan-out uses this to enqueue every
-        shard's program before the first host sync."""
-        import time as _time
+        shard's program before the first host sync (its
+        `evaluator.run_plan` span therefore ends at the launch; the
+        batch's `evaluator.sync` is `finish_all`'s)."""
+        with self._run_plan_span(chunk) as span:
+            return self._start_plan(plan, chunk, foreign_chunks, stats,
+                                    token, span)
 
-        from ytsaurus_tpu.utils.tracing import child_span
+    @staticmethod
+    def _run_plan_span(chunk: ColumnarChunk):
+        # Span per plan execution (ref: evaluator.cpp:67-75 annotates
+        # spans with query fingerprints; the tag lands once the dispatch
+        # has computed it).  INTERIOR site: records only under a live
+        # trace (gateway/scheduler root), so untraced evaluator use stays
+        # on the null fast path.  Children: evaluator.prepare / compile /
+        # launch, and on the synchronous path evaluator.sync.
+        return child_span("evaluator.run_plan", rows=chunk.row_count)
+
+    def _start_plan(self, plan, chunk, foreign_chunks, stats, token, span):
+        import time as _time
         if token is not None:
             token.check()
         t0 = _time.perf_counter()
+        pending = self._dispatch_traced(plan, chunk, foreign_chunks, stats,
+                                        t0, pool=getattr(token, "pool",
+                                                         None))
+        span.add_tag("fingerprint", pending.fingerprint)
+        span.add_tag("compile_seconds",
+                     round(getattr(pending, "compile_seconds", 0.0), 6))
+        span.add_tag("execution_tier",
+                     getattr(pending, "execution_tier", "compiled"))
+        return pending
+
+    def _dispatch_traced(self, plan, chunk, foreign_chunks, stats, t0,
+                         pool=None):
+        import time as _time
         jplan = None
         if isinstance(plan, ir.Query) and len(plan.joins) > 1:
             # Cost-based join order (ISSUE 14, query/planner.py): the
@@ -730,33 +769,6 @@ class Evaluator:
             from ytsaurus_tpu.query import planner
             plan, jplan = planner.reorder_for_chunks(
                 plan, chunk.row_count, foreign_chunks)
-        # Span per plan execution, tagged with the plan fingerprint (ref:
-        # evaluator.cpp:67-75 annotates spans with query fingerprints);
-        # computed once and reused as the compile-cache key.  With
-        # CompileConfig.parameterize this is the SHAPE fingerprint —
-        # literal values hoisted, limits bucketed (ISSUE 10) — so one
-        # cache entry serves every constant of a query shape.  INTERIOR
-        # site: records only under a live trace (gateway/scheduler root),
-        # so untraced evaluator use stays on the null fast path.
-        fp = plan_fingerprint(plan)
-        span = child_span("evaluator.run_plan", fingerprint=fp,
-                          rows=chunk.row_count)
-        with span:
-            pending = self._dispatch_traced(plan, chunk, foreign_chunks,
-                                            stats, t0, fp,
-                                            pool=getattr(token, "pool",
-                                                         None),
-                                            jplan=jplan)
-            span.add_tag("compile_seconds",
-                         round(getattr(pending, "compile_seconds", 0.0),
-                               6))
-            span.add_tag("execution_tier",
-                         getattr(pending, "execution_tier", "compiled"))
-            return pending
-
-    def _dispatch_traced(self, plan, chunk, foreign_chunks, stats, t0,
-                         fp=None, pool=None, jplan=None):
-        import time as _time
         owned_chunk = False
         if isinstance(plan, ir.Query) and plan.joins:
             foreign_chunks = foreign_chunks or {}
@@ -787,8 +799,9 @@ class Evaluator:
             # consumer, so its column planes are donatable (a totals
             # plan dispatches the same chunk twice — excluded below).
             owned_chunk = True
-        elif isinstance(plan, ir.Query):
-            chunk = _project_chunk(chunk, plan.schema)
+        # A plain scan's chunk is projected to the plan's columns inside
+        # the dispatch, under its `evaluator.prepare` span.
+        project = isinstance(plan, ir.Query) and not owned_chunk
 
         # GROUP BY ... WITH TOTALS: one extra grand-total row (null keys)
         # aggregated over the same filtered input, appended after the groups
@@ -797,7 +810,9 @@ class Evaluator:
         # The concat needs both row counts, so totals plans materialize
         # eagerly.
         if plan.group is not None and plan.group.totals:
-            main = self._dispatch(plan, chunk, stats, fp=fp, pool=pool)
+            if project:
+                chunk = _project_chunk(chunk, plan.schema)
+            main = self._dispatch(plan, chunk, stats, pool=pool)
             result = main.finish()
             totals_plan = _make_totals_plan(plan)
             totals_pending = self._dispatch(totals_plan, chunk, stats,
@@ -809,10 +824,11 @@ class Evaluator:
                 # keep it out of the execute bucket.
                 stats.execute_time += _time.perf_counter() - t0 - \
                     main.compile_seconds - totals_pending.compile_seconds
-            return _ReadyResult(result)
+            return _ReadyResult(result, main.fingerprint)
 
-        pending = self._dispatch(plan, chunk, stats, fp=fp, pool=pool,
-                                 donate_columns=owned_chunk)
+        pending = self._dispatch(plan, chunk, stats, pool=pool,
+                                 donate_columns=owned_chunk,
+                                 project=project)
         pending.stats = stats
         # The execute clock starts after compilation: wall = compile +
         # execute, reported separately (EXPLAIN ANALYZE's first split).
@@ -821,24 +837,43 @@ class Evaluator:
 
     def _dispatch(self, plan, chunk: ColumnarChunk,
                   stats: Optional[QueryStatistics] = None,
-                  fp: Optional[str] = None,
                   pool: Optional[str] = None,
-                  donate_columns: bool = False) -> _PendingResult:
-        prepared = prepare(plan, chunk)
-        key = (fp or plan_fingerprint(plan), chunk.capacity,
-               prepared.binding_shapes())
-        if donate_columns:
-            # A donating executable consumes its column planes; it must
-            # never be served to a dispatch over a persistent chunk.
-            key = key + ("donate-cols",)
-        columns = {c.name: (chunk.columns[c.name].data,
-                            chunk.columns[c.name].valid)
-                   for c in plan.schema}
-        args = (columns, chunk.row_valid, tuple(prepared.bindings))
-        with self._cache_lock:
-            fn = self._cache.get(key)
-            if fn is not None:
-                self._cache.move_to_end(key)
+                  donate_columns: bool = False,
+                  project: bool = False) -> _PendingResult:
+        # Everything between the call and the cache decision: the plan's
+        # fingerprint (with CompileConfig.parameterize the SHAPE
+        # fingerprint — literal values hoisted, limits bucketed, ISSUE 10
+        # — so one cache entry serves every constant of a query shape),
+        # the projection of a plain scan's chunk, the lowering's bind,
+        # the cache key and its lookup.  `cache` says how it ended.
+        with child_span("evaluator.prepare") as span:
+            fp = plan_fingerprint(plan)
+            if project:
+                chunk = _project_chunk(chunk, plan.schema)
+            prepared = prepare(plan, chunk)
+            key = (fp, chunk.capacity, prepared.binding_shapes())
+            if donate_columns:
+                # A donating executable consumes its column planes; it
+                # must never be served to a dispatch over a persistent
+                # chunk.
+                key = key + ("donate-cols",)
+            columns = {c.name: (chunk.columns[c.name].data,
+                                chunk.columns[c.name].valid)
+                       for c in plan.schema}
+            args = (columns, chunk.row_valid, tuple(prepared.bindings))
+            with self._cache_lock:
+                fn = self._cache.get(key)
+                if fn is not None:
+                    self._cache.move_to_end(key)
+            cache = "hit"
+            if fn is None:
+                # Single-flight: either a concurrent leader hands us the
+                # finished program (counted as a hit below), or WE are
+                # elected leader (None back) and must release the gate.
+                fn = self._acquire_inflight(key)
+                cache = "miss" if fn is None else "inflight"
+            span.add_tag("fingerprint", fp)
+            span.add_tag("cache", cache)
         compile_seconds = 0.0
         result = None
         if stats is not None:
@@ -846,11 +881,6 @@ class Evaluator:
             # bucket churn (a shape-spectrum leak) becomes visible PER
             # QUERY in EXPLAIN ANALYZE, not just in aggregate.
             stats.capacity_buckets.add(int(chunk.capacity))
-        if fn is None:
-            # Single-flight: either a concurrent leader hands us the
-            # finished program (counted as a hit below), or WE are
-            # elected leader (None back) and must release the gate.
-            fn = self._acquire_inflight(key)
         if fn is None:
             # Tier decision (ISSUE 18): with tiering on and the plan
             # inside the interpreter's DECLARED coverage, _compile_miss
@@ -914,23 +944,27 @@ class Evaluator:
         if stats is not None:
             stats.execution_tier = execution_tier
         if result is None:
-            try:
-                planes, count = fn(*args)
-            except Exception:
-                if hasattr(fn, "lower"):
-                    raise             # plain jitted fn: a genuine error
-                # AOT-compiled rejects an aval drift the cache key did
-                # not capture: rebuild through the tolerant jit wrapper
-                # (a genuine execution error re-raises identically).
-                fn = _jit_run(prepared.run, donate_columns)
-                with self._cache_lock:
-                    self._cache[key] = fn
-                planes, count = fn(*args)
+            # The dispatch call until it returns: the device runs on.
+            with child_span("evaluator.launch"):
+                try:
+                    planes, count = fn(*args)
+                except Exception:
+                    if hasattr(fn, "lower"):
+                        raise         # plain jitted fn: a genuine error
+                    # AOT-compiled rejects an aval drift the cache key
+                    # did not capture: rebuild through the tolerant jit
+                    # wrapper (a genuine execution error re-raises
+                    # identically).
+                    fn = _jit_run(prepared.run, donate_columns)
+                    with self._cache_lock:
+                        self._cache[key] = fn
+                    planes, count = fn(*args)
         else:
             planes, count = result
         pending = _PendingResult(planes, count, prepared.output)
         pending.compile_seconds = compile_seconds
         pending.execution_tier = execution_tier
+        pending.fingerprint = fp
         return pending
 
     def _interpreted(self, interp_query, key, chunk, prepared, args,
@@ -957,6 +991,7 @@ class Evaluator:
                 self._governor.rearm(key[0])
         pending = _PendingResult(planes, count, interp_query.output)
         pending.execution_tier = "interpreted"
+        pending.fingerprint = key[0]
         return pending
 
     def _compile_miss(self, key, prepared, chunk, args, stats, pool,
@@ -977,7 +1012,6 @@ class Evaluator:
         from ytsaurus_tpu.config import workload_config
         from ytsaurus_tpu.query.engine.aot_cache import (
             get_cluster_store, get_disk_cache)
-        from ytsaurus_tpu.utils.tracing import child_span
         cfg = workload_config()
         result = None
         # Cache miss, classified for the observatory BEFORE the
@@ -1079,11 +1113,6 @@ class Evaluator:
             else:
                 stats.compile_new_fingerprint += 1
         return fn, compile_seconds, result
-
-    def _execute(self, plan, chunk: ColumnarChunk,
-                 stats: Optional[QueryStatistics] = None,
-                 fp: Optional[str] = None) -> ColumnarChunk:
-        return self._dispatch(plan, chunk, stats, fp=fp).finish()
 
 
 def _initial_namespace(plan: ir.Query) -> list[tuple[str, str]]:

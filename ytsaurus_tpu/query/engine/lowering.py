@@ -324,263 +324,270 @@ def prepare(plan: "ir.Query | ir.FrontQuery", chunk) -> PreparedQuery:
         ctx = EmitContext(columns=columns, bindings=bindings, capacity=capacity)
         stage_cap = capacity
         mask = row_valid
-        if where_b is not None:
-            d, v = where_b.emit(ctx)
-            mask = mask & v & d.astype(bool)
-
-        if group is not None and fast_group is not None:
-            sizes_offsets, strides, dims, seg_cap = fast_group
-            nseg = dims + 1                    # +1 garbage slot for masked rows
-
-            def _pad(plane):
-                return jnp.zeros(seg_cap, dtype=plane.dtype).at[:nseg].set(plane)
-
-            key_planes = [b.emit(ctx) for _, b in group_key_b]
-            seg = jnp.zeros(capacity, dtype=jnp.int32)
-            for (data, valid), (size, key_offset), stride in zip(
-                    key_planes, sizes_offsets, strides):
-                if jnp.issubdtype(data.dtype, jnp.integer):
-                    # Modular uint64 subtraction: correct for int64 offsets
-                    # near the type bounds and uint64 keys >= 2^63.
-                    off = np.uint64(key_offset % (1 << 64))
-                    shifted = (data.astype(jnp.uint64) - off).astype(jnp.int32)
-                else:
-                    shifted = (data.astype(jnp.int64)
-                               - key_offset).astype(jnp.int32)
-                code = jnp.where(valid, shifted, size)
-                seg = seg + code * stride
-            seg = jnp.where(mask, seg, dims)   # masked-out rows → garbage slot
-            # Above the dense-reduce limit the reduction needs segment-
-            # sorted rows (scatter-adds serialize on TPU) — ONE u32 sort
-            # here is shared by every aggregate below.
-            from ytsaurus_tpu.ops.segments import presort_segments
-            grp_order = presort_segments(seg, nseg)
-            presorted = grp_order is not None
-            if presorted:
-                seg = seg[grp_order]
-                gmask = mask[grp_order]
-            else:
-                gmask = mask
-
-            def _r(plane):
-                return plane if grp_order is None else plane[grp_order]
-
-            present_counts, _ = segment_aggregate(
-                "count", gmask, gmask, seg, nseg, EValueType.int64,
-                assume_sorted=presorted)
-            present = _pad((jnp.arange(nseg) < dims) & (present_counts > 0))
-            new_columns: dict[str, tuple[jax.Array, jax.Array]] = {}
-            slot = jnp.arange(seg_cap)
-            for (name, bound), (size, key_offset), stride in zip(
-                    group_key_b, sizes_offsets, strides):
-                code = (slot // stride) % (size + 1)
-                key_valid = code < size
-                data = jnp.clip(code, 0, max(size - 1, 0))
-                if bound.type is EValueType.boolean:
-                    data = data.astype(jnp.bool_)
-                elif bound.type in (EValueType.int64, EValueType.uint64):
-                    dt = device_dtype(bound.type)
-                    data = data.astype(dt) + jnp.array(key_offset, dtype=dt)
-                else:
-                    data = data.astype(jnp.int32)
-                new_columns[name] = (data, key_valid)
-            for agg, arg, by_arg in agg_arg_b:
-                if agg.function == "avg":
-                    data, valid = arg.emit(ctx)
-                    data = _r(data).astype(jnp.float64)
-                    valid = _r(valid) & gmask
-                    s, sv = segment_aggregate("sum", data, valid, seg,
-                                              nseg, EValueType.double,
-                                              assume_sorted=presorted)
-                    c, _ = segment_aggregate("count", data, valid, seg,
-                                             nseg, EValueType.int64,
-                                             assume_sorted=presorted)
-                    new_columns[agg.name] = (_pad(s / jnp.maximum(c, 1)),
-                                             _pad(sv))
-                elif agg.function == "cardinality":
-                    data, valid = arg.emit(ctx)
-                    d, dv = segment_distinct_count(
-                        _r(data), _r(valid) & gmask, seg, nseg)
-                    new_columns[agg.name] = (_pad(d), _pad(dv))
-                elif agg.function in ("argmin", "argmax"):
-                    vd, vv = arg.emit(ctx)
-                    bd, bv = by_arg.emit(ctx)
-                    out_d, out_v = segment_arg_by(
-                        _r(vd), _r(vv), _r(bd), _r(bv) & gmask, seg, nseg,
-                        take_max=(agg.function == "argmax"),
-                        assume_sorted=presorted)
-                    new_columns[agg.name] = (_pad(out_d), _pad(out_v))
-                else:
-                    data, valid = arg.emit(ctx)
-                    valid = _r(valid) & gmask
-                    out, out_v = segment_aggregate(
-                        agg.function, _r(data), valid, seg, nseg, agg.type,
-                        assume_sorted=presorted)
-                    new_columns[agg.name] = (_pad(out), _pad(out_v))
-            mask = present
-            stage_cap = seg_cap
-            ctx = EmitContext(columns=new_columns, bindings=bindings,
-                              capacity=seg_cap)
-            if having_b is not None:
-                d, v = having_b.emit(ctx)
-                mask = mask & v & d.astype(bool)
-        elif group is not None:
-            key_planes = [b.emit(ctx) for _, b in group_key_b]
-            # Exact grouping order: equal key tuples made adjacent via
-            # the order-preserving key encoding (segments.py), masked
-            # rows last; large/wide keys dispatch to the tiled radix
-            # engine (ops/radix.py) instead of the one-pass network.
-            order_idx = hash_group_order(key_planes, mask)
-            sorted_mask = mask[order_idx]
-            sorted_keys = [(d[order_idx], v[order_idx]) for d, v in key_planes]
-            seg_ids, num_groups = segment_boundaries(sorted_keys, sorted_mask)
-            new_columns: dict[str, tuple[jax.Array, jax.Array]] = {}
-            for (name, _), (data, valid) in zip(group_key_b, sorted_keys):
-                out_d, _ = segment_aggregate("first", data, sorted_mask,
-                                             seg_ids, capacity,
-                                             EValueType.null,
-                                             assume_sorted=True)
-                out_v, _ = segment_aggregate(
-                    "first", valid.astype(jnp.int8), sorted_mask, seg_ids,
-                    capacity, EValueType.null, assume_sorted=True)
-                new_columns[name] = (out_d, out_v.astype(bool))
-            for agg, arg, by_arg in agg_arg_b:
-                if agg.function == "avg":
-                    data, valid = arg.emit(ctx)
-                    data = data[order_idx].astype(jnp.float64)
-                    valid = valid[order_idx] & sorted_mask
-                    s, sv = segment_aggregate("sum", data, valid, seg_ids,
-                                              capacity, EValueType.double,
-                                              assume_sorted=True)
-                    c, _ = segment_aggregate("count", data, valid, seg_ids,
-                                             capacity, EValueType.int64,
-                                             assume_sorted=True)
-                    cnt = jnp.maximum(c, 1)
-                    new_columns[agg.name] = (s / cnt, sv)
-                elif agg.function == "cardinality":
-                    data, valid = arg.emit(ctx)
-                    d, dv = segment_distinct_count(
-                        data[order_idx], valid[order_idx] & sorted_mask,
-                        seg_ids, capacity)
-                    new_columns[agg.name] = (d, dv)
-                elif agg.function in ("argmin", "argmax"):
-                    vd, vv = arg.emit(ctx)
-                    bd, bv = by_arg.emit(ctx)
-                    out_d, out_v = segment_arg_by(
-                        vd[order_idx], vv[order_idx],
-                        bd[order_idx], bv[order_idx] & sorted_mask,
-                        seg_ids, capacity,
-                        take_max=(agg.function == "argmax"),
-                        assume_sorted=True)
-                    new_columns[agg.name] = (out_d, out_v)
-                else:
-                    data, valid = arg.emit(ctx)
-                    data = data[order_idx]
-                    valid = valid[order_idx] & sorted_mask
-                    out, out_v = segment_aggregate(
-                        agg.function, data, valid, seg_ids, capacity,
-                        agg.type, assume_sorted=True)
-                    new_columns[agg.name] = (out, out_v)
-            mask = jnp.arange(capacity) < num_groups
-            ctx = EmitContext(columns=new_columns, bindings=bindings,
-                              capacity=capacity)
-            if having_b is not None:
-                d, v = having_b.emit(ctx)
+        # Stage names for the device trace (trace-time metadata: an HLO
+        # op's name carries the scope it was emitted under).
+        with jax.named_scope("ql.filter"):
+            if where_b is not None:
+                d, v = where_b.emit(ctx)
                 mask = mask & v & d.astype(bool)
 
-        if win_stage is not None:
-            # Window columns join the namespace; no rows move.
-            win_columns = win_stage.emit(ctx, mask)
-            ctx = EmitContext(columns={**ctx.columns, **win_columns},
-                              bindings=bindings, capacity=stage_cap)
+        with jax.named_scope("ql.group"):
+            if group is not None and fast_group is not None:
+                sizes_offsets, strides, dims, seg_cap = fast_group
+                nseg = dims + 1                    # +1 garbage slot for masked rows
 
-        if order_b and not presorted_skip:
-            # Candidates = top-k by value (masked excluded) ∪ up-to-k null
-            # rows (null ordering differs by direction; the tiny exact sort
-            # below settles it).
-            if use_topk:
-                bound, descending = order_b[0]
-                data, valid = bound.emit(ctx)
-                value, null_key = sort_key_planes(data, valid, descending)
-                # Invert the value so top_k picks the query's front.  Valid
-                # rows compete by value; null rows are all equal (their
-                # position relative to values is settled by the tiny exact
-                # sort below), so an indicator pass covers them; a third
-                # indicator pass covers valid rows whose inverted value
-                # aliases the exclusion sentinel (single value class).
-                if jnp.issubdtype(value.dtype, jnp.unsignedinteger):
-                    inv = ~value
-                elif jnp.issubdtype(value.dtype, jnp.integer) or \
-                        value.dtype == jnp.bool_:
-                    inv = ~value.astype(jnp.int64)
+                def _pad(plane):
+                    return jnp.zeros(seg_cap, dtype=plane.dtype).at[:nseg].set(plane)
+
+                key_planes = [b.emit(ctx) for _, b in group_key_b]
+                seg = jnp.zeros(capacity, dtype=jnp.int32)
+                for (data, valid), (size, key_offset), stride in zip(
+                        key_planes, sizes_offsets, strides):
+                    if jnp.issubdtype(data.dtype, jnp.integer):
+                        # Modular uint64 subtraction: correct for int64 offsets
+                        # near the type bounds and uint64 keys >= 2^63.
+                        off = np.uint64(key_offset % (1 << 64))
+                        shifted = (data.astype(jnp.uint64) - off).astype(jnp.int32)
+                    else:
+                        shifted = (data.astype(jnp.int64)
+                                   - key_offset).astype(jnp.int32)
+                    code = jnp.where(valid, shifted, size)
+                    seg = seg + code * stride
+                seg = jnp.where(mask, seg, dims)   # masked-out rows → garbage slot
+                # Above the dense-reduce limit the reduction needs segment-
+                # sorted rows (scatter-adds serialize on TPU) — ONE u32 sort
+                # here is shared by every aggregate below.
+                from ytsaurus_tpu.ops.segments import presort_segments
+                grp_order = presort_segments(seg, nseg)
+                presorted = grp_order is not None
+                if presorted:
+                    seg = seg[grp_order]
+                    gmask = mask[grp_order]
                 else:
-                    inv = -value.astype(jnp.float64)
-                if jnp.issubdtype(inv.dtype, jnp.integer):
-                    bottom = jnp.array(jnp.iinfo(inv.dtype).min, inv.dtype)
-                else:
-                    bottom = jnp.array(-jnp.inf, inv.dtype)
-                include = mask & valid
-                ranked = jnp.where(include, inv, bottom)
-                _, idx1 = jax.lax.top_k(ranked, k_limit)
-                nulls = (mask & ~valid).astype(jnp.int32)
-                _, idx2 = jax.lax.top_k(nulls, k_limit)
-                aliased = (include & (inv == bottom)).astype(jnp.int32)
-                _, idx3 = jax.lax.top_k(aliased, k_limit)
-                cand = jnp.concatenate([idx1, idx2, idx3])
-                # Dedupe candidates (overlap would duplicate rows).
-                cand_sorted = jnp.sort(cand)
-                dup = jnp.concatenate([
-                    jnp.zeros(1, dtype=bool),
-                    cand_sorted[1:] == cand_sorted[:-1]])
-                cand_cap = cand.shape[0]
+                    gmask = mask
+
+                def _r(plane):
+                    return plane if grp_order is None else plane[grp_order]
+
+                present_counts, _ = segment_aggregate(
+                    "count", gmask, gmask, seg, nseg, EValueType.int64,
+                    assume_sorted=presorted)
+                present = _pad((jnp.arange(nseg) < dims) & (present_counts > 0))
+                new_columns: dict[str, tuple[jax.Array, jax.Array]] = {}
+                slot = jnp.arange(seg_cap)
+                for (name, bound), (size, key_offset), stride in zip(
+                        group_key_b, sizes_offsets, strides):
+                    code = (slot // stride) % (size + 1)
+                    key_valid = code < size
+                    data = jnp.clip(code, 0, max(size - 1, 0))
+                    if bound.type is EValueType.boolean:
+                        data = data.astype(jnp.bool_)
+                    elif bound.type in (EValueType.int64, EValueType.uint64):
+                        dt = device_dtype(bound.type)
+                        data = data.astype(dt) + jnp.array(key_offset, dtype=dt)
+                    else:
+                        data = data.astype(jnp.int32)
+                    new_columns[name] = (data, key_valid)
+                for agg, arg, by_arg in agg_arg_b:
+                    if agg.function == "avg":
+                        data, valid = arg.emit(ctx)
+                        data = _r(data).astype(jnp.float64)
+                        valid = _r(valid) & gmask
+                        s, sv = segment_aggregate("sum", data, valid, seg,
+                                                  nseg, EValueType.double,
+                                                  assume_sorted=presorted)
+                        c, _ = segment_aggregate("count", data, valid, seg,
+                                                 nseg, EValueType.int64,
+                                                 assume_sorted=presorted)
+                        new_columns[agg.name] = (_pad(s / jnp.maximum(c, 1)),
+                                                 _pad(sv))
+                    elif agg.function == "cardinality":
+                        data, valid = arg.emit(ctx)
+                        d, dv = segment_distinct_count(
+                            _r(data), _r(valid) & gmask, seg, nseg)
+                        new_columns[agg.name] = (_pad(d), _pad(dv))
+                    elif agg.function in ("argmin", "argmax"):
+                        vd, vv = arg.emit(ctx)
+                        bd, bv = by_arg.emit(ctx)
+                        out_d, out_v = segment_arg_by(
+                            _r(vd), _r(vv), _r(bd), _r(bv) & gmask, seg, nseg,
+                            take_max=(agg.function == "argmax"),
+                            assume_sorted=presorted)
+                        new_columns[agg.name] = (_pad(out_d), _pad(out_v))
+                    else:
+                        data, valid = arg.emit(ctx)
+                        valid = _r(valid) & gmask
+                        out, out_v = segment_aggregate(
+                            agg.function, _r(data), valid, seg, nseg, agg.type,
+                            assume_sorted=presorted)
+                        new_columns[agg.name] = (_pad(out), _pad(out_v))
+                mask = present
+                stage_cap = seg_cap
+                ctx = EmitContext(columns=new_columns, bindings=bindings,
+                                  capacity=seg_cap)
+                if having_b is not None:
+                    d, v = having_b.emit(ctx)
+                    mask = mask & v & d.astype(bool)
+            elif group is not None:
+                key_planes = [b.emit(ctx) for _, b in group_key_b]
+                # Exact grouping order: equal key tuples made adjacent via
+                # the order-preserving key encoding (segments.py), masked
+                # rows last; large/wide keys dispatch to the tiled radix
+                # engine (ops/radix.py) instead of the one-pass network.
+                order_idx = hash_group_order(key_planes, mask)
+                sorted_mask = mask[order_idx]
+                sorted_keys = [(d[order_idx], v[order_idx]) for d, v in key_planes]
+                seg_ids, num_groups = segment_boundaries(sorted_keys, sorted_mask)
+                new_columns: dict[str, tuple[jax.Array, jax.Array]] = {}
+                for (name, _), (data, valid) in zip(group_key_b, sorted_keys):
+                    out_d, _ = segment_aggregate("first", data, sorted_mask,
+                                                 seg_ids, capacity,
+                                                 EValueType.null,
+                                                 assume_sorted=True)
+                    out_v, _ = segment_aggregate(
+                        "first", valid.astype(jnp.int8), sorted_mask, seg_ids,
+                        capacity, EValueType.null, assume_sorted=True)
+                    new_columns[name] = (out_d, out_v.astype(bool))
+                for agg, arg, by_arg in agg_arg_b:
+                    if agg.function == "avg":
+                        data, valid = arg.emit(ctx)
+                        data = data[order_idx].astype(jnp.float64)
+                        valid = valid[order_idx] & sorted_mask
+                        s, sv = segment_aggregate("sum", data, valid, seg_ids,
+                                                  capacity, EValueType.double,
+                                                  assume_sorted=True)
+                        c, _ = segment_aggregate("count", data, valid, seg_ids,
+                                                 capacity, EValueType.int64,
+                                                 assume_sorted=True)
+                        cnt = jnp.maximum(c, 1)
+                        new_columns[agg.name] = (s / cnt, sv)
+                    elif agg.function == "cardinality":
+                        data, valid = arg.emit(ctx)
+                        d, dv = segment_distinct_count(
+                            data[order_idx], valid[order_idx] & sorted_mask,
+                            seg_ids, capacity)
+                        new_columns[agg.name] = (d, dv)
+                    elif agg.function in ("argmin", "argmax"):
+                        vd, vv = arg.emit(ctx)
+                        bd, bv = by_arg.emit(ctx)
+                        out_d, out_v = segment_arg_by(
+                            vd[order_idx], vv[order_idx],
+                            bd[order_idx], bv[order_idx] & sorted_mask,
+                            seg_ids, capacity,
+                            take_max=(agg.function == "argmax"),
+                            assume_sorted=True)
+                        new_columns[agg.name] = (out_d, out_v)
+                    else:
+                        data, valid = arg.emit(ctx)
+                        data = data[order_idx]
+                        valid = valid[order_idx] & sorted_mask
+                        out, out_v = segment_aggregate(
+                            agg.function, data, valid, seg_ids, capacity,
+                            agg.type, assume_sorted=True)
+                        new_columns[agg.name] = (out, out_v)
+                mask = jnp.arange(capacity) < num_groups
+                ctx = EmitContext(columns=new_columns, bindings=bindings,
+                                  capacity=capacity)
+                if having_b is not None:
+                    d, v = having_b.emit(ctx)
+                    mask = mask & v & d.astype(bool)
+
+        with jax.named_scope("ql.window"):
+            if win_stage is not None:
+                # Window columns join the namespace; no rows move.
+                win_columns = win_stage.emit(ctx, mask)
+                ctx = EmitContext(columns={**ctx.columns, **win_columns},
+                                  bindings=bindings, capacity=stage_cap)
+
+        with jax.named_scope("ql.order"):
+            if order_b and not presorted_skip:
+                # Candidates = top-k by value (masked excluded) ∪ up-to-k null
+                # rows (null ordering differs by direction; the tiny exact sort
+                # below settles it).
+                if use_topk:
+                    bound, descending = order_b[0]
+                    data, valid = bound.emit(ctx)
+                    value, null_key = sort_key_planes(data, valid, descending)
+                    # Invert the value so top_k picks the query's front.  Valid
+                    # rows compete by value; null rows are all equal (their
+                    # position relative to values is settled by the tiny exact
+                    # sort below), so an indicator pass covers them; a third
+                    # indicator pass covers valid rows whose inverted value
+                    # aliases the exclusion sentinel (single value class).
+                    if jnp.issubdtype(value.dtype, jnp.unsignedinteger):
+                        inv = ~value
+                    elif jnp.issubdtype(value.dtype, jnp.integer) or \
+                            value.dtype == jnp.bool_:
+                        inv = ~value.astype(jnp.int64)
+                    else:
+                        inv = -value.astype(jnp.float64)
+                    if jnp.issubdtype(inv.dtype, jnp.integer):
+                        bottom = jnp.array(jnp.iinfo(inv.dtype).min, inv.dtype)
+                    else:
+                        bottom = jnp.array(-jnp.inf, inv.dtype)
+                    include = mask & valid
+                    ranked = jnp.where(include, inv, bottom)
+                    _, idx1 = jax.lax.top_k(ranked, k_limit)
+                    nulls = (mask & ~valid).astype(jnp.int32)
+                    _, idx2 = jax.lax.top_k(nulls, k_limit)
+                    aliased = (include & (inv == bottom)).astype(jnp.int32)
+                    _, idx3 = jax.lax.top_k(aliased, k_limit)
+                    cand = jnp.concatenate([idx1, idx2, idx3])
+                    # Dedupe candidates (overlap would duplicate rows).
+                    cand_sorted = jnp.sort(cand)
+                    dup = jnp.concatenate([
+                        jnp.zeros(1, dtype=bool),
+                        cand_sorted[1:] == cand_sorted[:-1]])
+                    cand_cap = cand.shape[0]
+                    ctx = EmitContext(
+                        columns={name: (d[cand_sorted], v[cand_sorted])
+                                 for name, (d, v) in ctx.columns.items()},
+                        bindings=bindings, capacity=cand_cap)
+                    mask = mask[cand_sorted] & ~dup
+                    stage_cap = cand_cap
+                # Packed composite sort key: masked-last bit + every ORDER BY
+                # item (null bit + order-preserving value bits) packed into as
+                # few u64 words as possible — minimum operands through the
+                # device sort network (payload columns are gathered after).
+                items = [((~mask), jnp.ones_like(mask), False, 1)]
+                for (bound, descending), bits in zip(order_b, order_bits):
+                    data, valid = bound.emit(ctx)
+                    items.append((data, valid, descending, bits))
+                order_idx = packed_sort_indices(items)
                 ctx = EmitContext(
-                    columns={name: (d[cand_sorted], v[cand_sorted])
+                    columns={name: (d[order_idx], v[order_idx])
                              for name, (d, v) in ctx.columns.items()},
-                    bindings=bindings, capacity=cand_cap)
-                mask = mask[cand_sorted] & ~dup
-                stage_cap = cand_cap
-            # Packed composite sort key: masked-last bit + every ORDER BY
-            # item (null bit + order-preserving value bits) packed into as
-            # few u64 words as possible — minimum operands through the
-            # device sort network (payload columns are gathered after).
-            items = [((~mask), jnp.ones_like(mask), False, 1)]
-            for (bound, descending), bits in zip(order_b, order_bits):
-                data, valid = bound.emit(ctx)
-                items.append((data, valid, descending, bits))
-            order_idx = packed_sort_indices(items)
-            ctx = EmitContext(
-                columns={name: (d[order_idx], v[order_idx])
-                         for name, (d, v) in ctx.columns.items()},
-                bindings=bindings, capacity=stage_cap)
-            mask = mask[order_idx]
+                    bindings=bindings, capacity=stage_cap)
+                mask = mask[order_idx]
 
-        planes = []
-        for name, bound in project_b:
-            d, v = bound.emit(ctx)
-            planes.append((d, v))
+        with jax.named_scope("ql.compact"):
+            planes = []
+            for name, bound in project_b:
+                d, v = bound.emit(ctx)
+                planes.append((d, v))
 
-        # Compact valid rows to the front (stable → preserves sort order).
-        comp_idx, total = compact_mask(mask)
-        if offset_slot is not None:
-            # Dynamic offset/limit (read from bindings): clamped to the
-            # stage capacity so the downstream int32 arithmetic is safe.
-            off = jnp.minimum(bindings[offset_slot],
-                              stage_cap).astype(total.dtype)
-        else:
-            off = offset
-        count = total - off
-        if limit is not None:
-            lim = jnp.minimum(bindings[limit_slot],
-                              stage_cap).astype(total.dtype) \
-                if limit_slot is not None else limit
-            count = jnp.minimum(count, lim)
-        count = jnp.maximum(count, 0)
-        out_planes = []
-        shift = jnp.clip(jnp.arange(stage_cap) + off, 0, stage_cap - 1)
-        for d, v in planes:
-            d = d[comp_idx][shift]
-            v = v[comp_idx][shift] & (jnp.arange(stage_cap) < count)
-            out_planes.append((d, v))
+            # Compact valid rows to the front (stable → preserves sort order).
+            comp_idx, total = compact_mask(mask)
+            if offset_slot is not None:
+                # Dynamic offset/limit (read from bindings): clamped to the
+                # stage capacity so the downstream int32 arithmetic is safe.
+                off = jnp.minimum(bindings[offset_slot],
+                                  stage_cap).astype(total.dtype)
+            else:
+                off = offset
+            count = total - off
+            if limit is not None:
+                lim = jnp.minimum(bindings[limit_slot],
+                                  stage_cap).astype(total.dtype) \
+                    if limit_slot is not None else limit
+                count = jnp.minimum(count, lim)
+            count = jnp.maximum(count, 0)
+            out_planes = []
+            shift = jnp.clip(jnp.arange(stage_cap) + off, 0, stage_cap - 1)
+            for d, v in planes:
+                d = d[comp_idx][shift]
+                v = v[comp_idx][shift] & (jnp.arange(stage_cap) < count)
+                out_planes.append((d, v))
         return out_planes, count
 
     return PreparedQuery(

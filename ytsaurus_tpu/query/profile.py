@@ -210,14 +210,18 @@ def format_profile_dict(p: dict) -> str:
 
 
 def format_span_tree(nodes: list[dict], indent: int = 0) -> list[str]:
-    """Indented one-line-per-span rendering of a span_tree() forest."""
+    """Indented one-line-per-span rendering of a span_tree() forest: the
+    span's duration and, where it has children, the part of it that no
+    child covers (`self`)."""
     lines = []
     for node in nodes:
         tags = {k: v for k, v in (node.get("tags") or {}).items()}
         tag_str = "  " + " ".join(f"{k}={v}" for k, v in
                                   sorted(tags.items())) if tags else ""
+        self_str = f" (self {_ms(node.get('self_time', 0.0))})" \
+            if node.get("children") and "self_time" in node else ""
         lines.append(f"{'  ' * indent}- {node['name']} "
-                     f"{_ms(node.get('duration', 0.0))}{tag_str}")
+                     f"{_ms(node.get('duration', 0.0))}{self_str}{tag_str}")
         lines.extend(format_span_tree(node.get("children") or [],
                                       indent + 1))
     return lines

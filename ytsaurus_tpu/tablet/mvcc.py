@@ -32,6 +32,7 @@ assert bit-exact row parity between the two implementations.
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -46,8 +47,9 @@ from ytsaurus_tpu.ops.segments import (
     stable_argsort_u32,
 )
 from ytsaurus_tpu.schema import EValueType, TableSchema
+from ytsaurus_tpu.utils.tracing import child_span
 
-# (kind, versioned-schema key, capacity) → jitted program.  Capacity
+# (kind, versioned-schema key, capacity) → compiled program.  Capacity
 # buckets are powers of two (chunks/columnar.pad_capacity), so the cache
 # stays bounded the same way the evaluator's compile cache does.
 _PROGRAMS: dict = {}
@@ -236,21 +238,53 @@ def _build_retained(key_names: tuple, value_names: tuple, capacity: int):
     return run
 
 
-def _program(kind: str, merged: ColumnarChunk, key_names: tuple,
+def _builder(kind: str, capacity: int, key_names: tuple,
              value_names: tuple):
+    if kind == "visible":
+        return _build_visible(key_names, value_names, capacity)
+    if kind == "sorted":
+        return _build_sorted(key_names, capacity)
+    return _build_retained(key_names, value_names, capacity)
+
+
+def _run_program(kind: str, merged: ColumnarChunk, key_names: tuple,
+                 value_names: tuple, scalars: tuple, stats=None):
+    """Run the (kind, schema, capacity) program over `merged`.  An
+    entry's first call compiles it ahead of time, apart from its
+    execution: the seconds land in a `tablet.mvcc_compile` span and,
+    where the caller threads a QueryStatistics down, in its
+    `compile_time` / `compile_count` (these programs are jitted outside
+    the evaluator, whose counters would otherwise miss them)."""
     key = (kind, _schema_key(merged.schema), merged.capacity)
+    args = (_planes(merged),) + scalars
     fn = _PROGRAMS.get(key)
     if fn is None:
-        if kind == "visible":
-            builder = _build_visible(key_names, value_names,
-                                     merged.capacity)
-        elif kind == "sorted":
-            builder = _build_sorted(key_names, merged.capacity)
-        else:
-            builder = _build_retained(key_names, value_names,
-                                      merged.capacity)
-        fn = _PROGRAMS[key] = jax.jit(builder)
-    return fn
+        jitted = jax.jit(_builder(kind, merged.capacity, key_names,
+                                  value_names))
+        with child_span("tablet.mvcc_compile", kind=kind,
+                        capacity=merged.capacity):
+            t0 = time.perf_counter()
+            try:
+                fn = jitted.lower(*args).compile()
+            except Exception:   # noqa: BLE001 — AOT is how the compile
+                # is timed apart; what it cannot lower runs as the jit
+                # wrapper (the first call then compiles, untimed).
+                fn = jitted
+            seconds = time.perf_counter() - t0
+        _PROGRAMS[key] = fn
+        if stats is not None:
+            stats.compile_count += 1
+            stats.compile_time += seconds
+    try:
+        return fn(*args)
+    except (TypeError, ValueError):
+        if hasattr(fn, "lower"):
+            raise               # the jit wrapper: a genuine error
+        # An AOT executable rejects an aval drift the key did not
+        # capture: serve this entry through the tolerant jit wrapper.
+        fn = _PROGRAMS[key] = jax.jit(_builder(
+            kind, merged.capacity, key_names, value_names))
+        return fn(*args)
 
 
 def _planes(chunk: ColumnarChunk) -> dict:
@@ -277,15 +311,15 @@ def _emit_chunk(schema: TableSchema, out_planes: dict, n: int,
 
 
 def visible_chunk(merged: ColumnarChunk, table_schema: TableSchema,
-                  timestamp: int) -> ColumnarChunk:
+                  timestamp: int, stats=None) -> ColumnarChunk:
     """MVCC merge at `timestamp` over a concatenated versioned chunk →
     the select-input ColumnarChunk (plain table schema, key order)."""
     key_names = tuple(table_schema.key_column_names)
     value_names = tuple(c.name for c in table_schema
                         if c.sort_order is None)
-    fn = _program("visible", merged, key_names, value_names)
-    out, count = fn(_planes(merged), np.int64(merged.row_count),
-                    np.int64(timestamp))
+    out, count = _run_program(
+        "visible", merged, key_names, value_names,
+        (np.int64(merged.row_count), np.int64(timestamp)), stats)
     chunk = _emit_chunk(table_schema.to_unsorted(), out, int(count), merged)
     # The merge emits key order — seal it so ORDER BY <key prefix> over a
     # tablet snapshot skips the packed-key sort (ISSUE 19 layout sealing).
@@ -297,8 +331,8 @@ def sorted_versioned_chunk(merged: ColumnarChunk,
     """Stable (key asc, ts desc) ordering of a versioned chunk — the
     flush sort, without materializing rows."""
     key_names = tuple(table_schema.key_column_names)
-    fn = _program("sorted", merged, key_names, ())
-    out = fn(_planes(merged), np.int64(merged.row_count))
+    out = _run_program("sorted", merged, key_names, (),
+                       (np.int64(merged.row_count),))
     return _emit_chunk(merged.schema, out, merged.row_count, merged)
 
 
@@ -310,7 +344,7 @@ def retained_chunk(merged: ColumnarChunk, table_schema: TableSchema,
     key_names = tuple(table_schema.key_column_names)
     value_names = tuple(c.name for c in table_schema
                         if c.sort_order is None)
-    fn = _program("retained", merged, key_names, value_names)
-    out, count = fn(_planes(merged), np.int64(merged.row_count),
-                    np.int64(retention_timestamp))
+    out, count = _run_program(
+        "retained", merged, key_names, value_names,
+        (np.int64(merged.row_count), np.int64(retention_timestamp)))
     return _emit_chunk(merged.schema, out, int(count), merged)

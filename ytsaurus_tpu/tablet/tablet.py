@@ -452,9 +452,12 @@ class Tablet:
                     valid=jnp.zeros(cap, dtype=bool))
         return ColumnarChunk(schema=vschema, row_count=n, columns=columns)
 
-    def read_snapshot(self, timestamp: int = MAX_TIMESTAMP) -> ColumnarChunk:
+    def read_snapshot(self, timestamp: int = MAX_TIMESTAMP,
+                      stats=None) -> ColumnarChunk:
         """Materialize the tablet contents as of `timestamp` into a plain
-        columnar chunk (the select_rows input).
+        columnar chunk (the select_rows input).  `stats` (the calling
+        query's QueryStatistics) takes the merge program's compile
+        seconds when this read is the first to need it.
 
         Columnar MVCC pipeline (tablet/mvcc.py): versioned chunk planes
         and store-ingested planes concatenate on device, one packed
@@ -463,7 +466,11 @@ class Tablet:
         version) memoize the materialized chunk per generation, so
         repeated selects skip the merge entirely until the next
         write/flush/compact."""
+        t_lock = time.perf_counter()
         with child_span("tablet.read_snapshot") as span, self._lock:
+            # The wait for the tablet's lock apart from the work under it.
+            span.add_tag("lock_wait_s",
+                         round(time.perf_counter() - t_lock, 6))
             generation = self._generation()
             latest = timestamp >= self._latest_ts_floor()
             if latest:
@@ -476,7 +483,7 @@ class Tablet:
                 _SNAP_MISSES.increment()
             span.add_tag("snapshot_cache",
                          "miss" if latest else "bypass")
-            chunk = self._read_snapshot_uncached(timestamp)
+            chunk = self._read_snapshot_uncached(timestamp, stats)
             span.add_tag("rows", chunk.row_count)
             if latest and tablet_config().snapshot_cache_enabled:
                 if self._snapshot_cache is not None:
@@ -509,7 +516,8 @@ class Tablet:
                         return cached[1], age
         return self.read_snapshot(timestamp), 0.0
 
-    def _read_snapshot_uncached(self, timestamp: int) -> ColumnarChunk:
+    def _read_snapshot_uncached(self, timestamp: int,
+                                stats=None) -> ColumnarChunk:
         total = sum(s.store_row_count for s in
                     [self.active_store] + self.passive_stores)
         for cid in self.chunk_ids:
@@ -530,7 +538,7 @@ class Tablet:
                     ColumnarChunk.from_rows(self.schema.to_unsorted(), []),
                     sorted_by=tuple(self.schema.key_column_names))
             return mvcc.visible_chunk(concat_chunks(sources), self.schema,
-                                      timestamp)
+                                      timestamp, stats=stats)
 
     def read_snapshot_reference(self,
                                 timestamp: int = MAX_TIMESTAMP
@@ -571,8 +579,11 @@ class Tablet:
         counters = _lookup_counters.counters(pool)
         counters["reads"].increment()
         counters["keys"].increment(len(keys))
+        t_lock = time.perf_counter()
         with child_span("tablet.lookup", keys=len(keys),
-                        chunks=len(self.chunk_ids)), self._lock:
+                        chunks=len(self.chunk_ids)) as span, self._lock:
+            span.add_tag("lock_wait_s",
+                         round(time.perf_counter() - t_lock, 6))
             key_names = self.schema.key_column_names
             out: list[Optional[dict]] = []
             if not normalized:

@@ -13,16 +13,14 @@ encoding is a plain dict injected into the RPC envelope.
 
 Span-site discipline (what keeps an untraced hot path ~free):
 
-  start_span(name)        child of the ambient context, or a SAMPLED
-                          fresh root (rate from config.TracingConfig) —
-                          legacy entry-point helper.
   child_span(name)        INTERIOR site: child of the ambient context,
                           NULL when there is none (or it is unsampled).
                           This is the probe threaded through the query/
                           operation planes; its disabled fast path is one
-                          contextvar read + a singleton return (≲1µs,
-                          asserted by `bench.py --config trace_overhead`,
-                          mirroring the failpoints fast-path assert).
+                          contextvar read + the `NULL_SPAN` singleton
+                          (`child_span(...) is NULL_SPAN`, asserted by
+                          tests/test_flight_recorder.py), which opens
+                          nothing: no record, no profiler annotation.
   start_query_span(name)  ENTRY point (gateway select/lookup, scheduler
                           operation, HTTP proxy): continues the ambient
                           trace when one exists, else roots a new trace
@@ -31,7 +29,17 @@ Span-site discipline (what keeps an untraced hot path ~free):
 
 The collector is a bounded ring with a CURSOR-based drain: the daemon's
 TraceExporter consumes each span once while `/traces`, `find()`, and the
-flight recorder keep serving from the retained tail.
+flight recorder keep serving from the retained tail; `dropped` says how
+many spans have left the ring, so a reader can tell a whole window from a
+tail.
+
+Two clocks per span: `start` (wall, for people) and `start_mono`
+(`time.perf_counter()`, for comparing with a caller's own timings).  A
+span's `self_time` is its duration less what its children covered.  Where
+`jax` is already loaded, a sampled span also opens a
+`jax.profiler.TraceAnnotation("yt." + name)`, so a profiler trace shows
+the program's spans on the device lines' clock; this module never imports
+`jax` itself (the RPC daemons must not load it through tracing).
 """
 
 from __future__ import annotations
@@ -40,8 +48,9 @@ import contextvars
 import itertools
 import os
 import random
-import threading
+import sys
 import time
+from collections import deque
 from typing import Any, Optional
 from ytsaurus_tpu.utils import sanitizers
 
@@ -70,6 +79,7 @@ _current: contextvars.ContextVar[Optional["TraceContext"]] = \
 # span site, same discipline as utils/failpoints._STATE).
 _ENABLED = True
 _SAMPLE_RATE = 1.0
+_RING_CAPACITY = 16384      # one Q1 window of the benchmark (PERF.md §3)
 
 
 def configure(config) -> None:
@@ -77,7 +87,7 @@ def configure(config) -> None:
     global _ENABLED, _SAMPLE_RATE
     if config is None:
         _ENABLED, _SAMPLE_RATE = True, 1.0
-        _collector.set_capacity(4096)
+        _collector.set_capacity(_RING_CAPACITY)
         return
     _ENABLED = bool(config.enabled)
     _SAMPLE_RATE = float(config.sample_rate)
@@ -92,7 +102,8 @@ class SpanRecord:
     """One finished span (exporter unit)."""
 
     __slots__ = ("trace_id", "span_id", "parent_span_id", "name", "start",
-                 "duration", "tags", "baggage", "seq")
+                 "start_mono", "duration", "self_time", "tags", "baggage",
+                 "seq")
 
     def __init__(self, ctx: "TraceContext", duration: float):
         self.trace_id = ctx.trace_id
@@ -100,7 +111,10 @@ class SpanRecord:
         self.parent_span_id = ctx.parent_span_id
         self.name = ctx.name
         self.start = ctx.start_time
+        self.start_mono = ctx._t0
         self.duration = duration
+        # Children on other threads (prefetch) may overlap: clamped.
+        self.self_time = max(duration - ctx._covered, 0.0)
         self.tags = dict(ctx.tags)
         self.baggage = dict(ctx.baggage)
         self.seq = 0                    # stamped by the collector
@@ -115,31 +129,36 @@ class SpanCollector:
     `drain()` hands each span to the exporter exactly once; the ring
     RETAINS everything up to `capacity` so `/traces` and `find()` keep
     serving after an export cycle (the pre-flight-recorder destructive
-    drain made a daemon's trace views go empty between scrapes)."""
+    drain made a daemon's trace views go empty between scrapes).
+    `dropped` counts the spans that have left the ring since the process
+    started: a reader of a window compares it before and after, or checks
+    that the oldest retained span precedes the window."""
 
-    def __init__(self, capacity: int = 4096):
+    def __init__(self, capacity: int = _RING_CAPACITY):
         self.capacity = capacity
-        # guards: _spans, _seq, _drained, _hists, capacity
+        # guards: _spans, _seq, _drained, _hists, capacity, dropped
         self._lock = sanitizers.register_lock(
             "tracing.SpanCollector._lock")
-        self._spans: list[SpanRecord] = []
+        self._spans: "deque[SpanRecord]" = deque(maxlen=capacity)
         self._seq = 0                  # spans ever added
         self._drained = 0              # seq consumed by drain()
+        self.dropped = 0               # spans evicted from the ring
         self._hists: dict[str, Any] = {}
 
     def set_capacity(self, capacity: int) -> None:
         with self._lock:
             self.capacity = max(int(capacity), 1)
-            if len(self._spans) > self.capacity:
-                del self._spans[:len(self._spans) - self.capacity]
+            if self._spans.maxlen != self.capacity:
+                self.dropped += max(len(self._spans) - self.capacity, 0)
+                self._spans = deque(self._spans, maxlen=self.capacity)
 
     def add(self, span: SpanRecord) -> None:
         with self._lock:
             self._seq += 1
             span.seq = self._seq
+            if len(self._spans) == self.capacity:
+                self.dropped += 1      # the append below evicts the oldest
             self._spans.append(span)
-            if len(self._spans) > self.capacity:
-                del self._spans[:len(self._spans) - self.capacity]
         self._record_duration(span)
 
     def _record_duration(self, span: SpanRecord) -> None:
@@ -184,12 +203,21 @@ def get_collector() -> SpanCollector:
     return _collector
 
 
+def _profiler_annotation(name: str):
+    """A `jax.profiler.TraceAnnotation` for a sampled span, where `jax` is
+    already loaded; else None.  Never imports `jax`: a process that has not
+    loaded it has no profiler to annotate for."""
+    jax = sys.modules.get("jax")
+    return None if jax is None else jax.profiler.TraceAnnotation("yt." + name)
+
+
 class TraceContext:
     """One span; use as a context manager to time + activate it."""
 
     def __init__(self, name: str, *, trace_id: Optional[str] = None,
                  parent_span_id: Optional[str] = None, sampled: bool = True,
-                 baggage: Optional[dict] = None):
+                 baggage: Optional[dict] = None,
+                 parent: "Optional[TraceContext]" = None):
         self.name = name
         self.trace_id = trace_id or _new_trace_id()
         self.span_id = _new_span_id()
@@ -199,13 +227,20 @@ class TraceContext:
         self.tags: dict[str, Any] = {}
         self.start_time = 0.0
         self._token = None
+        self._t0 = 0.0
+        # Self time: a finished child adds its duration to `_covered` of
+        # the in-process parent it was made from (none across the wire).
+        self._parent = parent
+        self._covered = 0.0
+        self._annotation = None
 
     # -- structure -------------------------------------------------------------
 
     def create_child(self, name: str) -> "TraceContext":
         return TraceContext(name, trace_id=self.trace_id,
                             parent_span_id=self.span_id,
-                            sampled=self.sampled, baggage=self.baggage)
+                            sampled=self.sampled, baggage=self.baggage,
+                            parent=self)
 
     def add_tag(self, key: str, value: Any) -> None:
         self.tags[key] = value
@@ -216,6 +251,10 @@ class TraceContext:
     # -- activation ------------------------------------------------------------
 
     def __enter__(self) -> "TraceContext":
+        if self.sampled:
+            self._annotation = _profiler_annotation(self.name)
+            if self._annotation is not None:
+                self._annotation.__enter__()
         self.start_time = time.time()
         self._t0 = time.perf_counter()
         self._token = _current.set(self)
@@ -224,9 +263,15 @@ class TraceContext:
     def __exit__(self, exc_type, exc, tb) -> bool:
         _current.reset(self._token)
         if self.sampled:
+            duration = time.perf_counter() - self._t0
+            if self._annotation is not None:
+                self._annotation.__exit__(exc_type, exc, tb)
+                self._annotation = None
+            if self._parent is not None:
+                self._parent._covered += duration
             if exc is not None and "error" not in self.tags:
                 self.tags["error"] = repr(exc)[:200]
-            _collector.add(SpanRecord(self, time.perf_counter() - self._t0))
+            _collector.add(SpanRecord(self, duration))
         return False
 
     # -- wire ------------------------------------------------------------------
@@ -290,23 +335,6 @@ def current_trace() -> Optional[TraceContext]:
     return _current.get()
 
 
-def start_span(name: str, **tags) -> "TraceContext | _NullSpan":
-    """Child of the ambient context, or a (sampled) fresh root."""
-    parent = _current.get()
-    if parent is not None:
-        if not parent.sampled:
-            return NULL_SPAN
-        ctx = parent.create_child(name)
-        ctx.tags.update(tags)
-        return ctx
-    if not _ENABLED or (_SAMPLE_RATE < 1.0 and
-                        random.random() >= _SAMPLE_RATE):
-        return NULL_SPAN
-    ctx = TraceContext(name)
-    ctx.tags.update(tags)
-    return ctx
-
-
 def child_span(name: str, **tags) -> "TraceContext | _NullSpan":
     """INTERIOR span site: records only under a live sampled trace.
     The no-trace fast path is one contextvar read + a singleton return."""
@@ -333,7 +361,8 @@ def start_query_span(name: str, force: bool = False,
             return NULL_SPAN
         ctx = TraceContext(name, trace_id=parent.trace_id,
                            parent_span_id=parent.span_id,
-                           sampled=True, baggage=parent.baggage)
+                           sampled=True, baggage=parent.baggage,
+                           parent=parent)
         ctx.tags.update(tags)
         return ctx
     if not force and (not _ENABLED or (_SAMPLE_RATE < 1.0 and
