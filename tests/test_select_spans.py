@@ -117,8 +117,8 @@ def test_dropped_counts_spans_that_left_the_ring():
 
 def test_default_ring_holds_a_benchmark_window():
     from ytsaurus_tpu import config as yt_config
-    assert yt_config.TracingConfig().ring_capacity == 16384
-    assert SpanCollector().capacity == 16384
+    assert yt_config.TracingConfig().ring_capacity == 65536
+    assert SpanCollector().capacity == 65536
 
 
 # -- the profiler annotation --------------------------------------------------
@@ -235,7 +235,8 @@ def test_select_rows_leaves_the_span_table(static_client):
                            key=lambda s: s.start_mono)
     decode = spans["query.decode"][0]
     assert first.start_mono < decode.start_mono < second.start_mono
-    assert decode.tags == {"rows": 3, "columns": 2}
+    assert decode.tags == {"rows": 3, "columns": 2, "fetch": "whole",
+                           "bytes": 2 * 128 * (8 + 1)}
     # the evaluator's three parts, the sync included, under run_plan
     run_plan = spans["evaluator.run_plan"][0]
     assert parent(run_plan) == "coordinator.shard"
@@ -254,6 +255,46 @@ def test_select_rows_leaves_the_span_table(static_client):
         pytest.approx(root.duration, rel=1e-6)
     # the execution is no shorter in the tree than in the statistics
     assert run_plan.duration >= profile.execute_time
+
+
+def test_decode_span_says_what_crossed(static_client):
+    """`query.decode` carries `fetch` and `bytes` (ISSUE 28): a small
+    result crosses whole, every output plane in it, and EXPLAIN ANALYZE's
+    tree shows both."""
+    profile = static_client.select_rows(
+        "k, v FROM [//s/t] WHERE k = 1 LIMIT 40", explain_analyze=True)
+    assert len(profile.rows) == 33
+    (decode,) = _by_name(profile.trace_id)["query.decode"]
+    assert decode.tags["rows"] == 33 and decode.tags["columns"] == 2
+    assert decode.tags["fetch"] == "whole"
+    # two columns, a data and a validity plane each, of one capacity
+    assert decode.tags["bytes"] % (2 * (8 + 1)) == 0
+    assert decode.tags["bytes"] >= 33 * 2 * (8 + 1)
+    from ytsaurus_tpu.query.profile import format_span_tree
+    (line,) = [l for l in format_span_tree(span_tree(profile.trace_id))
+               if "query.decode" in l]
+    assert "fetch=whole" in line and f"bytes={decode.tags['bytes']}" in line
+
+
+def test_default_ring_keeps_2600_selects(static_client):
+    """A Q1 window of the benchmark at half the call time: 2,600 selects
+    of 12 spans each stay in a ring of the default capacity, none
+    dropped (at 16,384 the window's first 1,235 selects would be)."""
+    static_client.select_rows(QUERY)              # compile
+    ring = SpanCollector()
+    real = tracing._collector
+    tracing._collector = ring
+    try:
+        for _ in range(2600):
+            static_client.select_rows(QUERY)
+    finally:
+        tracing._collector = real
+    spans = ring.snapshot()
+    assert ring.dropped == 0
+    assert len(spans) == 2600 * sum(SELECT_SPANS.values()) <= ring.capacity
+    assert len({s.trace_id for s in spans}) == 2600
+    assert sum(s.name == "query.decode" and s.tags["fetch"] == "whole"
+               for s in spans) == 2600
 
 
 def test_cold_select_nests_the_compile(static_client):

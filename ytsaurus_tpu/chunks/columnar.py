@@ -115,10 +115,27 @@ class Column:
     def capacity(self) -> int:
         return int(self.data.shape[0])
 
-    def decode(self, row_count: int) -> list:
-        """Materialize host values for the first `row_count` rows."""
-        data = np.asarray(self.data[:row_count])
-        valid = np.asarray(self.valid[:row_count])
+    def fetched_planes(self) -> tuple:
+        """The device planes `decode` reads, validity first: none of a
+        `null` column, no placeholder data plane of an `any` column."""
+        if self.type is EValueType.null:
+            return ()
+        if self.type is EValueType.any:
+            return (self.valid,)
+        return (self.valid, self.data)
+
+    def decode(self, row_count: int,
+               host: Optional[Sequence[np.ndarray]] = None) -> list:
+        """Materialize host values for the first `row_count` rows.
+        `host` is `fetched_planes()` already on the host (a chunk
+        fetches all its columns' at once); without it the column
+        fetches its own."""
+        if host is None:
+            host = fetch_prefix(self.fetched_planes(), row_count)[0]
+        if self.type is EValueType.null:
+            return [None] * row_count
+        valid, *rest = host
+        data = rest[0] if rest else None
         out: list = []
         for i in range(row_count):
             if not valid[i]:
@@ -133,11 +150,42 @@ class Column:
                 out.append(bool(data[i]))
             elif self.type is EValueType.double:
                 out.append(float(data[i]))
-            elif self.type is EValueType.null:
-                out.append(None)
             else:
                 out.append(int(data[i]))
         return out
+
+
+# Planes that total at most this cross to the host whole, with no program
+# dispatched; above it the live prefix is cut on the device first.  From a
+# reading on one v5e (PERF.md §6, PR 28): twenty planes cross whole in
+# 1.8 ms at 11 KB, 2.4 ms at 1.5 MB and 2.9 ms at 2.9 MB, where the cut to
+# a 128-row bucket and its fetch take 3.0 ms whatever the planes' size.
+WHOLE_FETCH_BYTES = 2 << 20
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _cut_planes(planes: tuple, bucket: int) -> tuple:
+    return tuple(plane[:bucket] for plane in planes)
+
+
+def fetch_prefix(planes: Sequence[jax.Array], row_count: int
+                 ) -> tuple[list[np.ndarray], str, int]:
+    """The first `row_count` rows of every plane, on the host, by ONE
+    device-to-host fetch (`jax.device_get` starts every copy before it
+    waits on any).  Returns (host planes, "whole" | "prefix", bytes that
+    crossed).  Small planes cross whole and are cut in numpy; large ones
+    are cut on the device to `pad_capacity(row_count)` (a bucket: few
+    shapes) by one program over all of them, so a LIMIT 10 out of a
+    million-slot plane never moves the plane."""
+    bucket = pad_capacity(row_count)
+    fetch = "whole"
+    if sum(plane.nbytes for plane in planes) > WHOLE_FETCH_BYTES and \
+            any(plane.shape[0] > bucket for plane in planes):
+        fetch = "prefix"
+        planes = _cut_planes(tuple(planes), bucket)
+    host = jax.device_get(list(planes))
+    return ([plane[:row_count] for plane in host], fetch,
+            sum(plane.nbytes for plane in host))
 
 
 @dataclass(frozen=True)
@@ -325,19 +373,32 @@ class ColumnarChunk:
 
     # --- materialization ------------------------------------------------------
 
-    def to_rows(self) -> list[dict[str, Any]]:
-        decoded = {name: col.decode(self.row_count)
-                   for name, col in self.columns.items()}
-        names = self.schema.column_names
-        return [
-            {name: decoded[name][i] for name in names}
-            for i in range(self.row_count)
-        ]
+    def _decode_columns(self, tag=None) -> list[list]:
+        """Host values of every schema column, in schema order, after
+        ONE fetch of all their planes."""
+        cols = [self.columns[name] for name in self.schema.column_names]
+        per_col = [col.fetched_planes() for col in cols]
+        host, fetch, nbytes = fetch_prefix(
+            [plane for planes in per_col for plane in planes],
+            self.row_count)
+        if tag is not None:
+            tag("fetch", fetch)
+            tag("bytes", nbytes)
+        host = iter(host)
+        return [col.decode(self.row_count, [next(host) for _ in planes])
+                for col, planes in zip(cols, per_col)]
 
-    def to_tuples(self) -> list[tuple]:
-        decoded = [self.columns[name].decode(self.row_count)
-                   for name in self.schema.column_names]
-        return [tuple(col[i] for col in decoded) for i in range(self.row_count)]
+    def to_rows(self, tag=None) -> list[dict[str, Any]]:
+        """The rows as dicts.  `tag(key, value)`, where given, is told
+        what crossed to the host: `fetch` ("whole" | "prefix") and
+        `bytes` (a span's `add_tag`)."""
+        names = self.schema.column_names
+        return [dict(zip(names, row)) for row in self.to_tuples(tag)]
+
+    def to_tuples(self, tag=None) -> list[tuple]:
+        decoded = self._decode_columns(tag)
+        return [tuple(col[i] for col in decoded)
+                for i in range(self.row_count)]
 
     # --- transforms -----------------------------------------------------------
 

@@ -1532,8 +1532,8 @@ class YtClient:
             log_event(get_logger("Query"), _logging.INFO, "select_rows",
                       query=query[:200], **stats.to_dict())
         with child_span("query.decode", rows=out.row_count,
-                        columns=len(out.columns)):
-            return out.to_rows()
+                        columns=len(out.columns)) as span:
+            return out.to_rows(tag=span.add_tag)
 
     def _indexed_source_chunks(self, plan, intervals, timestamp):
         """Serve the scan from a secondary index when one applies (WHERE

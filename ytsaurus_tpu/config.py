@@ -317,8 +317,10 @@ class TracingConfig(YsonStruct):
       are ALWAYS retained in the flight recorder's slow-query log;
       faster queries are retained at `sample_rate`.
     - `slow_log_capacity` / `recent_log_capacity`: bounded profile logs.
-    - `ring_capacity`: finished-span ring buffer size (bounded memory;
-      the default holds one window of the benchmark's Q1 cell, PERF.md).
+    - `ring_capacity`: finished-span ring buffer size (bounded memory:
+      34.5 MB of host memory full, at 527 B a span; the default holds a
+      51 s window of the benchmark's Q1 cell, 12 spans a select, down
+      to a 9.4 ms call, PERF.md §7).
     """
 
     enabled = param(True, type=bool)
@@ -326,7 +328,7 @@ class TracingConfig(YsonStruct):
     slow_query_threshold = param(0.5, type=float, ge=0.0)
     slow_log_capacity = param(128, type=int, ge=1)
     recent_log_capacity = param(128, type=int, ge=1)
-    ring_capacity = param(16384, type=int, ge=1)
+    ring_capacity = param(65536, type=int, ge=1)
 
 
 _TRACING_CONFIG: "Optional[TracingConfig]" = None

@@ -79,7 +79,8 @@ _current: contextvars.ContextVar[Optional["TraceContext"]] = \
 # span site, same discipline as utils/failpoints._STATE).
 _ENABLED = True
 _SAMPLE_RATE = 1.0
-_RING_CAPACITY = 16384      # one Q1 window of the benchmark (PERF.md §3)
+_RING_CAPACITY = 65536      # a Q1 window of the benchmark down to a 9.4 ms
+                            # call (PERF.md §7; config.TracingConfig)
 
 
 def configure(config) -> None:
