@@ -190,6 +190,35 @@ def test_dynamic_table_mvcc_program(one_chip, as_on_chip):
     compile_mvcc_visible(1_000_000, one_chip)
 
 
+def test_join_phase_programs(one_chip, as_on_chip):
+    """`execute_join`'s two device programs (phase 1: network sort of the
+    foreign keys, two searches, count; phase 2: expand) at 16,384 lines /
+    4,096 orders.  At the benchmark cell's capacities (1,048,576 /
+    262,144) the network sort's compile alone is minutes: PERF.md holds
+    those seconds, no test does."""
+    from test_tpch_join_deployment import join_phase_programs
+    from ytsaurus_tpu.chunks.columnar import ColumnarChunk
+    from ytsaurus_tpu.query.builder import build_query
+    from ytsaurus_tpu.schema import TableSchema
+    l_schema = TableSchema.make([("l_orderkey", "int64"),
+                                 ("l_quantity", "int64")])
+    o_schema = TableSchema.make([("o_orderkey", "int64", "ascending"),
+                                 ("o_shippriority", "int64")])
+    lines, orders = np.arange(16_384), np.arange(4_096)
+    probe = ColumnarChunk.from_arrays(
+        l_schema, {"l_orderkey": lines % 4_096, "l_quantity": lines})
+    foreign = ColumnarChunk.from_arrays(
+        o_schema, {"o_orderkey": orders, "o_shippriority": orders})
+    plan = build_query(
+        "sum(l_quantity + o_shippriority) AS s FROM [//l] JOIN [//o] "
+        "ON l_orderkey = o_orderkey GROUP BY 1",
+        {"//l": l_schema, "//o": o_schema})
+    phase1, args1, phase2, args2 = join_phase_programs(
+        plan.joins[0], probe, foreign)
+    compile_for(phase1, args1, one_chip)
+    compile_for(phase2, args2, one_chip)
+
+
 def _segment_end_reference(starts):
     n = len(starts)
     out = np.empty(n, dtype=np.int64)

@@ -3,7 +3,9 @@
 Data generators (seeded, numpy) + query text for the BASELINE.md configs:
   Q1  — scan + filter + 8-aggregate GROUP BY over lineitem
   Q3  — two-table join + GROUP BY (customer/orders condensed into dims)
-These drive bench.py and the graft entry.
+These drive bench.py and the graft entry; the chip-measured join deployment
+is benchmark/configs/tpch-orders-lineitem.json (dbgen-shaped ORDERS +
+LINEITEM, TPC-H Q12; cell tpch_q12_join).
 """
 
 from __future__ import annotations

@@ -784,11 +784,22 @@ class Evaluator:
                         f"No data provided for join table {join.foreign_table!r}",
                         code=EErrorCode.QueryExecutionError)
                 namespace = _extend_namespace(namespace, join)
-                current = execute_join(
-                    current, TableSchema.make(namespace), join,
-                    foreign_chunks[join.foreign_table], self._join_cache)
+                foreign = foreign_chunks[join.foreign_table]
+                # One span per join stage: key bind, phase 1's dispatch
+                # and wait (its child `join.count_sync`), phase 2's
+                # dispatch; phase 2 runs on under `evaluator.sync`.
+                t_join = _time.perf_counter()
+                with child_span("evaluator.join", table=join.foreign_table,
+                                self_rows=current.row_count,
+                                foreign_rows=foreign.row_count) as join_span:
+                    current = execute_join(
+                        current, TableSchema.make(namespace), join, foreign,
+                        self._join_cache, stats=stats, span=join_span)
+                    join_span.add_tag("out_rows", current.row_count)
                 if stats is not None:
                     stats.joins_executed += 1
+                    stats.join_time += _time.perf_counter() - t_join
+                    stats.join_rows_out += current.row_count
                     stats.note_join_stage(
                         pos, join.foreign_table, "local",
                         est_rows=decisions[pos].est_out

@@ -14,6 +14,7 @@ them.
 
 from __future__ import annotations
 
+import time
 from dataclasses import replace
 
 import jax
@@ -35,6 +36,7 @@ from ytsaurus_tpu.query.engine.expr import (
     _vocab_bucket,
 )
 from ytsaurus_tpu.schema import TableSchema
+from ytsaurus_tpu.utils.tracing import NULL_SPAN, child_span
 
 
 def _bind_keys(chunk: ColumnarChunk, schema: TableSchema,
@@ -171,12 +173,15 @@ def _join_fingerprint(join: ir.JoinClause) -> str:
 
 def execute_join(chunk: ColumnarChunk, combined_schema: TableSchema,
                  join: ir.JoinClause, foreign_chunk: ColumnarChunk,
-                 cache: dict) -> ColumnarChunk:
+                 cache: dict, stats=None, span=NULL_SPAN) -> ColumnarChunk:
     """Materialize `chunk ⋈ foreign_chunk` into a wider columnar chunk.
 
     `combined_schema` is the namespace *after* this join (flat names);
     `cache` holds the compiled phase programs (owned by the Evaluator so
-    lifetime/clearing follow the plan cache).
+    lifetime/clearing follow the plan cache).  `stats` (a
+    QueryStatistics) counts the host sync between the phases and its
+    seconds; `span` (the caller's `evaluator.join`) is tagged with how
+    the program lookup ended and with the output capacity.
     """
     self_schema = chunk.schema
     all_bindings: list = []
@@ -230,6 +235,7 @@ def execute_join(chunk: ColumnarChunk, combined_schema: TableSchema,
                  tuple(bind_structure),
                  tuple((tuple(b.shape), str(b.dtype)) for b in all_bindings))
     entry = cache.get(cache_key)
+    span.add_tag("cache", "miss" if entry is None else "hit")
     if entry is None:
         entry = _build_join_programs(
             self_bound, f_bound, self_slots, foreign_slots,
@@ -249,8 +255,17 @@ def execute_join(chunk: ColumnarChunk, combined_schema: TableSchema,
             foreign_chunk.row_valid, tuple(all_bindings),
             jnp.asarray(n_foreign, dtype=jnp.int64))
     lo, counts, f_order, total = phase1(*args)
-    total = int(total)
+    # The one host sync between the phases: the match count sizes phase
+    # 2's static output.  The wait is phase 1's device time as the host
+    # sees it.
+    t_sync = time.perf_counter()
+    with child_span("join.count_sync"):
+        total = int(total)
+    if stats is not None:
+        stats.join_host_syncs += 1
+        stats.join_sync_time += time.perf_counter() - t_sync
     out_cap = pad_capacity(max(total, 1))
+    span.add_tag("out_capacity", out_cap)
     phase2 = make_phase2(out_cap)
     out_planes, self_row, foreign_row = phase2(*args, lo, counts, f_order)
 
@@ -301,11 +316,13 @@ def _build_join_programs(self_bound, f_bound, self_slots, foreign_slots,
         self_keys = _emit_encoded_keys(self_bound, self_slots, s_ctx)
         foreign_keys = _emit_encoded_keys(f_bound, foreign_slots, f_ctx)
         # Sort foreign side (first key most significant; masked rows last).
-        f_order, f_sorted = sort_foreign_keys(foreign_keys, f_valid)
-        lo = _lex_searchsorted(f_sorted, n_foreign, foreign_cap, self_keys,
-                               "left")
-        hi = _lex_searchsorted(f_sorted, n_foreign, foreign_cap, self_keys,
-                               "right")
+        with jax.named_scope("ql.join.sort"):
+            f_order, f_sorted = sort_foreign_keys(foreign_keys, f_valid)
+        with jax.named_scope("ql.join.probe"):
+            lo = _lex_searchsorted(f_sorted, n_foreign, foreign_cap,
+                                   self_keys, "left")
+            hi = _lex_searchsorted(f_sorted, n_foreign, foreign_cap,
+                                   self_keys, "right")
         s_null = null_key_mask(self_keys)
         counts = jnp.where(s_valid & ~s_null, hi - lo, 0)
         if is_left:
@@ -322,6 +339,7 @@ def _build_join_programs(self_bound, f_bound, self_slots, foreign_slots,
         if fn is not None:
             return fn
 
+        @jax.named_scope("ql.join.expand")
         def phase2(self_columns, foreign_columns, s_valid, f_valid, bindings,
                    n_foreign, lo, counts, f_order):
             if is_left:

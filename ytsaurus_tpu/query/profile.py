@@ -163,7 +163,12 @@ def format_profile_dict(p: dict) -> str:
     join_stages = [e for e in (stats.get("join_plan") or []) if e]
     if join_stages:
         from ytsaurus_tpu.query.planner import est_drift
-        lines.append("join plan:")
+        lines.append(
+            f"join plan: ({stats.get('join_time', 0.0) * 1e3:.3f} ms in "
+            f"the cascade, {stats.get('join_sync_time', 0.0) * 1e3:.3f} ms "
+            f"of it in {stats.get('join_host_syncs', 0)} host syncs "
+            f"between phases, {stats.get('join_rows_out', 0)} rows "
+            f"materialized)")
         for i, entry in enumerate(join_stages):
             drift = est_drift(entry.get("est_rows", 0),
                               entry.get("actual_rows", 0))

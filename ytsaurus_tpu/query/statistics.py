@@ -33,6 +33,19 @@ class QueryStatistics:
     shards_staged: int = 0           # shards actually fetched/decoded
     retries: int = 0                 # transient per-shard retry attempts
     joins_executed: int = 0
+    # What the host-coordinated join cascade costs (counted by
+    # evaluator._dispatch_traced and joins.execute_join, host clock):
+    # join_time is the seconds inside the cascade's execute_join calls
+    # (key bind, phase 1's dispatch and wait, phase 2's dispatch; phase
+    # 2 itself runs on under the main program's sync); join_sync_time
+    # the part spent in the blocking read of the match count between
+    # the phases (phase 1's device time as the host sees it), one read
+    # per stage (join_host_syncs); join_rows_out the rows the stages
+    # materialized for the main program to scan.
+    join_time: float = 0.0
+    join_sync_time: float = 0.0
+    join_host_syncs: int = 0
+    join_rows_out: int = 0
     # Whole-plan SPMD execution (ISSUE 12): 1 when the query was served
     # by the fused one-program rung (parallel/whole_plan.py); retries
     # count exchange-quota overflow re-runs (each a fresh pow2 rung of
