@@ -8,9 +8,10 @@ shape, because stable_argsort_u32 dispatches between them by size.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
-from ytsaurus_tpu.ops.radix import radix_argsort_u32
+from ytsaurus_tpu.ops.radix import radix_argsort_u32, radix_pass
 from ytsaurus_tpu.ops.segments import (
     hash_group_order,
     pack_key_planes_bits,
@@ -23,6 +24,94 @@ from ytsaurus_tpu.ops.segments import (
 def _np_stable_argsort(words):
     # np.lexsort takes minor key FIRST; words are major-first.
     return np.lexsort(tuple(np.asarray(w) for w in reversed(words)))
+
+
+_DIGIT_KINDS = ("uniform", "one_value", "ends", "sorted", "sparse",
+                "one_bin_heavy")
+
+
+def _digits(kind, n, rng):
+    if kind == "uniform":
+        d = rng.integers(0, 256, n)
+    elif kind == "one_value":
+        d = np.full(n, 77)
+    elif kind == "ends":                     # only 0 and 255
+        d = rng.choice([0, 255], n)
+    elif kind == "sorted":
+        d = np.sort(rng.integers(0, 256, n))
+    elif kind == "sparse":                   # 200 of the 256 bins empty
+        d = rng.choice(rng.permutation(256)[:56], n)
+    else:       # one bin holds every row of all tiles but the last one
+        d = np.full(n, 5)
+        tail = min(n, 2048)
+        d[n - tail:] = rng.integers(0, 256, tail)
+    return d.astype(np.uint32)
+
+
+def _pass_cases():
+    cases = []
+    for n in (8, 2048, 4096):                # nt = 1, 1, 2
+        for i, kind in enumerate(_DIGIT_KINDS):
+            cases.append((n, kind, 1 + 2 * (i % 2), "gather"))
+    for kind in ("uniform", "sparse", "one_bin_heavy"):
+        cases.append((262_144, kind, 3, "gather"))
+    for i, kind in enumerate(_DIGIT_KINDS):
+        cases.append((4096, kind, 3 - 2 * (i % 2), "scatter"))
+    cases += [(8, "uniform", 1, "scatter"), (2048, "sparse", 3, "scatter"),
+              (262_144, "uniform", 1, "scatter")]
+    return cases
+
+
+@pytest.mark.parametrize("n,kind,planes,engine", _pass_cases())
+def test_radix_pass_matches_numpy_stable(n, kind, planes, engine):
+    """One byte pass is a stable ascending partition by digit: every
+    payload plane comes back in np.argsort(kind="stable") order."""
+    rng = np.random.default_rng(n + planes)
+    digit = _digits(kind, n, rng)
+    payloads = [np.arange(n, dtype=np.uint32),
+                rng.integers(-1 << 62, 1 << 62, n, dtype=np.int64),
+                rng.random(n)][:planes]
+    got = jax.jit(radix_pass, static_argnames="engine")(
+        jnp.asarray(digit), [jnp.asarray(p) for p in payloads],
+        engine=engine)
+    order = np.argsort(digit, kind="stable")
+    assert len(got) == planes
+    for plane, out in zip(payloads, got):
+        assert out.dtype == plane.dtype
+        np.testing.assert_array_equal(np.asarray(out), plane[order])
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations carry."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_radix_pass_has_no_per_slot_search(monkeypatch, backend):
+    """The default pass finds every row's place from run marks and a
+    prefix sum: traced at 1,048,576 rows (nothing executes), it carries no
+    loop over row-sized state and touches row-sized indices ONCE per
+    payload plane, to move it (a search would bring back a row-sized
+    gather per step).  `backend` picks prefix_scan's form (the CPU's
+    associative scan, the chip's shifted one)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    n = 1 << 20
+    plane = jax.ShapeDtypeStruct((n,), jnp.uint32)
+    jaxpr = jax.make_jaxpr(lambda d, p: radix_pass(d, [p]))(plane, plane)
+    row_loops, row_moves = [], 0
+    for eqn in _eqns(jaxpr.jaxpr):
+        name = eqn.primitive.name
+        if name in ("while", "scan"):        # fori_loop is either
+            row_loops += [v.aval.shape for v in eqn.outvars
+                          if v.aval.size >= n]
+        elif name == "gather" or name.startswith("scatter"):
+            indices = eqn.invars[1].aval
+            row_moves += int(np.prod(indices.shape[:-1])) >= n
+    assert not row_loops
+    assert row_moves == 1
 
 
 @pytest.mark.parametrize("n", [1, 2, 5, 100, 2048, 2049, 5000, 100_000])

@@ -11,16 +11,25 @@ histogram arithmetic:
     1. batched per-tile stable sort by (digit, position) — ONE u32
        composite key, network depth log^2(TILE) not log^2(n), vectorized
        across tiles on the VPU;
-    2. per-tile bin offsets via batched searchsorted over the sorted
-       digits (a (tiles, 256) table — tiny);
-    3. global stable rank for every output slot from exclusive cumsums of
-       that table, inverted with a vectorized binary search (log(tiles)
-       gather sweeps over the cumulative table);
-    4. one contiguous-run gather moves the payload planes.
+    2. per-tile digit counts as a small matrix product per tile (a
+       (tiles, 256) table — tiny), and from its exclusive sums where
+       every (tile, bin) run of the tile-sorted rows starts and where in
+       the output it goes;
+    3. the tile-sorted rows are those tiles x 256 runs laid end to end,
+       and inside a run (output slot - row) is one constant: each run's
+       first row is marked with the change of that constant (one
+       scatter-add of tiles x 256 sorted indices, an eighth of n at the
+       default tile) and a prefix sum of the marks gives every row its
+       output slot;
+    4. one unique-index scatter per payload plane moves the rows (a
+       permutation: no duplicate index, which is what serializes a TPU
+       scatter; on the v5e it costs half a row-sized gather).
 
-No data-dependent shapes, no giant network, no scatter (TPU scatters with
-duplicate indices serialize; the one permutation scatter variant is kept
-behind engine="scatter" for measurement, using unique_indices=True).
+No data-dependent shapes, no giant network, and no per-row search: a
+search costs a row-sized gather per step, and row-sized gathers were
+what a pass was made of (PERF.md section 6, PR 31).  engine="scatter" is
+the older way to the same scatter (two row-sized gathers from the
+tables), kept for measurement.
 
 Reference analog: the Sort operation's partition tree + k-way heap merge
 (yt/yt/server/controller_agent/controllers/sort_controller.cpp:459,
@@ -37,6 +46,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ytsaurus_tpu.ops.segments import prefix_scan
+
 # Tile size for the per-tile sort networks: the composite key is
 # (digit << LOG_TILE) | position, so RADIX_BITS + LOG_TILE must be <= 32.
 RADIX_TILE = int(os.environ.get("YT_TPU_RADIX_TILE", 2048))
@@ -45,7 +56,24 @@ _B = 1 << RADIX_BITS
 
 
 def _exclusive(x, axis):
-    return jnp.cumsum(x, axis=axis) - x
+    return jnp.cumsum(x, axis=axis, dtype=jnp.int32) - x
+
+
+def _tile_counts(d_sorted):
+    """counts[t, b] = rows of digit b in tile t, (nt, 256) int32, as one
+    small matrix product per tile: with b = 16 * hi + lo it is
+    onehot(hi)^T . onehot(lo), (16 x tile) . (tile x 16).  The 0/1 factors
+    are exact in bf16 and the sums in f32 (a tile holds < 2**24 rows).  No
+    search and no (nt, tile, 256) plane: a search over the sorted digits
+    costs a table-sized gather per step (16 ms of a 26-ms pass at 512
+    tiles, PERF.md section 6), and the plain compare-and-sum against all
+    256 bins is left unfused by the CPU backend."""
+    nibble = jnp.arange(16, dtype=jnp.int32)[None, :, None]
+    hi = ((d_sorted >> 4)[:, None, :] == nibble).astype(jnp.bfloat16)
+    lo = ((d_sorted & 15)[:, None, :] == nibble).astype(jnp.bfloat16)
+    counts = jnp.einsum("tha,tla->thl", hi, lo,
+                        preferred_element_type=jnp.float32)
+    return counts.astype(jnp.int32).reshape(d_sorted.shape[0], _B)
 
 
 @jax.named_scope("radix.pass")       # the name its ops carry in a trace
@@ -75,51 +103,41 @@ def radix_pass(digit: jax.Array, payloads: list[jax.Array],
     d_sorted = (sorted_ops[0] >> np.uint32(log_tile)).astype(jnp.int32)
     pay_sorted = [p.reshape(n) for p in sorted_ops[1:]]
 
+    counts = _tile_counts(d_sorted)                             # (nt, B)
     # local_start[t, b] = first position of digit b inside tile t.
-    bins = jnp.arange(_B, dtype=jnp.int32)
-    local_start = jax.vmap(
-        lambda row: jnp.searchsorted(row, bins, side="left"))(d_sorted)
-    local_start = local_start.astype(jnp.int32)                 # (nt, B)
-    ends = jnp.concatenate(
-        [local_start[:, 1:], jnp.full((nt, 1), tile, jnp.int32)], axis=1)
-    counts = ends - local_start                                 # (nt, B)
-
-    per_bin = counts.sum(axis=0)                                # (B,)
+    local_start = _exclusive(counts, 1)                         # (nt, B)
+    per_bin = counts.sum(axis=0, dtype=jnp.int32)               # (B,)
     bin_start = _exclusive(per_bin, 0)                          # (B,)
     tile_excl = _exclusive(counts, 0)                           # (nt, B)
+    # dest of tile t's bin-b run = bin_start[b] + rows of b in earlier
+    # tiles; every element's destination is unique (a permutation).
+    run_start = bin_start[None, :] + tile_excl                  # (nt, B)
 
     if engine == "scatter":
-        # dest of tile t's bin-b run = bin_start[b] + rows of b in earlier
-        # tiles; every element's destination is unique (a permutation).
-        run_start = bin_start[None, :] + tile_excl              # (nt, B)
         rs = jnp.take_along_axis(run_start, d_sorted, axis=1)
         ls = jnp.take_along_axis(local_start, d_sorted, axis=1)
         dest = (rs + (pos[None, :].astype(jnp.int32) - ls)).reshape(n)
-        return [jnp.zeros(n, p.dtype).at[dest].set(
-                    p, unique_indices=True, mode="drop")
-                for p in pay_sorted]
-
-    # engine == "gather": invert the permutation by rank arithmetic.
-    # For output slot j: which bin, which tile, which local row?
-    j = jnp.arange(n, dtype=jnp.int32)
-    b = jnp.clip(jnp.searchsorted(bin_start, j, side="right") - 1, 0,
-                 _B - 1).astype(jnp.int32)
-    k = j - bin_start[b]                       # rank of j within its bin
-    # Vectorized binary search over the per-bin inclusive tile cumsums:
-    # t(j) = first tile whose inclusive count exceeds k.
-    ccounts = (tile_excl + counts).T.reshape(-1)     # (B*nt,) row-major b
-    lo = jnp.zeros(n, jnp.int32)
-    hi = jnp.full(n, nt, jnp.int32)
-    for _ in range(max(nt.bit_length(), 1)):
-        mid = (lo + hi) >> 1
-        go_right = ccounts[b * nt + jnp.minimum(mid, nt - 1)] <= k
-        lo = jnp.where(go_right, mid + 1, lo)
-        hi = jnp.where(go_right, hi, mid)
-    t = jnp.clip(lo, 0, nt - 1)
-    prev = jnp.where(t > 0, ccounts[b * nt + jnp.maximum(t - 1, 0)], 0)
-    r = k - prev                               # rank within tile t's run
-    src = t * tile + local_start.reshape(-1)[t * _B + b] + r
-    return [p[src] for p in pay_sorted]
+    else:
+        # engine == "gather" (the name is older than the form).  The
+        # tile-sorted rows are the nt x 256 runs in (tile, bin) order; run
+        # (t, b) starts at row src0 and goes to slots run_start[t, b]
+        # onward, so inside a run dest - row is the constant run_start -
+        # src0.  Mark each run's first row with the CHANGE of that
+        # constant and prefix-sum the marks: an empty run shares its row
+        # with the run after it and their changes add up, so the sum at a
+        # row telescopes to the constant of the run that holds it.  No
+        # per-row search, no row-sized gather.  Every plane is held to
+        # int32 (the package enables x64); wrap-around is harmless.
+        src0 = (jnp.arange(nt, dtype=jnp.int32)[:, None] * np.int32(tile)
+                + local_start)                                  # (nt, B)
+        jump = jnp.diff((run_start - src0).reshape(-1), prepend=0)
+        # Runs that start past the last row (src0 == n) are empty: dropped.
+        marks = jnp.zeros(n, jnp.int32).at[src0.reshape(-1)].add(
+            jump, indices_are_sorted=True, mode="drop")
+        dest = jnp.arange(n, dtype=jnp.int32) + prefix_scan(jnp.add, marks)
+    return [jnp.zeros(n, p.dtype).at[dest].set(
+                p, unique_indices=True, mode="drop")
+            for p in pay_sorted]
 
 
 def _pad_to_tile(x: jax.Array, n_pad: int, fill) -> jax.Array:
@@ -136,7 +154,8 @@ def radix_argsort_u32(words: list[jax.Array],
     word k (higher bits must be zero) — digit passes above the bound are
     skipped, so a packed 12-bit key costs 2 byte passes, not 4.
 
-    engine: "gather" | "scatter" (ops above).
+    engine: "gather" | "scatter" (ops above; both move rows by one
+    scatter, the names are older than that).
 
     Pad rows (to the tile multiple) carry all-ones keys and sort last;
     ties against real all-ones rows resolve to the real rows first by
