@@ -76,6 +76,41 @@ def test_warm_start_across_evaluators(tmp_path):
     assert snap["hits"] == 1 and snap["errors"] == 0
 
 
+def test_cluster_store_serves_a_joining_evaluator_without_a_fresh_compile(
+        tmp_path):
+    """The cluster rung (memory -> disk -> CLUSTER -> compile): what one
+    evaluator compiled and published, a fresh one (empty memory, no disk
+    tier — a replica that joins hot) fetches: every program it loads is
+    a cluster hit, none a fresh compile."""
+    from ytsaurus_tpu.chunks.store import FsChunkStore
+    from ytsaurus_tpu.query.engine import aot_cache
+    from ytsaurus_tpu.query.engine.evaluator import Evaluator
+    from ytsaurus_tpu.query.statistics import QueryStatistics
+    store = aot_cache.ClusterArtifactStore(
+        FsChunkStore(str(tmp_path / "artifacts")))
+    aot_cache.set_cluster_store(store)
+    try:
+        schema, chunk = _inputs()
+        queries = ["k FROM [//t] WHERE v < 10",
+                   "k, sum(v) AS s FROM [//t] GROUP BY k"]
+        first = QueryStatistics()
+        for q in queries:
+            Evaluator().run_plan(_plan(q, schema), chunk, stats=first)
+        assert first.compile_count == 2 and first.compile_cluster_hit == 0
+        assert store.snapshot()["publishes"] == 2
+        joined = QueryStatistics()
+        joiner = Evaluator()
+        outs = [joiner.run_plan(_plan(q, schema), chunk, stats=joined)
+                for q in queries]
+        assert [r["k"] for r in outs[0].to_rows()] == [0, 1, 2, 3, 4]
+        assert joined.compile_count == 2
+        assert joined.compile_count - joined.compile_cluster_hit == 0, \
+            "a joining evaluator must fetch, not compile"
+        assert store.snapshot()["hits"] == 2
+    finally:
+        aot_cache.set_cluster_store(None)
+
+
 def test_cross_process_persistence(tmp_path):
     """ISSUE 10 acceptance: compile in ONE process, start a fresh
     evaluator in ANOTHER on the same cache dir, assert disk hits and

@@ -115,10 +115,10 @@ def test_sort_chunk_descending_with_nulls_and_strings():
     assert got == want
 
 
-def test_lsd_radix_argsort_matches_single_pass():
-    """The large-N LSD path (one stable single-word sort per key word)
-    must produce EXACTLY the single-pass variadic network's permutation —
-    including stability across duplicate composite keys."""
+def test_lsd_radix_argsort_matches_single_pass(monkeypatch):
+    """The large-N LSD radix engine must produce EXACTLY the single-pass
+    variadic network's permutation — including stability across
+    duplicate composite keys."""
     import jax.numpy as jnp
 
     from ytsaurus_tpu.ops.segments import stable_argsort_u32
@@ -130,8 +130,10 @@ def test_lsd_radix_argsort_matches_single_pass():
         jnp.asarray(rng.integers(0, 1 << 32, n, dtype=np.uint32)),
         jnp.asarray(rng.integers(0, 3, n, dtype=np.uint32)),    # heavy dups
     ]
-    single = np.asarray(stable_argsort_u32(words, lsd=False))
-    radix = np.asarray(stable_argsort_u32(words, lsd=True))
+    monkeypatch.setenv("YT_TPU_SORT_ENGINE", "network")
+    single = np.asarray(stable_argsort_u32(words))
+    monkeypatch.setenv("YT_TPU_SORT_ENGINE", "radix")
+    radix = np.asarray(stable_argsort_u32(words))
     np.testing.assert_array_equal(single, radix)
 
 

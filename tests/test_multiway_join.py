@@ -152,6 +152,28 @@ def _dual_check(mw_tables, corpus):
         assert _canon(got2.to_rows()) == _canon(want.to_rows()), query
 
 
+def test_stitched_cascade_pays_syncs_per_join(mw_tables):
+    """What the fused multiway join is compared with: the stitched
+    binary cascade (`DistributedEvaluator.run`, shuffle) runs count /
+    probe / expand programs per join with host syncs between them —
+    three a query or more over a 3-join plan, against the fused rung's
+    one — and answers the same rows."""
+    from ytsaurus_tpu.parallel.distributed import (
+        DistributedEvaluator,
+        host_sync_count,
+    )
+    mesh, _chunks, table, merged, foreign = mw_tables
+    plan = build_query(CORPUS[3], SCHEMAS)
+    yt_config.set_compile_config(yt_config.CompileConfig(whole_plan=False))
+    de = DistributedEvaluator(mesh)
+    de.run(plan, table, foreign, shuffle=True)               # warm-up
+    s0 = host_sync_count()
+    got = de.run(plan, table, foreign, shuffle=True)
+    assert host_sync_count() - s0 >= 3
+    want = Evaluator().run_plan(plan, merged, foreign)
+    assert _canon(got.to_rows()) == _canon(want.to_rows())
+
+
 def test_multiway_dual_check_corpus(mw_tables):
     """Fused multiway joins vs the local evaluator over the quick
     shape-representative corpus, with exactly ONE steady-state host

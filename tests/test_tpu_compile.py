@@ -21,12 +21,11 @@ from jax.sharding import SingleDeviceSharding
 
 @pytest.fixture
 def as_on_chip(monkeypatch):
-    """Repo code that asks `jax.default_backend()` takes its TPU branch
-    (segments.segment_engine -> "scan", segments.prefix_scan -> shifted
-    form), as it does in a process that holds the chip; sorts ride the
-    radix engine, as chip_smoke.py runs them (the network engine `auto`
-    picks below 8M rows costs ~40 s of chip compile PER KEY WORD)."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    """Sorts ride the radix engine, as the benchmark's configs and
+    chip_smoke.py run them (the network engine `auto` picks below 8M
+    rows costs ~40 s of chip compile PER KEY WORD).  Nothing else
+    differs: ops/ asks no backend, the programs traced here are the
+    ones every test executes."""
     monkeypatch.setenv("YT_TPU_SORT_ENGINE", "radix")
 
 
@@ -232,10 +231,9 @@ def _segment_end_reference(starts):
 
 @pytest.mark.parametrize("n", [1, 2, 7, 300])
 @pytest.mark.parametrize("function", ["sum", "min", "max"])
-def test_shifted_prefix_scan_matches_associative_scan(as_on_chip, function,
-                                                      n):
-    """The chip's scan form, EXECUTED here on the CPU, against the CPU's
-    lax.associative_scan form (which every other test runs)."""
+def test_shifted_prefix_scan_matches_associative_scan(function, n):
+    """prefix_scan's one form (shifted, log-step; what every backend
+    runs) against `lax.associative_scan` as its reference."""
     from ytsaurus_tpu.ops import segments
     rng = np.random.default_rng(n)
     data = jnp.asarray(rng.integers(-50, 50, n))

@@ -9,6 +9,7 @@ NearestBatcher (co-admitted cohort → ONE batched distance matmul).
 """
 
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -440,81 +441,83 @@ def test_nearest_rows_client_api(vclient):
     _assert_recall([r["k"] for r in out], rows, q, "dot", 5)
 
 
-def test_cohort_shares_one_batched_matmul(vclient):
+def _hold_flush_until_pending(monkeypatch, batcher, n):
+    """The flusher's accumulate step, for this test: wait until the
+    batcher SHOWS `n` requests pending, in place of the serving poll
+    (flush once 0.2 ms pass with no arrival, inside `flush_window_ms`),
+    which eight threads on a busy host do not reliably beat.  The
+    cohort's membership then follows from what the test observes, not
+    from the wall clock."""
+    def accumulate():
+        deadline = time.monotonic() + 60.0
+        while time.monotonic() < deadline:
+            with batcher._cond:
+                pending = sum(len(b.queries)
+                              for b in batcher._batches.values())
+            if pending >= n:
+                return
+            time.sleep(0.001)
+    monkeypatch.setattr(batcher, "_accumulate", accumulate)
+
+
+def _nearest_from_threads(client, calls):
+    """One `nearest_rows(//home/vec, emb, vector, k)` per (vector, k) of
+    `calls`, each from its own thread; the results in call order."""
+    results = [None] * len(calls)
+
+    def work(i):
+        vector, k = calls[i]
+        results[i] = client.nearest_rows("//home/vec", "emb", vector, k)
+
+    threads = [threading.Thread(target=work, args=(i,))
+               for i in range(len(calls))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120.0)
+    assert not any(t.is_alive() for t in threads)
+    return results
+
+
+def test_cohort_shares_one_batched_matmul(vclient, monkeypatch):
     """THE serving acceptance: N co-admitted NEAREST queries on one
     (table, column, metric) execute as ONE batched flush — the batcher
     counts one batch, and the jitted kernel does not re-trace for the
     co-batched queries (they ride the batch dimension of one matmul)."""
     from ytsaurus_tpu.query import vector as vmod
     client, rows = vclient
-    gateway = client.cluster.gateway
-    batcher = gateway.nearest_batcher
-    # Widen the coalescing window so all workers land in one cohort
-    # deterministically (the default 2ms window is a latency tuning,
-    # not a correctness bound).
-    old_window = gateway.config.flush_window_ms
-    gateway.config.flush_window_ms = 200.0
-    try:
-        # Warm one flush so the kernel for this (capacity, batch-bucket,
-        # k-bucket) is already traced, then assert the cohort run adds
-        # exactly one batch and zero fresh traces for its members.
-        client.nearest_rows("//home/vec", "emb", QUERY_VECTORS[1], 3)
-        rng = np.random.default_rng(31)
-        queries = [[float(x) for x in rng.integers(-6, 7, DIM)]
-                   for _ in range(8)]
-        b0 = batcher.batches_n
-        t0 = vmod.nearest_trace_count()
-        results = [None] * len(queries)
-        barrier = threading.Barrier(len(queries))
-
-        def work(i):
-            barrier.wait()
-            results[i] = client.nearest_rows("//home/vec", "emb",
-                                             queries[i], 3)
-
-        threads = [threading.Thread(target=work, args=(i,))
-                   for i in range(len(queries))]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        assert batcher.batches_n - b0 == 1, \
-            "co-admitted cohort must flush as ONE batch"
-        assert vmod.nearest_trace_count() - t0 <= 1, \
-            "cohort members must share one compiled kernel"
-        for i, q in enumerate(queries):
-            _assert_recall([r["k"] for r in results[i]], rows, q,
-                           "l2", 3)
-    finally:
-        gateway.config.flush_window_ms = old_window
+    batcher = client.cluster.gateway.nearest_batcher
+    # Warm one flush so the kernel for this (capacity, batch-bucket,
+    # k-bucket) is already traced, then assert the cohort run adds
+    # exactly one batch and zero fresh traces for its members.
+    client.nearest_rows("//home/vec", "emb", QUERY_VECTORS[1], 3)
+    rng = np.random.default_rng(31)
+    queries = [[float(x) for x in rng.integers(-6, 7, DIM)]
+               for _ in range(8)]
+    _hold_flush_until_pending(monkeypatch, batcher, len(queries))
+    b0 = batcher.batches_n
+    t0 = vmod.nearest_trace_count()
+    results = _nearest_from_threads(client, [(q, 3) for q in queries])
+    assert batcher.batches_n - b0 == 1, \
+        "co-admitted cohort must flush as ONE batch"
+    assert vmod.nearest_trace_count() - t0 <= 1, \
+        "cohort members must share one compiled kernel"
+    for i, q in enumerate(queries):
+        _assert_recall([r["k"] for r in results[i]], rows, q, "l2", 3)
 
 
-def test_mixed_k_cohort_each_member_gets_its_k(vclient):
+def test_mixed_k_cohort_each_member_gets_its_k(vclient, monkeypatch):
     client, rows = vclient
-    gateway = client.cluster.gateway
-    old_window = gateway.config.flush_window_ms
-    gateway.config.flush_window_ms = 200.0
-    try:
-        ks = [1, 3, 7, 2]
-        results = [None] * len(ks)
-        barrier = threading.Barrier(len(ks))
-
-        def work(i):
-            barrier.wait()
-            results[i] = client.nearest_rows(
-                "//home/vec", "emb", QUERY_VECTORS[0], ks[i])
-
-        threads = [threading.Thread(target=work, args=(i,))
-                   for i in range(len(ks))]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        for i, k in enumerate(ks):
-            _assert_recall([r["k"] for r in results[i]], rows,
-                           QUERY_VECTORS[0], "l2", k)
-    finally:
-        gateway.config.flush_window_ms = old_window
+    batcher = client.cluster.gateway.nearest_batcher
+    ks = [1, 3, 7, 2]
+    _hold_flush_until_pending(monkeypatch, batcher, len(ks))
+    b0 = batcher.batches_n
+    results = _nearest_from_threads(
+        client, [(QUERY_VECTORS[0], k) for k in ks])
+    assert batcher.batches_n - b0 == 1      # one cohort, mixed k
+    for i, k in enumerate(ks):
+        _assert_recall([r["k"] for r in results[i]], rows,
+                       QUERY_VECTORS[0], "l2", k)
 
 
 def test_nearest_accounting_folds(vclient):

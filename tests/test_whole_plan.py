@@ -157,6 +157,32 @@ def test_dual_check_corpus(table8):
         assert _canon(got.to_rows()) == _canon(want.to_rows()), query
 
 
+def test_stitched_shuffle_rung_pays_more_than_one_sync(table8):
+    """What the fused rung is compared with: the same GROUP BY with
+    `whole_plan` off goes down the stitched shuffle rung, which reads
+    counts back between its programs — two host syncs a query or more,
+    against the fused rung's one — and answers the same rows."""
+    from ytsaurus_tpu.parallel.distributed import (
+        DistributedEvaluator,
+        coordinate_distributed,
+        host_sync_count,
+    )
+    mesh, chunks, _table, merged = table8
+    plan = build_query(CORPUS[0], {T: SCHEMA})
+    yt_config.set_compile_config(yt_config.CompileConfig(whole_plan=False))
+    de = DistributedEvaluator(mesh)
+    stats = QueryStatistics()
+    coordinate_distributed(plan, mesh, chunks, evaluator=de,
+                           prefer_shuffle=True, stats=stats)   # warm-up
+    assert stats.whole_plan == 0
+    s0 = host_sync_count()
+    got = coordinate_distributed(plan, mesh, chunks, evaluator=de,
+                                 prefer_shuffle=True)
+    assert host_sync_count() - s0 >= 2
+    want = Evaluator().run_plan(plan, merged)
+    assert _canon(got.to_rows()) == _canon(want.to_rows())
+
+
 def test_repeat_query_compiles_nothing(table8):
     """Steady state: a repeated fused query is a pure cache hit — zero
     fresh compiles, zero overflow retries (the quota memo settled)."""

@@ -3,9 +3,10 @@
 Data generators (seeded, numpy) + query text for the BASELINE.md configs:
   Q1  — scan + filter + 8-aggregate GROUP BY over lineitem
   Q3  — two-table join + GROUP BY (customer/orders condensed into dims)
-These drive bench.py and the graft entry; the chip-measured join deployment
-is benchmark/configs/tpch-orders-lineitem.json (dbgen-shaped ORDERS +
-LINEITEM, TPC-H Q12; cell tpch_q12_join).
+These drive chip_smoke.py, the graft entry and tests/test_tpu_compile.py;
+the chip-measured deployments are benchmark/configs/ (dbgen-shaped
+LINEITEM and ORDERS; cells tpch_q1_sf1, tpch_group_topk_sf01,
+tpch_q12_join).
 """
 
 from __future__ import annotations
@@ -61,105 +62,6 @@ Q3 = (
     "ORDER BY sum(l_extendedprice * (1 - l_discount)) DESC, l_orderkey "
     "LIMIT 10"
 )
-
-
-def device_planes(specs: dict, n_rows: int, seed: int = 0) -> dict:
-    """Generate column planes ON DEVICE with jax.random — nothing crosses
-    the host↔device link, so set-up time does not grow with a host copy
-    of the table (the in-memory-mode analog).
-
-    specs: name → ("arange",) | ("randint", lo, hi) | ("uniform", lo, hi)
-                 | ("randint_f64", lo, hi)
-    Planes come back zero-padded to pad_capacity(n_rows) with values only
-    in [0, n_rows).
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax import random
-
-    from ytsaurus_tpu.chunks.columnar import pad_capacity
-
-    cap = pad_capacity(max(n_rows, 1))
-    names = sorted(specs)
-
-    def gen(key):
-        valid = jnp.arange(cap) < n_rows
-        out = {}
-        for i, name in enumerate(names):
-            spec = specs[name]
-            k = random.fold_in(key, i)
-            kind = spec[0]
-            if kind == "arange":
-                plane = jnp.arange(cap, dtype=jnp.int64)
-            elif kind == "randint":
-                plane = random.randint(k, (cap,), spec[1], spec[2],
-                                       dtype=jnp.int64)
-            elif kind == "randint_f64":
-                plane = random.randint(k, (cap,), spec[1], spec[2],
-                                       dtype=jnp.int64).astype(jnp.float64)
-            elif kind == "uniform":
-                plane = random.uniform(k, (cap,), dtype=jnp.float64,
-                                       minval=spec[1], maxval=spec[2])
-            else:
-                raise ValueError(f"Unknown spec {spec!r}")
-            zero = jnp.zeros((), dtype=plane.dtype)
-            out[name] = jnp.where(valid, plane, zero)
-        return out
-
-    return jax.jit(gen)(random.PRNGKey(seed))
-
-
-def device_chunk(schema: TableSchema, planes: dict, n_rows: int,
-                 dictionaries: dict | None = None) -> ColumnarChunk:
-    """Wrap device-resident planes into a ColumnarChunk (no host copy)."""
-    import jax.numpy as jnp
-
-    from ytsaurus_tpu.chunks.columnar import Column, pad_capacity
-    from ytsaurus_tpu.schema import device_dtype
-
-    cap = pad_capacity(max(n_rows, 1))
-    valid = jnp.arange(cap) < n_rows
-    columns = {}
-    for col in schema:
-        data = planes[col.name].astype(device_dtype(col.type))
-        vocab = None
-        if dictionaries is not None and col.name in dictionaries:
-            vocab = np.asarray(dictionaries[col.name], dtype=object)
-        columns[col.name] = Column(type=col.type, data=data, valid=valid,
-                                   dictionary=vocab)
-    return ColumnarChunk(schema=schema, row_count=n_rows, columns=columns)
-
-
-def generate_lineitem_device(n_rows: int, seed: int = 0,
-                             n_orders: int | None = None) -> ColumnarChunk:
-    """lineitem generated entirely in HBM (same schema/distributions as
-    generate_lineitem; dictionary codes for the two flag columns)."""
-    n_orders = n_orders or max(n_rows // 4, 1)
-    planes = device_planes({
-        "l_orderkey": ("randint", 0, n_orders),
-        "l_quantity": ("randint_f64", 1, 51),
-        "l_extendedprice": ("uniform", 900.0, 105000.0),
-        "l_discount": ("uniform", 0.0, 0.10),
-        "l_tax": ("uniform", 0.0, 0.08),
-        "l_returnflag": ("randint", 0, 3),
-        "l_linestatus": ("randint", 0, 2),
-        "l_shipdate": ("randint", 8000, 10600),
-    }, n_rows, seed)
-    flags = np.array([b"A", b"N", b"R"], dtype=object)
-    status = np.array([b"F", b"O"], dtype=object)
-    return device_chunk(LINEITEM_SCHEMA, planes, n_rows,
-                        dictionaries={"l_returnflag": flags,
-                                      "l_linestatus": status})
-
-
-def generate_orders_device(n_orders: int, seed: int = 1) -> ColumnarChunk:
-    planes = device_planes({
-        "o_orderkey": ("arange",),
-        "o_custkey": ("randint", 0, max(n_orders // 10, 1)),
-        "o_orderdate": ("randint", 8000, 10600),
-        "o_shippriority": ("randint", 0, 2),
-    }, n_orders, seed)
-    return device_chunk(ORDERS_SCHEMA, planes, n_orders)
 
 
 def generate_lineitem(n_rows: int, seed: int = 0,

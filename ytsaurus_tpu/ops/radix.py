@@ -27,9 +27,7 @@ histogram arithmetic:
 
 No data-dependent shapes, no giant network, and no per-row search: a
 search costs a row-sized gather per step, and row-sized gathers were
-what a pass was made of (PERF.md section 6, PR 31).  engine="scatter" is
-the older way to the same scatter (two row-sized gathers from the
-tables), kept for measurement.
+what a pass was made of (PERF.md section 6, PR 31).
 
 Reference analog: the Sort operation's partition tree + k-way heap merge
 (yt/yt/server/controller_agent/controllers/sort_controller.cpp:459,
@@ -40,8 +38,6 @@ batch-synchronous vector machine wants.
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -50,7 +46,7 @@ from ytsaurus_tpu.ops.segments import prefix_scan
 
 # Tile size for the per-tile sort networks: the composite key is
 # (digit << LOG_TILE) | position, so RADIX_BITS + LOG_TILE must be <= 32.
-RADIX_TILE = int(os.environ.get("YT_TPU_RADIX_TILE", 2048))
+RADIX_TILE = 2048
 RADIX_BITS = 8
 _B = 1 << RADIX_BITS
 
@@ -77,8 +73,8 @@ def _tile_counts(d_sorted):
 
 
 @jax.named_scope("radix.pass")       # the name its ops carry in a trace
-def radix_pass(digit: jax.Array, payloads: list[jax.Array],
-               engine: str = "gather") -> list[jax.Array]:
+def radix_pass(digit: jax.Array,
+               payloads: list[jax.Array]) -> list[jax.Array]:
     """One stable ascending partition by `digit` (u32 values < 256).
 
     digit and each payload are (N,) with N % RADIX_TILE == 0; returns the
@@ -113,28 +109,22 @@ def radix_pass(digit: jax.Array, payloads: list[jax.Array],
     # tiles; every element's destination is unique (a permutation).
     run_start = bin_start[None, :] + tile_excl                  # (nt, B)
 
-    if engine == "scatter":
-        rs = jnp.take_along_axis(run_start, d_sorted, axis=1)
-        ls = jnp.take_along_axis(local_start, d_sorted, axis=1)
-        dest = (rs + (pos[None, :].astype(jnp.int32) - ls)).reshape(n)
-    else:
-        # engine == "gather" (the name is older than the form).  The
-        # tile-sorted rows are the nt x 256 runs in (tile, bin) order; run
-        # (t, b) starts at row src0 and goes to slots run_start[t, b]
-        # onward, so inside a run dest - row is the constant run_start -
-        # src0.  Mark each run's first row with the CHANGE of that
-        # constant and prefix-sum the marks: an empty run shares its row
-        # with the run after it and their changes add up, so the sum at a
-        # row telescopes to the constant of the run that holds it.  No
-        # per-row search, no row-sized gather.  Every plane is held to
-        # int32 (the package enables x64); wrap-around is harmless.
-        src0 = (jnp.arange(nt, dtype=jnp.int32)[:, None] * np.int32(tile)
-                + local_start)                                  # (nt, B)
-        jump = jnp.diff((run_start - src0).reshape(-1), prepend=0)
-        # Runs that start past the last row (src0 == n) are empty: dropped.
-        marks = jnp.zeros(n, jnp.int32).at[src0.reshape(-1)].add(
-            jump, indices_are_sorted=True, mode="drop")
-        dest = jnp.arange(n, dtype=jnp.int32) + prefix_scan(jnp.add, marks)
+    # The tile-sorted rows are the nt x 256 runs in (tile, bin) order; run
+    # (t, b) starts at row src0 and goes to slots run_start[t, b] onward,
+    # so inside a run dest - row is the constant run_start - src0.  Mark
+    # each run's first row with the CHANGE of that constant and prefix-sum
+    # the marks: an empty run shares its row with the run after it and
+    # their changes add up, so the sum at a row telescopes to the constant
+    # of the run that holds it.  No per-row search, no row-sized gather.
+    # Every plane is held to int32 (the package enables x64); wrap-around
+    # is harmless.
+    src0 = (jnp.arange(nt, dtype=jnp.int32)[:, None] * np.int32(tile)
+            + local_start)                                      # (nt, B)
+    jump = jnp.diff((run_start - src0).reshape(-1), prepend=0)
+    # Runs that start past the last row (src0 == n) are empty: dropped.
+    marks = jnp.zeros(n, jnp.int32).at[src0.reshape(-1)].add(
+        jump, indices_are_sorted=True, mode="drop")
+    dest = jnp.arange(n, dtype=jnp.int32) + prefix_scan(jnp.add, marks)
     return [jnp.zeros(n, p.dtype).at[dest].set(
                 p, unique_indices=True, mode="drop")
             for p in pay_sorted]
@@ -147,15 +137,11 @@ def _pad_to_tile(x: jax.Array, n_pad: int, fill) -> jax.Array:
 
 
 def radix_argsort_u32(words: list[jax.Array],
-                      word_bits: "list[int] | None" = None,
-                      engine: str = "gather") -> jax.Array:
+                      word_bits: "list[int] | None" = None) -> jax.Array:
     """Stable ascending argsort over u32 key words (major word first) via
     LSD radix passes.  `word_bits[k]` bounds the significant LOW bits of
     word k (higher bits must be zero) — digit passes above the bound are
     skipped, so a packed 12-bit key costs 2 byte passes, not 4.
-
-    engine: "gather" | "scatter" (ops above; both move rows by one
-    scatter, the names are older than that).
 
     Pad rows (to the tile multiple) carry all-ones keys and sort last;
     ties against real all-ones rows resolve to the real rows first by
@@ -169,7 +155,6 @@ def radix_argsort_u32(words: list[jax.Array],
         word_bits = [32] * len(words)
     pass_bits = RADIX_BITS
     tile = min(RADIX_TILE, 1 << max(n - 1, 1).bit_length())
-    pass_fn = lambda d, p: radix_pass(d, p, engine=engine)  # noqa: E731
     padded = ((n + tile - 1) // tile) * tile
     n_pad = padded - n
     perm = jnp.arange(padded, dtype=jnp.uint32)
@@ -182,5 +167,5 @@ def radix_argsort_u32(words: list[jax.Array],
         wpad = _pad_to_tile(word.astype(jnp.uint32), n_pad, fill)
         for shift in range(0, min(bits, 32), pass_bits):
             digit = (jnp.take(wpad, perm) >> np.uint32(shift)) & mask
-            (perm,) = pass_fn(digit, [perm])
+            (perm,) = radix_pass(digit, [perm])
     return perm[:n]

@@ -55,16 +55,14 @@ def prefix_scan(combine, elems):
     """Inclusive prefix scan of 1-D planes (a pytree) under an associative
     `combine` — the one scan primitive every segmented scan here rides.
 
-    Off the CPU it is the log-step shifted form (Hillis-Steele: step d
-    combines each row with the row d before it): n log n work, but every
-    step is one fused elementwise pass over whole planes.  libtpu takes
-    MINUTES to compile `lax.associative_scan`'s strided slice/interleave
-    ladder (compiled for a described v5e, libtpu 0.0.34: one 1M-row
-    segmented sum 161 s and 41 MB of code, `jnp.cumsum` 96 s; the shifted
-    form 6 s), and a served query carries several.  The CPU keeps the
-    work-efficient associative_scan (tools/kernel_floors.json gates it)."""
-    if jax.default_backend() == "cpu":
-        return jax.lax.associative_scan(combine, elems)
+    The log-step shifted form (Hillis-Steele: step d combines each row
+    with the row d before it): n log n work, but every step is one fused
+    elementwise pass over whole planes.  libtpu takes MINUTES to compile
+    `lax.associative_scan`'s strided slice/interleave ladder (compiled
+    for a described v5e, libtpu 0.0.34: one 1M-row segmented sum 161 s
+    and 41 MB of code, `jnp.cumsum` 96 s; the shifted form 6 s), and a
+    served query carries several.  One form on every backend, so the
+    tests run what the chip runs."""
     n = jax.tree_util.tree_leaves(elems)[0].shape[0]
     iota = jnp.arange(n, dtype=jnp.int32)
     d = 1
@@ -103,58 +101,12 @@ def segment_boundaries(sorted_keys: list[tuple[jax.Array, jax.Array]],
     return seg, num_segments
 
 
-# Below this many segments, scatter-based segment ops are replaced by masked
-# broadcast-reductions: XLA fuses the (S, N) compare+select into the reduce
-# (bandwidth-bound VPU work), while TPU scatter-adds serialize badly
-# (~130 ms per 2M-row f64 plane measured on v5e vs ~1 ms for the fused form).
+# At or below this many segments a reduce is a masked broadcast-reduction:
+# XLA fuses the (S, N) compare+select into the reduce (bandwidth-bound VPU
+# work, its cost linear in S).  Above it: one sort by segment id, then
+# _sorted_segment_reduce.  Never a scatter: a TPU scatter-add with
+# duplicate indices serializes.
 _DENSE_SEGMENT_LIMIT = 256
-
-# The CPU backend inverts the TPU scatter economics: XLA:CPU lowers
-# scatter-add/min/max to a serial update loop that costs ~1 pass over the
-# rows REGARDLESS of segment count (round-14: 84 ms for a 2M-row 10k-group
-# i64 sum vs 828 ms presort + 91 ms scan on the sort-based path), while the
-# dense broadcast-reduce costs nseg passes.  Engine dispatch below picks
-# per backend; YT_TPU_SEGMENT_ENGINE ∈ {scan, scatter} overrides (read at
-# trace time — switching it mid-process does not invalidate cached
-# programs, same contract as YT_TPU_SORT_ENGINE).
-_DENSE_SEGMENT_LIMIT_SCATTER = 16
-
-
-def segment_engine() -> str:
-    """Reduction engine for segment counts above the dense limit:
-    "scan" (presort + segmented associative scan — the TPU path) or
-    "scatter" (native .at[].add/min/max — the CPU path)."""
-    engine = os.environ.get("YT_TPU_SEGMENT_ENGINE", "auto")
-    if engine == "auto":
-        return "scatter" if jax.default_backend() == "cpu" else "scan"
-    if engine not in ("scan", "scatter"):
-        raise ValueError(f"unknown YT_TPU_SEGMENT_ENGINE {engine!r}")
-    return engine
-
-
-def _dense_limit() -> int:
-    # Scatter costs ~flat in nseg, so the dense crossover sits far lower
-    # than the scan engine's (dense cost grows ~linearly with nseg).
-    return _DENSE_SEGMENT_LIMIT_SCATTER if segment_engine() == "scatter" \
-        else _DENSE_SEGMENT_LIMIT
-
-
-def _scatter_segment_reduce(function: str, data: jax.Array,
-                            seg_ids: jax.Array, num_segments: int):
-    """Single-pass native scatter reduce.  Out-of-range segment ids (the
-    general group path parks masked rows at a traced id that can equal
-    num_segments) drop silently — exactly the trailing-garbage contract of
-    the other engines."""
-    if function == "sum":
-        init = jnp.zeros(num_segments, dtype=data.dtype)
-        return init.at[seg_ids].add(data, mode="drop")
-    neutral = _reduce_neutral(data.dtype, function)
-    init = jnp.full(num_segments, neutral, dtype=data.dtype)
-    if function == "min":
-        return init.at[seg_ids].min(data, mode="drop")
-    if function == "max":
-        return init.at[seg_ids].max(data, mode="drop")
-    raise ValueError(function)
 
 
 def _dense_segment_reduce(function: str, data: jax.Array, seg_ids: jax.Array,
@@ -182,8 +134,8 @@ def _sorted_segment_reduce(function: str, data: jax.Array,
     """Segment reduce for NONDECREASING seg_ids with no scatter: a
     segmented associative scan (the combine resets at segment starts, so
     float sums keep per-segment precision) + a searchsorted gather at each
-    segment's last row.  TPU scatter-adds serialize (~130 ms per 2M-row
-    f64 plane measured on v5e); log-depth scans and gathers do not."""
+    segment's last row.  Ids at or past num_segments (masked rows parked
+    there) fall outside every searched range and drop."""
     cap = data.shape[0]
     starts = jnp.concatenate([
         jnp.ones(1, dtype=bool), seg_ids[1:] != seg_ids[:-1]])
@@ -216,22 +168,13 @@ def _sorted_segment_reduce(function: str, data: jax.Array,
 @jax.named_scope("segments.reduce")  # the name its ops carry in a trace
 def _segment_reduce(function: str, data: jax.Array, seg_ids: jax.Array,
                     num_segments: int, assume_sorted: bool = False):
-    if num_segments <= _dense_limit():
+    if num_segments <= _DENSE_SEGMENT_LIMIT:
         return _dense_segment_reduce(function, data, seg_ids, num_segments)
-    if segment_engine() == "scatter":
-        # CPU: one native scatter pass, sorted or not.  (Float sums
-        # accumulate in scatter-visit order rather than per-segment scan
-        # order — the same sanctioned divergence the interpreter tier's
-        # np.add.at already has.)
-        return _scatter_segment_reduce(function, data, seg_ids,
-                                       num_segments)
     if assume_sorted:
         return _sorted_segment_reduce(function, data, seg_ids, num_segments)
-    # Unsorted mid/high cardinality: NEVER scatter (TPU scatter-adds with
-    # duplicate indices serialize — measured 23.8 s for a 64M-row 10k-group
-    # segment_sum on v5e).  One u32 sort by segment id + a segmented scan
-    # is orders of magnitude cheaper.  Hot paths pre-sort ONCE for all
-    # aggregates (lowering's group stage) and take assume_sorted instead.
+    # Unsorted mid/high cardinality: one u32 sort by segment id, then the
+    # segmented scan.  Hot paths pre-sort ONCE for all aggregates
+    # (lowering's group stage) and take assume_sorted instead.
     order = stable_argsort_u32([seg_ids.astype(jnp.uint32)])
     return _sorted_segment_reduce(function, data[order], seg_ids[order],
                                   num_segments)
@@ -242,11 +185,9 @@ def presort_segments(seg_ids: jax.Array,
     """Shared presort policy for multi-aggregate group stages: returns the
     row order to apply once (then pass assume_sorted=True for every
     aggregate), or None when the reduce needs no ordering — the dense
-    broadcast path, and the ENTIRE scatter engine (CPU), whose reduces are
-    order-independent single passes; skipping the group-stage sort there
-    is the round-14 groupby win.  Keeping the dispatch HERE keeps it in
-    lockstep with _segment_reduce's threshold."""
-    if num_segments <= _dense_limit() or segment_engine() == "scatter":
+    broadcast path.  Keeping the dispatch HERE keeps it in lockstep with
+    _segment_reduce's threshold."""
+    if num_segments <= _DENSE_SEGMENT_LIMIT:
         return None
     return stable_argsort_u32([seg_ids.astype(jnp.uint32)])
 
@@ -258,8 +199,8 @@ def segment_aggregate(function: str, data: jax.Array, valid: jax.Array,
                       ) -> tuple[jax.Array, jax.Array]:
     """Aggregate `data` per segment, skipping nulls. Returns (out, out_valid)
     planes of length num_segments (static capacity).  assume_sorted=True
-    (nondecreasing seg_ids — the hash-grouped general path) switches to the
-    scatter-free segmented-scan reduction."""
+    (nondecreasing seg_ids — the presorted and hash-grouped paths) spares
+    the reduce its own sort above the dense limit."""
     contributes = valid
     count = _segment_reduce(
         "sum", contributes.astype(jnp.int64), seg_ids, num_segments,
@@ -670,13 +611,11 @@ def pack_key_planes_bits(items) -> tuple[list[jax.Array], list[int]]:
 # Above this row count, sorts leave the single-pass network (which
 # re-evaluates the composite comparator inside every compare-exchange of
 # an O(n log^2 n) network whose depth grows with the FULL row count) for
-# the tiled radix engine.  Tunable: the v5e cliff sits past ~8M.
-LSD_SORT_THRESHOLD = int(os.environ.get("YT_TPU_LSD_SORT_THRESHOLD",
-                                        8 * 1024 * 1024))
+# the tiled radix engine.
+LSD_SORT_THRESHOLD = 8 * 1024 * 1024
 
 
 def stable_argsort_u32(words: list[jax.Array],
-                       lsd: "bool | None" = None,
                        word_bits: "list[int] | None" = None) -> jax.Array:
     """Stable ascending argsort over u32 key words (major first); the
     payload rides as a u32 iota so no 64-bit plane enters the sort.
@@ -684,22 +623,19 @@ def stable_argsort_u32(words: list[jax.Array],
     word_bits[k] (optional) bounds the significant LOW bits of word k —
     the radix engine skips byte passes above the bound.
 
-    Engine dispatch (YT_TPU_SORT_ENGINE overrides):
-      network — one variadic lax.sort; best below the ~8M network cliff.
-      lsd32   — one full-width stable u32 lax.sort per word (round-2
-                engine, kept for measurement).
+    Engine dispatch (YT_TPU_SORT_ENGINE = auto | network | radix, read
+    at trace time):
+      network — one variadic lax.sort; `auto` below the ~8M network cliff.
       radix   — tiled 8-bit LSD counting sort (ops/radix.py): per-TILE
                 sort networks + histogram rank movement; depth never
-                grows with n.  Default past LSD_SORT_THRESHOLD.
-      radix_scatter — radix with the permutation-scatter write path.
+                grows with n.  `auto` past LSD_SORT_THRESHOLD.
 
     Unknown engine names raise (a typo must not silently run the
-    one-pass network into the very cliff the engines exist to avoid).
+    one-pass network into the very cliff the radix engine exists to
+    avoid).
     """
     n = words[0].shape[0]
     engine = os.environ.get("YT_TPU_SORT_ENGINE", "auto")
-    if lsd is not None:                      # explicit caller override
-        engine = "lsd32" if lsd else "network"
     if engine == "auto":
         # The network's comparator cost grows with operand count too
         # (round-1 observation: full multi-plane lexsorts collapse past
@@ -707,20 +643,12 @@ def stable_argsort_u32(words: list[jax.Array],
         effective = min(LSD_SORT_THRESHOLD,
                         2 * LSD_SORT_THRESHOLD // max(len(words), 1))
         engine = "network" if n <= effective else "radix"
-    if engine in ("radix", "radix_scatter"):
+    if engine == "radix":
         from ytsaurus_tpu.ops.radix import radix_argsort_u32
-        sub_engine = {"radix": "gather", "radix_scatter": "scatter"}[engine]
-        return radix_argsort_u32(words, word_bits, engine=sub_engine)
-    if engine not in ("network", "lsd32"):
+        return radix_argsort_u32(words, word_bits)
+    if engine != "network":
         raise ValueError(f"unknown YT_TPU_SORT_ENGINE {engine!r}")
     iota = jnp.arange(n, dtype=jnp.uint32)
-    if engine == "lsd32":
-        perm = iota
-        for word in reversed(words):
-            keys = jnp.take(word, perm)
-            _, perm = jax.lax.sort((keys, perm), num_keys=1,
-                                   is_stable=True)
-        return perm
     out = jax.lax.sort((*words, iota), num_keys=len(words),
                        is_stable=True)
     return out[-1]

@@ -65,7 +65,8 @@ _FP_GATHER = failpoints.register_site("parallel.gather",
 # read a distributed query performs notes here — the stitched rungs pay
 # one per exchange-quota decision plus the final count; the whole-plan
 # path pays exactly one (the final stacked transfer).  A plain counter
-# (not a sensor): `bench.py --config whole_plan` reads deltas.
+# (not a sensor): tests/test_whole_plan.py and test_multiway_join.py
+# assert on its deltas.
 _host_syncs_n = 0
 
 

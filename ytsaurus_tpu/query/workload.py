@@ -437,7 +437,7 @@ class WorkloadLog:
     def export_capture(self, path: str,
                        limit: Optional[int] = None) -> int:
         """Write the retained records as a versioned capture file; the
-        artifact `yt replay` and `bench.py --config replay` consume."""
+        artifact `yt replay` consumes."""
         return write_capture(path, self.records(), limit=limit)
 
     def import_capture(self, path: str) -> int:

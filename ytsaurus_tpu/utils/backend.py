@@ -1,6 +1,6 @@
 """Backend selection + compile-cache placement for driver entry points.
 
-Entry points (chip_smoke.py, bench.py, __graft_entry__.py) call these
+Entry points (chip_smoke.py, __graft_entry__.py) call these
 explicitly; nothing here runs at import time.  There is no fallback: a
 process that was not told `JAX_PLATFORMS=cpu` by its caller either finds a
 TPU in this process or raises.

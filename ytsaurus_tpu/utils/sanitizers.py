@@ -30,9 +30,10 @@ Gating: `YT_TPU_SANITIZE=1` (tests/conftest.py arms it suite-wide, the
 same pattern as YT_TPU_INVARIANTS) or `config.SanitizerConfig.enabled`
 via `configure()`.  DISABLED is the default and costs nothing:
 `register_lock()` returns the plain `threading.Lock` unwrapped — zero
-wrapper objects, zero per-acquire overhead (asserted by `bench.py
---config sanitizer_overhead`).  Locks created before enablement stay
-plain; enable before constructing the daemons you want watched.
+wrapper objects, zero per-acquire overhead (tests/test_sanitizer.py::
+test_register_lock_disabled_returns_plain_lock).  Locks created before
+enablement stay plain; enable before constructing the daemons you want
+watched.
 
 Registration names are stable SITE ids (`profiling.Counter._lock`):
 every instance of a class shares its site's name, matching the static
