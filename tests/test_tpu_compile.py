@@ -177,6 +177,23 @@ def test_radix_argsort_above_threshold(one_chip, as_on_chip):
                 one_chip)
 
 
+@pytest.mark.parametrize("dtype", [jnp.int64, jnp.float64],
+                         ids=["int64", "double"])
+def test_sorted_segment_reduce_at_cell_capacity(one_chip, dtype):
+    """The top-k cell's reduce (1,048,576 rows, as many segments): what
+    the chip's compiler makes of it holds one scatter and no loop (a
+    search would be a `while` over row-sized planes)."""
+    from ytsaurus_tpu.ops.segments import _sorted_segment_reduce
+    n = 1 << 20
+    args = (jax.ShapeDtypeStruct((n,), dtype),
+            jax.ShapeDtypeStruct((n,), jnp.int32))
+    compiled, _ = compile_for(
+        lambda d, s: _sorted_segment_reduce("sum", d, s, n), args, one_chip)
+    ops = compiled.as_text().splitlines()
+    assert not [line for line in ops if " while(" in line]
+    assert sum(" scatter(" in line for line in ops) == 1
+
+
 def test_sort_chunk_program(one_chip, as_on_chip):
     compile_sort_chunk(200_000, one_chip)
 

@@ -104,8 +104,10 @@ def segment_boundaries(sorted_keys: list[tuple[jax.Array, jax.Array]],
 # At or below this many segments a reduce is a masked broadcast-reduction:
 # XLA fuses the (S, N) compare+select into the reduce (bandwidth-bound VPU
 # work, its cost linear in S).  Above it: one sort by segment id, then
-# _sorted_segment_reduce.  Never a scatter: a TPU scatter-add with
-# duplicate indices serializes.
+# _sorted_segment_reduce.  Never a scatter with DUPLICATE indices (a
+# scatter-add of rows into their segments): that is what serializes on a
+# TPU.  The sorted reduce's one scatter has pairwise distinct indices and
+# says so (unique_indices=True), as ops/radix.py's does.
 _DENSE_SEGMENT_LIMIT = 256
 
 
@@ -131,11 +133,17 @@ def _dense_segment_reduce(function: str, data: jax.Array, seg_ids: jax.Array,
 
 def _sorted_segment_reduce(function: str, data: jax.Array,
                            seg_ids: jax.Array, num_segments: int):
-    """Segment reduce for NONDECREASING seg_ids with no scatter: a
-    segmented associative scan (the combine resets at segment starts, so
-    float sums keep per-segment precision) + a searchsorted gather at each
-    segment's last row.  Ids at or past num_segments (masked rows parked
-    there) fall outside every searched range and drop."""
+    """Segment reduce for NONDECREASING, non-negative seg_ids: a segmented
+    prefix scan (the combine resets at segment starts, so float sums keep
+    per-segment precision) leaves each segment's value at its LAST row,
+    and the start marks the scan already has say where that is: row i
+    closes its segment where row i + 1 opens one.  One unique-index
+    scatter places those rows' indices by segment id and one gather reads
+    the scanned plane there.  No search: a search for every segment's
+    rows is two row-sized gathers per step, 2 x 21 steps at 1,048,576
+    rows (PERF.md section 6, PR 33).  Ids at or past num_segments (masked
+    rows parked there) are dropped; a segment no row names reads the
+    function's neutral."""
     cap = data.shape[0]
     starts = jnp.concatenate([
         jnp.ones(1, dtype=bool), seg_ids[1:] != seg_ids[:-1]])
@@ -154,15 +162,24 @@ def _sorted_segment_reduce(function: str, data: jax.Array,
         return jnp.where(yf, yv, combine_val(xv, yv)), xf | yf
 
     scanned, _ = prefix_scan(combine, (data, starts))
-    sids = jnp.arange(num_segments, dtype=seg_ids.dtype)
-    left = jnp.searchsorted(seg_ids, sids, side="left")
-    right = jnp.searchsorted(seg_ids, sids, side="right")
-    out = scanned[jnp.clip(right - 1, 0, cap - 1)]
+    # Every plane is held to int32 (the package enables x64).  A row that
+    # closes no kept segment goes to an out-of-range slot of its own, so
+    # the indices are pairwise distinct and mode="drop" discards it.  This
+    # depends on seg_ids and num_segments alone: the aggregates of one
+    # group stage share it.
+    iota = jnp.arange(cap, dtype=jnp.int32)
+    ids = seg_ids.astype(jnp.int32)
+    closes = jnp.concatenate([starts[1:], jnp.ones(1, dtype=bool)]) & \
+        (ids < num_segments)
+    last_row = jnp.full(num_segments, -1, dtype=jnp.int32).at[
+        jnp.where(closes, ids, np.int32(num_segments) + iota)].set(
+            iota, unique_indices=True, mode="drop")
+    out = scanned[jnp.clip(last_row, 0, cap - 1)]
     if function == "sum":
         neutral = jnp.zeros((), dtype=data.dtype)
     else:
         neutral = _reduce_neutral(data.dtype, function)
-    return jnp.where(right > left, out, neutral)
+    return jnp.where(last_row >= 0, out, neutral)
 
 
 @jax.named_scope("segments.reduce")  # the name its ops carry in a trace
