@@ -400,7 +400,7 @@ def test_join_select_opens_the_join_spans(deployment):
     assert by_id[sync.parent_span_id] is join
     capacity = 1 << int(np.ceil(np.log2(SIZES["rows"])))
     assert join.tags == {
-        "table": "//tpch/orders", "self_rows": SIZES["rows"],
+        "table": "//tpch/orders", "stage": 0, "self_rows": SIZES["rows"],
         "foreign_rows": SIZES["orders"], "out_rows": SIZES["rows"],
         "out_capacity": capacity, "cache": "hit"}
     assert join.duration >= sync.duration > 0

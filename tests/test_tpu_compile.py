@@ -235,6 +235,96 @@ def test_join_phase_programs(one_chip, as_on_chip):
     compile_for(phase2, args2, one_chip)
 
 
+Q3_SCHEMAS = {
+    "//l": [("l_orderkey", "int64"), ("l_extendedprice", "double"),
+            ("l_discount", "double"), ("l_shipdate", "int64")],
+    "//o": [("o_orderkey", "int64", "ascending"), ("o_custkey", "int64"),
+            ("o_orderdate", "int64"), ("o_shippriority", "int64")],
+    "//c": [("c_custkey", "int64", "ascending"), ("c_mktsegment", "string")],
+}
+Q3 = (
+    "l_orderkey, sum(l_extendedprice * (1 - l_discount)) AS revenue, "
+    "o_orderdate, o_shippriority FROM [//l] JOIN [//o] ON l_orderkey = "
+    "o_orderkey JOIN [//c] ON o_custkey = c_custkey WHERE c_mktsegment = "
+    "'BUILDING' AND o_orderdate < 9204 AND l_shipdate > 9204 GROUP BY "
+    "l_orderkey, o_orderdate, o_shippriority ORDER BY "
+    "sum(l_extendedprice * (1 - l_discount)) DESC, o_orderdate LIMIT 10")
+
+
+def q3_tables(lines, orders, customers):
+    """TPC-H Q3's plan and its three tables (the columns it reads), every
+    line with one order and every order with one customer."""
+    from ytsaurus_tpu.chunks.columnar import ColumnarChunk
+    from ytsaurus_tpu.query.builder import build_query
+    from ytsaurus_tpu.schema import TableSchema
+    schemas = {path: TableSchema.make(columns)
+               for path, columns in Q3_SCHEMAS.items()}
+    row, order, customer = (np.arange(n) for n in (lines, orders, customers))
+    chunks = {
+        "//l": ColumnarChunk.from_arrays(schemas["//l"], {
+            "l_orderkey": row % orders, "l_extendedprice": row * 1.5,
+            "l_discount": (row % 11) / 100.0, "l_shipdate": 9000 + row % 400}),
+        "//o": ColumnarChunk.from_arrays(schemas["//o"], {
+            "o_orderkey": order, "o_custkey": order % customers,
+            "o_orderdate": 9000 + order % 400,
+            "o_shippriority": np.zeros(orders, dtype=np.int64)}),
+        "//c": ColumnarChunk.from_arrays(
+            schemas["//c"],
+            {"c_custkey": customer, "c_mktsegment": customer % 2},
+            dictionaries={"c_mktsegment": np.array(
+                [b"AUTOMOBILE", b"BUILDING"], dtype=object)}),
+    }
+    return build_query(Q3, schemas), chunks
+
+
+def q3_joined(plan, chunks, stages):
+    """The cascade's intermediate after `stages` joins, made on the CPU
+    as `evaluator._dispatch_traced` makes it."""
+    from ytsaurus_tpu.query.engine import evaluator, joins
+    from ytsaurus_tpu.schema import TableSchema
+    namespace = list(evaluator._initial_namespace(plan))
+    current = evaluator._project_chunk(chunks["//l"],
+                                       TableSchema.make(namespace))
+    for join in plan.joins[:stages]:
+        namespace = evaluator._extend_namespace(namespace, join)
+        current = joins.execute_join(current, TableSchema.make(namespace),
+                                     join, chunks[join.foreign_table], {})
+    return current
+
+
+def test_join_stage_2_phase_programs(one_chip, as_on_chip):
+    """The second stage of a cascade probes with a JOINED INTERMEDIATE
+    (16,384 slots, the first stage's output with both tables' columns),
+    not a staged table: its two programs compile for the chip."""
+    from test_tpch_join_deployment import join_phase_programs
+    plan, chunks = q3_tables(16_384, 4_096, 512)
+    intermediate = q3_joined(plan, chunks, stages=1)
+    assert intermediate.row_count == 16_384
+    assert "o_custkey" in intermediate.schema.column_names
+    phase1, args1, phase2, args2 = join_phase_programs(
+        plan.joins[1], intermediate, chunks["//c"])
+    compile_for(phase1, args1, one_chip)
+    compile_for(phase2, args2, one_chip)
+
+
+def test_three_key_group_two_key_order(one_chip, as_on_chip):
+    """TPC-H Q3's main program over the twice-joined rows: the filter on
+    all three tables' columns, a group by three int64 keys above the dense
+    limit (sort + sorted segment reduce), then ORDER BY a double DESC and
+    a date, LIMIT 10."""
+    from ytsaurus_tpu.query.engine.lowering import prepare
+    plan, chunks = q3_tables(131_072, 32_768, 4_096)
+    joined = q3_joined(plan, chunks, stages=2)
+    assert joined.row_count == 131_072 and joined.capacity == 131_072
+    prepared = prepare(plan, joined)
+    columns = {name: (column.data, column.valid)
+               for name, column in joined.columns.items()}
+    args = (columns, joined.row_valid, tuple(prepared.bindings))
+    compiled, _ = compile_for(prepared.run, args, one_chip)
+    text = compiled.as_text()
+    assert "ql.group" in text and "ql.order" in text
+
+
 def _segment_end_reference(starts):
     n = len(starts)
     out = np.empty(n, dtype=np.int64)

@@ -766,9 +766,20 @@ class Evaluator:
             # the fingerprint: the reordered plan's fingerprint is how
             # the order reaches the compile cache — stable stats hit the
             # same program, a stats-driven flip compiles a fresh one.
+            # The order is decided here, off the STAGED chunks, after
+            # the client's `query.plan` has closed: a second span of that
+            # name carries it (`join_order`: the foreign tables as they
+            # execute; `join_reordered`: the planner left the declared
+            # order).
             from ytsaurus_tpu.query import planner
-            plan, jplan = planner.reorder_for_chunks(
-                plan, chunk.row_count, foreign_chunks)
+            with child_span("query.plan") as plan_span:
+                declared = plan.joins
+                plan, jplan = planner.reorder_for_chunks(
+                    plan, chunk.row_count, foreign_chunks)
+                plan_span.add_tag("join_order", [
+                    join.foreign_table for join in plan.joins])
+                plan_span.add_tag("join_reordered",
+                                  plan.joins != declared)
         owned_chunk = False
         if isinstance(plan, ir.Query) and plan.joins:
             foreign_chunks = foreign_chunks or {}
@@ -790,7 +801,7 @@ class Evaluator:
                 # dispatch; phase 2 runs on under `evaluator.sync`.
                 t_join = _time.perf_counter()
                 with child_span("evaluator.join", table=join.foreign_table,
-                                self_rows=current.row_count,
+                                stage=pos, self_rows=current.row_count,
                                 foreign_rows=foreign.row_count) as join_span:
                     current = execute_join(
                         current, TableSchema.make(namespace), join, foreign,
@@ -798,7 +809,8 @@ class Evaluator:
                     join_span.add_tag("out_rows", current.row_count)
                 if stats is not None:
                     stats.joins_executed += 1
-                    stats.join_time += _time.perf_counter() - t_join
+                    stats.note_join_seconds(
+                        pos, _time.perf_counter() - t_join)
                     stats.join_rows_out += current.row_count
                     stats.note_join_stage(
                         pos, join.foreign_table, "local",

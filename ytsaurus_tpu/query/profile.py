@@ -169,14 +169,17 @@ def format_profile_dict(p: dict) -> str:
             f"of it in {stats.get('join_host_syncs', 0)} host syncs "
             f"between phases, {stats.get('join_rows_out', 0)} rows "
             f"materialized)")
+        stage_seconds = stats.get("join_stage_seconds") or []
         for i, entry in enumerate(join_stages):
             drift = est_drift(entry.get("est_rows", 0),
                               entry.get("actual_rows", 0))
+            seconds = f", {stage_seconds[i] * 1e3:.3f} ms" \
+                if i < len(stage_seconds) else ""
             lines.append(
                 f"  {i + 1}. {entry.get('table')} "
                 f"[{entry.get('strategy')}] est rows "
                 f"{entry.get('est_rows', 0)} -> actual "
-                f"{entry.get('actual_rows', 0)} (drift {drift})")
+                f"{entry.get('actual_rows', 0)} (drift {drift}{seconds})")
     # ISSUE 20: the mesh telemetry block(s) each SPMD program returned
     # stacked with its result — per-shard row spread (the skew answer),
     # exchange traffic with quota headroom, and the compile-time memory

@@ -41,11 +41,14 @@ class QueryStatistics:
     # the part spent in the blocking read of the match count between
     # the phases (phase 1's device time as the host sees it), one read
     # per stage (join_host_syncs); join_rows_out the rows the stages
-    # materialized for the main program to scan.
+    # materialized for the main program to scan; join_stage_seconds
+    # splits join_time by stage, one entry per stage in EXECUTION order
+    # (it accumulates across shard programs, as join_plan does).
     join_time: float = 0.0
     join_sync_time: float = 0.0
     join_host_syncs: int = 0
     join_rows_out: int = 0
+    join_stage_seconds: list = field(default_factory=list)
     # Whole-plan SPMD execution (ISSUE 12): 1 when the query was served
     # by the fused one-program rung (parallel/whole_plan.py); retries
     # count exchange-quota overflow re-runs (each a fresh pow2 rung of
@@ -123,6 +126,14 @@ class QueryStatistics:
         entry["est_rows"] += int(est_rows)
         if actual_rows is not None:
             entry["actual_rows"] += int(actual_rows)
+
+    def note_join_seconds(self, position: int, seconds: float) -> None:
+        """One executed join stage's host-clock seconds, into join_time
+        and into its position of join_stage_seconds."""
+        self.join_time += seconds
+        while len(self.join_stage_seconds) <= position:
+            self.join_stage_seconds.append(0.0)
+        self.join_stage_seconds[position] += seconds
 
     def to_dict(self) -> dict:
         out = {}
