@@ -402,7 +402,9 @@ def test_join_select_opens_the_join_spans(deployment):
     assert join.tags == {
         "table": "//tpch/orders", "stage": 0, "self_rows": SIZES["rows"],
         "foreign_rows": SIZES["orders"], "out_rows": SIZES["rows"],
-        "out_capacity": capacity, "cache": "hit"}
+        "out_capacity": capacity, "cache": "hit",
+        # Q12 reads 5 of the two tables' 25 columns after its join
+        "columns_out": 5, "columns_pruned": 20}
     assert join.duration >= sync.duration > 0
     # the join runs before the main program is looked up
     assert join.start_mono + join.duration <= \
@@ -449,12 +451,13 @@ def test_q1_opens_exactly_the_spans_it_opened_before(deployment):
     assert "join plan" not in profile.format()
 
 
-def join_phase_programs(join, chunk, foreign):
+def join_phase_programs(stage, chunk, foreign):
     """(phase1, args1, phase2, args2): the two jitted programs of one
-    join and what `execute_join` calls them with, caught on the way
-    through one real call with an empty program cache."""
-    from ytsaurus_tpu.query.engine import evaluator, joins
-    from ytsaurus_tpu.schema import TableSchema
+    join stage (an `ir.JoinStage`: the join and the namespace it
+    materializes, both cut to the live columns) and what `execute_join`
+    calls them with, caught on the way through one real call with an
+    empty program cache."""
+    from ytsaurus_tpu.query.engine import joins
     caught = []
     build = joins._build_join_programs
 
@@ -468,28 +471,36 @@ def join_phase_programs(join, chunk, foreign):
             return call
         return catch(phase1), lambda cap: catch(make_phase2(cap))
 
-    namespace = evaluator._extend_namespace(
-        [(c.name, c.type.value) for c in chunk.schema], join)
     with mock.patch.object(joins, "_build_join_programs", catching):
-        joins.execute_join(chunk, TableSchema.make(namespace), join,
-                           foreign, {})
+        joins.execute_join(chunk, stage.schema, stage.join, foreign, {})
     return tuple(caught)
 
 
+def cascade_intermediate(plan, chunk, foreign_chunks, stages):
+    """(the plan's `ir.JoinCascade`, its intermediate after `stages`
+    joins), made as `evaluator._dispatch_traced` makes it: the FROM
+    chunk projected to `from_schema`, then one `execute_join` a stage."""
+    from ytsaurus_tpu.query import ir
+    from ytsaurus_tpu.query.engine import evaluator, joins
+    cascade = ir.join_cascade(plan)
+    current = evaluator._project_chunk(chunk, cascade.from_schema)
+    for stage in cascade.stages[:stages]:
+        current = joins.execute_join(
+            current, stage.schema, stage.join,
+            foreign_chunks[stage.join.foreign_table], {})
+    return cascade, current
+
+
 def q12_join_inputs(client, driver):
-    """(join clause, probe chunk, foreign chunk) of the cell's query over
+    """(join stage, probe chunk, foreign chunk) of the cell's query over
     the tables `client` holds."""
     from ytsaurus_tpu.client import _SchemaResolver
     from ytsaurus_tpu.query.builder import build_query
-    from ytsaurus_tpu.query.engine import evaluator
-    from ytsaurus_tpu.schema import TableSchema
     plan = build_query(q12(driver)["ql"], _SchemaResolver(client))
-    (join,) = plan.joins
     lines = client._query_shards("//tpch/lineitem", 2 ** 62)[0]
     orders = client._query_shards("//tpch/orders", 2 ** 62)[0]
-    probe = evaluator._project_chunk(
-        lines, TableSchema.make(evaluator._initial_namespace(plan)))
-    return join, probe, orders
+    cascade, probe = cascade_intermediate(plan, lines, {}, stages=0)
+    return cascade.stages[0], probe, orders
 
 
 def test_join_scopes_name_the_phase_programs(deployment):

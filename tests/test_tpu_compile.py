@@ -212,7 +212,8 @@ def test_join_phase_programs(one_chip, as_on_chip):
     4,096 orders.  At the benchmark cell's capacities (1,048,576 /
     262,144) the network sort's compile alone is minutes: PERF.md holds
     those seconds, no test does."""
-    from test_tpch_join_deployment import join_phase_programs
+    from test_tpch_join_deployment import (
+        cascade_intermediate, join_phase_programs)
     from ytsaurus_tpu.chunks.columnar import ColumnarChunk
     from ytsaurus_tpu.query.builder import build_query
     from ytsaurus_tpu.schema import TableSchema
@@ -229,8 +230,9 @@ def test_join_phase_programs(one_chip, as_on_chip):
         "sum(l_quantity + o_shippriority) AS s FROM [//l] JOIN [//o] "
         "ON l_orderkey = o_orderkey GROUP BY 1",
         {"//l": l_schema, "//o": o_schema})
+    cascade, probe = cascade_intermediate(plan, probe, {}, stages=0)
     phase1, args1, phase2, args2 = join_phase_programs(
-        plan.joins[0], probe, foreign)
+        cascade.stages[0], probe, foreign)
     compile_for(phase1, args1, one_chip)
     compile_for(phase2, args2, one_chip)
 
@@ -277,32 +279,20 @@ def q3_tables(lines, orders, customers):
     return build_query(Q3, schemas), chunks
 
 
-def q3_joined(plan, chunks, stages):
-    """The cascade's intermediate after `stages` joins, made on the CPU
-    as `evaluator._dispatch_traced` makes it."""
-    from ytsaurus_tpu.query.engine import evaluator, joins
-    from ytsaurus_tpu.schema import TableSchema
-    namespace = list(evaluator._initial_namespace(plan))
-    current = evaluator._project_chunk(chunks["//l"],
-                                       TableSchema.make(namespace))
-    for join in plan.joins[:stages]:
-        namespace = evaluator._extend_namespace(namespace, join)
-        current = joins.execute_join(current, TableSchema.make(namespace),
-                                     join, chunks[join.foreign_table], {})
-    return current
-
-
 def test_join_stage_2_phase_programs(one_chip, as_on_chip):
     """The second stage of a cascade probes with a JOINED INTERMEDIATE
-    (16,384 slots, the first stage's output with both tables' columns),
-    not a staged table: its two programs compile for the chip."""
-    from test_tpch_join_deployment import join_phase_programs
+    (16,384 slots, the first stage's output: the columns of both tables
+    that are live after it), not a staged table: its two programs compile
+    for the chip."""
+    from test_tpch_join_deployment import (
+        cascade_intermediate, join_phase_programs)
     plan, chunks = q3_tables(16_384, 4_096, 512)
-    intermediate = q3_joined(plan, chunks, stages=1)
+    cascade, intermediate = cascade_intermediate(
+        plan, chunks["//l"], chunks, stages=1)
     assert intermediate.row_count == 16_384
     assert "o_custkey" in intermediate.schema.column_names
     phase1, args1, phase2, args2 = join_phase_programs(
-        plan.joins[1], intermediate, chunks["//c"])
+        cascade.stages[1], intermediate, chunks["//c"])
     compile_for(phase1, args1, one_chip)
     compile_for(phase2, args2, one_chip)
 
@@ -312,11 +302,13 @@ def test_three_key_group_two_key_order(one_chip, as_on_chip):
     all three tables' columns, a group by three int64 keys above the dense
     limit (sort + sorted segment reduce), then ORDER BY a double DESC and
     a date, LIMIT 10."""
+    from test_tpch_join_deployment import cascade_intermediate
     from ytsaurus_tpu.query.engine.lowering import prepare
     plan, chunks = q3_tables(131_072, 32_768, 4_096)
-    joined = q3_joined(plan, chunks, stages=2)
+    cascade, joined = cascade_intermediate(
+        plan, chunks["//l"], chunks, stages=2)
     assert joined.row_count == 131_072 and joined.capacity == 131_072
-    prepared = prepare(plan, joined)
+    prepared = prepare(cascade.query, joined)
     columns = {name: (column.data, column.valid)
                for name, column in joined.columns.items()}
     args = (columns, joined.row_valid, tuple(prepared.bindings))

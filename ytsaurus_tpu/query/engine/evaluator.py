@@ -783,18 +783,23 @@ class Evaluator:
         owned_chunk = False
         if isinstance(plan, ir.Query) and plan.joins:
             foreign_chunks = foreign_chunks or {}
-            # Materialize joins in (planner) execution order, widening
-            # the namespace; each stage's actual cardinality folds into
-            # the EXPLAIN ANALYZE join plan next to the estimate.
-            namespace = list(_initial_namespace(plan))
-            current = _project_chunk(chunk, TableSchema.make(namespace))
+            # Materialize joins in (planner) execution order.  Each
+            # stage carries only the columns that are live after it
+            # (ir.join_cascade), and the main program is dispatched with
+            # the plan cut the same way: its prepare, cache key and args
+            # see the chunk they get.  Each stage's actual cardinality
+            # folds into the EXPLAIN ANALYZE join plan next to the
+            # estimate.
+            cascade = ir.join_cascade(plan)
+            plan = cascade.query
+            current = _project_chunk(chunk, cascade.from_schema)
             decisions = jplan.decisions if jplan is not None else None
-            for pos, join in enumerate(plan.joins):
+            for pos, stage in enumerate(cascade.stages):
+                join = stage.join
                 if join.foreign_table not in foreign_chunks:
                     raise YtError(
                         f"No data provided for join table {join.foreign_table!r}",
                         code=EErrorCode.QueryExecutionError)
-                namespace = _extend_namespace(namespace, join)
                 foreign = foreign_chunks[join.foreign_table]
                 # One span per join stage: key bind, phase 1's dispatch
                 # and wait (its child `join.count_sync`), phase 2's
@@ -802,9 +807,12 @@ class Evaluator:
                 t_join = _time.perf_counter()
                 with child_span("evaluator.join", table=join.foreign_table,
                                 stage=pos, self_rows=current.row_count,
-                                foreign_rows=foreign.row_count) as join_span:
+                                foreign_rows=foreign.row_count,
+                                columns_out=len(stage.schema),
+                                columns_pruned=stage.columns_pruned
+                                ) as join_span:
                     current = execute_join(
-                        current, TableSchema.make(namespace), join, foreign,
+                        current, stage.schema, join, foreign,
                         self._join_cache, stats=stats, span=join_span)
                     join_span.add_tag("out_rows", current.row_count)
                 if stats is not None:
@@ -816,7 +824,9 @@ class Evaluator:
                         pos, join.foreign_table, "local",
                         est_rows=decisions[pos].est_out
                         if decisions is not None else 0,
-                        actual_rows=current.row_count)
+                        actual_rows=current.row_count,
+                        columns_out=len(stage.schema),
+                        columns_pruned=stage.columns_pruned)
             chunk = current
             # The cascade built `chunk`; this dispatch is its only
             # consumer, so its column planes are donatable (a totals
@@ -1136,24 +1146,6 @@ class Evaluator:
             else:
                 stats.compile_new_fingerprint += 1
         return fn, compile_seconds, result
-
-
-def _initial_namespace(plan: ir.Query) -> list[tuple[str, str]]:
-    """Self-table columns = plan.schema minus columns contributed by joins."""
-    joined = set()
-    for join in plan.joins:
-        for fname in join.foreign_columns:
-            joined.add(f"{join.alias}.{fname}" if join.alias else fname)
-    return [(c.name, c.type.value) for c in plan.schema if c.name not in joined]
-
-
-def _extend_namespace(namespace: list[tuple[str, str]],
-                      join: ir.JoinClause) -> list[tuple[str, str]]:
-    out = list(namespace)
-    for fname in join.foreign_columns:
-        flat = f"{join.alias}.{fname}" if join.alias else fname
-        out.append((flat, join.foreign_schema.get(fname).type.value))
-    return out
 
 
 def _project_chunk(chunk: ColumnarChunk, schema: TableSchema) -> ColumnarChunk:

@@ -176,7 +176,10 @@ def execute_join(chunk: ColumnarChunk, combined_schema: TableSchema,
                  cache: dict, stats=None, span=NULL_SPAN) -> ColumnarChunk:
     """Materialize `chunk ⋈ foreign_chunk` into a wider columnar chunk.
 
-    `combined_schema` is the namespace *after* this join (flat names);
+    `combined_schema` is the namespace *after* this join (flat names):
+    the columns of `chunk` it names and `join.foreign_columns` are the
+    ones phase 2 expands (`ir.join_cascade` leaves out what nothing
+    reads afterwards, the stage's own key among them);
     `cache` holds the compiled phase programs (owned by the Evaluator so
     lifetime/clearing follow the plan cache).  `stats` (a
     QueryStatistics) counts the host sync between the phases and its
@@ -184,6 +187,7 @@ def execute_join(chunk: ColumnarChunk, combined_schema: TableSchema,
     the program lookup ended and with the output capacity.
     """
     self_schema = chunk.schema
+    self_names = [c.name for c in self_schema if c.name in combined_schema]
     all_bindings: list = []
     bind_structure: list = []
     self_bound = _bind_keys(chunk, self_schema, join.self_equations,
@@ -226,7 +230,7 @@ def execute_join(chunk: ColumnarChunk, combined_schema: TableSchema,
         for b in list(self_bound) + list(f_bound))
     cache_key = (_join_fingerprint(join), chunk.capacity,
                  foreign_chunk.capacity,
-                 tuple(c.name for c in self_schema),
+                 tuple(c.name for c in self_schema), tuple(self_names),
                  vocab_structure,
                  # Bind-phase structure notebook (ISSUE 10): host
                  # constants the equation binds BAKE (concat's nb
@@ -240,7 +244,7 @@ def execute_join(chunk: ColumnarChunk, combined_schema: TableSchema,
         entry = _build_join_programs(
             self_bound, f_bound, self_slots, foreign_slots,
             chunk.capacity, foreign_chunk.capacity, join.is_left,
-            [c.name for c in self_schema], list(join.foreign_columns))
+            self_names, list(join.foreign_columns))
         cache[cache_key] = entry
     phase1, make_phase2 = entry
 
@@ -271,7 +275,8 @@ def execute_join(chunk: ColumnarChunk, combined_schema: TableSchema,
 
     columns: dict[str, Column] = {}
     self_row_np = None
-    for name, col in chunk.columns.items():
+    for name in self_names:
+        col = chunk.columns[name]
         data, valid = out_planes["self"][name]
         host_values = None
         if col.host_values is not None:

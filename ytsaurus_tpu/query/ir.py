@@ -14,7 +14,7 @@ fingerprint (library/query/engine/folding_profiler.cpp).
 from __future__ import annotations
 
 import hashlib
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Optional
 
 from ytsaurus_tpu.schema import EValueType, TableSchema
@@ -286,6 +286,72 @@ class Query:
             # front stage can still reference them.
             cols += [(w.name, w.type.value) for w in self.window.items]
         return TableSchema.make(cols)
+
+
+@dataclass(frozen=True)
+class JoinStage:
+    """One stage of a join cascade, cut to the columns that are live
+    after it (`join_cascade`)."""
+    join: JoinClause           # foreign_columns: the live ones only
+    schema: TableSchema        # the namespace the stage materializes
+    columns_pruned: int        # columns of the full namespace left behind
+
+
+@dataclass(frozen=True)
+class JoinCascade:
+    from_schema: TableSchema   # what the FROM chunk is projected to
+    stages: tuple[JoinStage, ...]
+    query: Query               # the plan over the last stage's namespace
+
+
+def join_cascade(query: Query) -> JoinCascade:
+    """Column liveness through a plan's joins, taken in the order they
+    stand in (execution order: after planner.reorder_for_chunks).
+
+    A join plan's schema names every column of every table; a stage
+    that materializes them all gathers planes nobody reads.  Live after
+    stage k is what the clauses above the joins read (WHERE, GROUP BY,
+    aggregates, HAVING, windows, ORDER BY, the projection) plus the
+    self keys of the stages after k: a column that is only a later
+    stage's key is dropped after that stage, and a stage's own key is
+    carried out of it only when a clause reads it.  A bare select (no
+    projection, no grouping: `referenced_columns` is None) reads every
+    column and keeps every column.  Every schema is a cut of
+    `query.schema`, in its order."""
+    def flat(join, name):
+        return f"{join.alias}.{name}" if join.alias else name
+
+    reads = referenced_columns(replace(query, joins=()))
+    # live[0] cuts the FROM chunk, live[k + 1] stage k's output.
+    live = [reads]
+    for join in reversed(query.joins):
+        keys = {name for eq in join.self_equations
+                for name in expr_references(eq)}
+        live.append(None if reads is None else live[-1] | keys)
+    live.reverse()
+    pulled = [{flat(join, name) for name in join.foreign_columns}
+              for join in query.joins]
+    present = {c.name for c in query.schema}.difference(*pulled)
+
+    def cut(wanted) -> TableSchema:
+        return TableSchema.make(
+            [c for c in query.schema
+             if c.name in present and (wanted is None or c.name in wanted)])
+
+    schema = from_schema = cut(live[0])
+    stages = []
+    for k, join in enumerate(query.joins):
+        present |= pulled[k]
+        schema = cut(live[k + 1])
+        stages.append(JoinStage(
+            join=replace(join, foreign_columns=tuple(
+                name for name in join.foreign_columns
+                if flat(join, name) in schema)),
+            schema=schema, columns_pruned=len(present) - len(schema)))
+    return JoinCascade(
+        from_schema=from_schema, stages=tuple(stages),
+        query=replace(query, schema=schema,
+                      joins=tuple(stage.join for stage in stages)))
 
 
 @dataclass(frozen=True)

@@ -175,11 +175,15 @@ def format_profile_dict(p: dict) -> str:
                               entry.get("actual_rows", 0))
             seconds = f", {stage_seconds[i] * 1e3:.3f} ms" \
                 if i < len(stage_seconds) else ""
+            columns = (f", {entry['columns_out']} columns out / "
+                       f"{entry['columns_pruned']} pruned") \
+                if "columns_out" in entry else ""
             lines.append(
                 f"  {i + 1}. {entry.get('table')} "
                 f"[{entry.get('strategy')}] est rows "
                 f"{entry.get('est_rows', 0)} -> actual "
-                f"{entry.get('actual_rows', 0)} (drift {drift}{seconds})")
+                f"{entry.get('actual_rows', 0)} "
+                f"(drift {drift}{columns}{seconds})")
     # ISSUE 20: the mesh telemetry block(s) each SPMD program returned
     # stacked with its result — per-shard row spread (the skew answer),
     # exchange traffic with quota headroom, and the compile-time memory

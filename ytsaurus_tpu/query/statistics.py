@@ -43,12 +43,18 @@ class QueryStatistics:
     # per stage (join_host_syncs); join_rows_out the rows the stages
     # materialized for the main program to scan; join_stage_seconds
     # splits join_time by stage, one entry per stage in EXECUTION order
-    # (it accumulates across shard programs, as join_plan does).
+    # (it accumulates across shard programs, as join_plan does);
+    # join_columns_out the columns the stages materialized and
+    # join_columns_pruned the columns of the plan's full namespace they
+    # left behind because nothing reads them afterwards
+    # (ir.join_cascade), summed over the stages.
     join_time: float = 0.0
     join_sync_time: float = 0.0
     join_host_syncs: int = 0
     join_rows_out: int = 0
     join_stage_seconds: list = field(default_factory=list)
+    join_columns_out: int = 0
+    join_columns_pruned: int = 0
     # Whole-plan SPMD execution (ISSUE 12): 1 when the query was served
     # by the fused one-program rung (parallel/whole_plan.py); retries
     # count exchange-quota overflow re-runs (each a fresh pow2 rung of
@@ -115,7 +121,8 @@ class QueryStatistics:
             self.mesh_memory_watermark_bytes, watermark)
 
     def note_join_stage(self, position: int, table: str, strategy: str,
-                        est_rows: int = 0, actual_rows=None) -> None:
+                        est_rows: int = 0, actual_rows=None,
+                        columns_out=None, columns_pruned: int = 0) -> None:
         while len(self.join_plan) <= position:
             self.join_plan.append(None)
         entry = self.join_plan[position]
@@ -126,6 +133,13 @@ class QueryStatistics:
         entry["est_rows"] += int(est_rows)
         if actual_rows is not None:
             entry["actual_rows"] += int(actual_rows)
+        if columns_out is not None:
+            # The local cascade's stages say what they materialized; a
+            # stage has the same columns in every shard program.
+            entry["columns_out"] = columns_out
+            entry["columns_pruned"] = columns_pruned
+            self.join_columns_out += columns_out
+            self.join_columns_pruned += columns_pruned
 
     def note_join_seconds(self, position: int, seconds: float) -> None:
         """One executed join stage's host-clock seconds, into join_time
