@@ -206,6 +206,88 @@ def test_dynamic_table_mvcc_program(one_chip, as_on_chip):
     compile_mvcc_visible(1_000_000, one_chip)
 
 
+def dynamic_lineitem_schema():
+    """The benchmark's dynamic LINEITEM (all 16 columns, keyed on
+    l_orderkey, l_linenumber) as its configuration states it."""
+    import json
+    import os
+
+    from ytsaurus_tpu.schema import TableSchema
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "tpch-lineitem-dynamic-8t.json")
+    with open(path) as f:
+        columns = json.load(f)["columns"]
+    return TableSchema.make(
+        [(c["name"], c["type"], c["sort_order"]) if c.get("sort_order")
+         else (c["name"], c["type"]) for c in columns], unique_keys=True)
+
+
+def test_dynamic_lineitem_mvcc_program(one_chip, as_on_chip):
+    """The dynamic LINEITEM cell's snapshot merge at one tablet's
+    capacity, 131,072 versions (two versioned chunks and a store, ~75,000
+    versions): two key columns, fourteen value columns and their written
+    flags."""
+    from ytsaurus_tpu.chunks.columnar import _plane_dtype
+    from ytsaurus_tpu.tablet import mvcc
+    from ytsaurus_tpu.tablet.tablet import versioned_schema
+    schema = dynamic_lineitem_schema()
+    capacity = 131_072
+    planes = {c.name: (np.zeros(capacity, _plane_dtype(c.type)),
+                       np.zeros(capacity, bool))
+              for c in versioned_schema(schema)}
+    builder = mvcc._build_visible(
+        tuple(schema.key_column_names),
+        tuple(c.name for c in schema if c.sort_order is None), capacity)
+    compile_for(builder, (planes, np.int64(capacity), np.int64(1 << 60)),
+                one_chip)
+
+
+def test_q1_over_coalesced_tablets(one_chip, as_on_chip):
+    """Q1 over what the coordinator's fan-in hands it in the dynamic
+    cell: the 8 tablet snapshots concatenated, 600,572 rows of all 16
+    columns in a capacity of 1,048,576."""
+    from ytsaurus_tpu.chunks.columnar import ColumnarChunk
+    from ytsaurus_tpu.models import tpch
+    from ytsaurus_tpu.schema import EValueType
+    schema = dynamic_lineitem_schema().to_unsorted()
+    rows = 600_572
+    vocabs = {"l_returnflag": [b"A", b"N", b"R"], "l_linestatus": [b"F", b"O"]}
+    arrays, dictionaries = {}, {}
+    for c in schema:
+        if c.type is EValueType.string:
+            vocab = vocabs.get(c.name, [b"x"])
+            arrays[c.name] = np.arange(rows) % len(vocab)
+            dictionaries[c.name] = np.array(vocab, dtype=object)
+        elif c.type is EValueType.double:
+            arrays[c.name] = np.ones(rows)
+        else:
+            arrays[c.name] = np.arange(rows)
+    chunk = ColumnarChunk.from_arrays(schema, arrays,
+                                      dictionaries=dictionaries)
+    assert chunk.capacity == 1_048_576
+    compiled, _ = compile_query(tpch.Q1, {"//tpch/lineitem": schema}, chunk,
+                                one_chip)
+    assert "ql.group" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.int64, jnp.int32, jnp.bool_],
+                         ids=["int64", "codes", "flags"])
+def test_fan_in_concat_at_cell_capacity(one_chip, dtype):
+    """The fan-in's concatenation of one column in the dynamic cell: 8
+    snapshots of 131,072 slots into 1,048,576, one program whatever the
+    tablets' row counts (chunks/columnar._concat_planes)."""
+    from ytsaurus_tpu.chunks.columnar import _concat_planes
+    parts = tuple(jax.ShapeDtypeStruct((131_072,), dtype) for _ in range(8))
+    flags = tuple(jax.ShapeDtypeStruct((131_072,), jnp.bool_)
+                  for _ in range(8))
+    compile_for(
+        lambda d, v, o, t: _concat_planes(d, v, o, t, capacity=1_048_576,
+                                          dtype=np.dtype(dtype)),
+        (parts, flags, jax.ShapeDtypeStruct((8,), jnp.int32),
+         jax.ShapeDtypeStruct((), jnp.int32)), one_chip)
+
+
 def test_join_phase_programs(one_chip, as_on_chip):
     """`execute_join`'s two device programs (phase 1: network sort of the
     foreign keys, two searches, count; phase 2: expand) at 16,384 lines /

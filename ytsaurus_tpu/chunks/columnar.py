@@ -601,10 +601,46 @@ def unify_dictionaries(columns: Sequence[Column]) -> tuple[list[Column], np.ndar
             if len(old_vocab) else np.array([], dtype=np.int32)
         if len(remap_np) == 0:
             remap_np = np.zeros(1, dtype=np.int32)
-        remap = jnp.asarray(remap_np)
-        new_codes = remap[jnp.clip(col.data, 0, len(remap_np) - 1)]
-        out.append(replace(col, data=new_codes.astype(jnp.int32), dictionary=merged))
+        table = np.zeros(next_pow2(len(remap_np)), dtype=np.int32)
+        table[:len(remap_np)] = remap_np
+        new_codes = _remap_codes(jnp.asarray(table), col.data,
+                                 np.int32(len(remap_np) - 1))
+        out.append(replace(col, data=new_codes, dictionary=merged))
     return out, merged
+
+
+@jax.jit
+def _remap_codes(table: jax.Array, codes: jax.Array,
+                 last: jax.Array) -> jax.Array:
+    """codes -> table[codes], each code clipped into the table's first
+    `last + 1` entries.  The table is padded to a power of two, so the
+    program is keyed on that bucket and on the plane's capacity, not on
+    the vocabulary's exact length."""
+    return table[jnp.clip(codes, 0, last)].astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("capacity", "dtype"))
+def _concat_planes(datas: tuple, valids: tuple, offsets: jax.Array,
+                   total: jax.Array, capacity: int, dtype) -> tuple:
+    """Planes of `capacity` rows holding each part's first rows from
+    offsets[i] on, in one program keyed on the parts' capacities and not
+    on their row counts.  Each part is written whole, padding included,
+    in order: its padding lands where the next part is written after it,
+    or at or past `total`, where the output is zero and invalid."""
+    trailing = datas[0].shape[1:]
+    spare = max(d.shape[0] for d in datas)
+    data = jnp.zeros((capacity + spare,) + trailing, dtype=dtype)
+    valid = jnp.zeros(capacity + spare, dtype=bool)
+    rest = (jnp.zeros((), offsets.dtype),) * len(trailing)
+    for i, (part, part_valid) in enumerate(zip(datas, valids)):
+        data = jax.lax.dynamic_update_slice(
+            data, part.astype(dtype), (offsets[i],) + rest)
+        valid = jax.lax.dynamic_update_slice(
+            valid, part_valid.astype(bool), (offsets[i],))
+    live = jnp.arange(capacity) < total
+    data = jnp.where(live.reshape((capacity,) + (1,) * len(trailing)),
+                     data[:capacity], jnp.zeros((), dtype=dtype))
+    return data, valid[:capacity] & live
 
 
 # Bound on string min/max stat values stored in chunk meta.  chunk_may_match
@@ -935,7 +971,10 @@ def chunk_column_stats(chunk: ColumnarChunk) -> dict:
 
 
 def concat_chunks(chunks: Sequence[ColumnarChunk]) -> ColumnarChunk:
-    """Concatenate chunks of identical schema into one (device concat + repad)."""
+    """Concatenate chunks of identical schema into one (device concat + repad).
+    The device work is compiled per capacity bucket of the parts and of the
+    result, whatever their row counts: chunks of new row totals at known
+    capacities compile nothing."""
     if not chunks:
         raise YtError("concat_chunks: empty input")
     if len(chunks) == 1:
@@ -947,6 +986,8 @@ def concat_chunks(chunks: Sequence[ColumnarChunk]) -> ColumnarChunk:
                           code=EErrorCode.ChunkFormatError)
     total = sum(c.row_count for c in chunks)
     cap = pad_capacity(max(total, 1))
+    offsets = np.cumsum([0] + [c.row_count for c in chunks[:-1]],
+                        dtype=np.int32)
     columns: dict[str, Column] = {}
     for col_schema in schema:
         name = col_schema.name
@@ -954,16 +995,10 @@ def concat_chunks(chunks: Sequence[ColumnarChunk]) -> ColumnarChunk:
         vocab = None
         if col_schema.type is EValueType.string:
             cols, vocab = unify_dictionaries(cols)
-        data_parts, valid_parts = [], []
-        for chunk, col in zip(chunks, cols):
-            data_parts.append(col.data[: chunk.row_count])
-            valid_parts.append(col.valid[: chunk.row_count])
-        dt = _plane_dtype(col_schema.type)
-        trailing = (col_schema.type.dim,) \
-            if isinstance(col_schema.type, VectorType) else ()
-        data = jnp.zeros((cap,) + trailing, dtype=dt).at[:total].set(
-            jnp.concatenate(data_parts))
-        valid = jnp.zeros(cap, dtype=bool).at[:total].set(jnp.concatenate(valid_parts))
+        data, valid = _concat_planes(
+            tuple(col.data for col in cols), tuple(col.valid for col in cols),
+            offsets, np.int32(total), capacity=cap,
+            dtype=_plane_dtype(col_schema.type))
         host_values = None
         if col_schema.type is EValueType.any:
             host_values = []

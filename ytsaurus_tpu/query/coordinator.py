@@ -441,7 +441,7 @@ def coordinate_and_execute(
             # Bare LIMIT (or no early exit): full coalescing — a
             # selective WHERE may scan everything, so dispatch overhead
             # dominates and the early exit still skips whole groups.
-            chunks = _coalesce_shards(chunks, merge_shards_below)
+            chunks = _coalesce_shards(chunks, merge_shards_below, stats)
         else:
             # Ordered exit: the scan is expected to stop after ~needed
             # rows, so a group only needs to hold the scan budget —
@@ -449,7 +449,7 @@ def coordinate_and_execute(
             # program and forfeit the skip.  (A selective WHERE on an
             # ordered scan pays per-shard dispatch; that is the price
             # of being able to stop at all.)
-            chunks = _coalesce_shards(chunks, max(needed, 1))
+            chunks = _coalesce_shards(chunks, max(needed, 1), stats)
     if stats is not None:
         stats.shards_total += len(chunks)
         if not lazy:
@@ -601,19 +601,42 @@ def coordinate_and_execute(
 
 
 def _coalesce_shards(chunks: Sequence[ColumnarChunk],
-                     min_rows: int) -> list[ColumnarChunk]:
-    groups: list[list[ColumnarChunk]] = []
-    current: list[ColumnarChunk] = []
-    current_rows = 0
-    for chunk in chunks:
-        current.append(chunk)
-        current_rows += chunk.row_count
-        if current_rows >= min_rows:
-            groups.append(current)
-            current, current_rows = [], 0
-    if current:
-        if groups:
-            groups[-1].extend(current)
-        else:
-            groups.append(current)
-    return [concat_chunks(g) if len(g) > 1 else g[0] for g in groups]
+                     min_rows: int, stats=None) -> list[ColumnarChunk]:
+    """The fan-in: consecutive shards concatenated (planes and string
+    dictionaries, every column) until each group holds `min_rows`.
+    Its seconds go to `stats.coalesce_time`, the shards it concatenated
+    to `stats.shards_coalesced`, and a `coordinator.coalesce` span."""
+    t0 = time.perf_counter()
+    with child_span("coordinator.coalesce",
+                    shards_in=len(chunks)) as span:
+        groups: list[list[ColumnarChunk]] = []
+        current: list[ColumnarChunk] = []
+        current_rows = 0
+        for chunk in chunks:
+            current.append(chunk)
+            current_rows += chunk.row_count
+            if current_rows >= min_rows:
+                groups.append(current)
+                current, current_rows = [], 0
+        if current:
+            if groups:
+                groups[-1].extend(current)
+            else:
+                groups.append(current)
+        out = [concat_chunks(g) if len(g) > 1 else g[0] for g in groups]
+        merged = [c for g in groups if len(g) > 1 for c in g]
+        if span.sampled:
+            strings = [c.name for c in chunks[0].schema
+                       if c.type is EValueType.string]
+            span.add_tag("groups_out", len(out))
+            span.add_tag("rows", sum(c.row_count for c in chunks))
+            span.add_tag("columns", len(chunks[0].schema))
+            span.add_tag("string_columns", len(strings))
+            span.add_tag("vocab_entries", sum(
+                len(c.columns[name].dictionary) for c in merged
+                for name in strings
+                if c.columns[name].dictionary is not None))
+    if stats is not None:
+        stats.coalesce_time += time.perf_counter() - t0
+        stats.shards_coalesced += len(merged)
+    return out

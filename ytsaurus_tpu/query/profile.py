@@ -136,6 +136,14 @@ def format_profile_dict(p: dict) -> str:
         # back to the decoded remap-table path.
         f"execution: {stats.get('execution_encoding', 'encoded')}",
     ]
+    # Tablet snapshots and the coordinator's fan-in: what a select over
+    # a dynamic table pays on the host before its program runs.
+    if stats.get("snapshot_time") or stats.get("shards_coalesced"):
+        lines.append(
+            f"fan-in: {stats.get('shards_coalesced', 0)} shards coalesced "
+            f"in {_ms(stats.get('coalesce_time', 0.0))}; tablet snapshots "
+            f"{_ms(stats.get('snapshot_time', 0.0))} "
+            f"({stats.get('snapshot_cache_misses', 0)} merged anew)")
     # ISSUE 8: why those misses happened (new fingerprint vs new shape
     # vs eviction) and which pow2 capacity buckets the programs ran
     # against — per-query bucket churn is a shape-spectrum leak.

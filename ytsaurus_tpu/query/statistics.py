@@ -55,6 +55,18 @@ class QueryStatistics:
     join_stage_seconds: list = field(default_factory=list)
     join_columns_out: int = 0
     join_columns_pruned: int = 0
+    # What a select over a sorted dynamic table costs before its program
+    # runs (host clock): snapshot_time is the seconds inside
+    # Tablet.read_snapshot, lock wait and MVCC merge included, summed
+    # over the tablets; snapshot_cache_misses the read-latest snapshots
+    # that had to be merged anew; coalesce_time the seconds inside the
+    # coordinator's fan-in (coordinator._coalesce_shards: concat_chunks
+    # and its dictionary unions); shards_coalesced the shards that went
+    # into a concatenation there.
+    snapshot_time: float = 0.0
+    snapshot_cache_misses: int = 0
+    coalesce_time: float = 0.0
+    shards_coalesced: int = 0
     # Whole-plan SPMD execution (ISSUE 12): 1 when the query was served
     # by the fused one-program rung (parallel/whole_plan.py); retries
     # count exchange-quota overflow re-runs (each a fresh pow2 rung of

@@ -457,7 +457,9 @@ class Tablet:
         """Materialize the tablet contents as of `timestamp` into a plain
         columnar chunk (the select_rows input).  `stats` (the calling
         query's QueryStatistics) takes the merge program's compile
-        seconds when this read is the first to need it.
+        seconds when this read is the first to need it, the read's
+        seconds (`snapshot_time`) and its cache miss
+        (`snapshot_cache_misses`).
 
         Columnar MVCC pipeline (tablet/mvcc.py): versioned chunk planes
         and store-ingested planes concatenate on device, one packed
@@ -467,32 +469,39 @@ class Tablet:
         repeated selects skip the merge entirely until the next
         write/flush/compact."""
         t_lock = time.perf_counter()
-        with child_span("tablet.read_snapshot") as span, self._lock:
-            # The wait for the tablet's lock apart from the work under it.
-            span.add_tag("lock_wait_s",
-                         round(time.perf_counter() - t_lock, 6))
-            generation = self._generation()
-            latest = timestamp >= self._latest_ts_floor()
-            if latest:
-                cached = self._snapshot_cache
-                if cached is not None and cached[0] == generation:
-                    _SNAP_HITS.increment()
-                    span.add_tag("snapshot_cache", "hit")
-                    span.add_tag("rows", cached[1].row_count)
-                    return cached[1]
-                _SNAP_MISSES.increment()
-            span.add_tag("snapshot_cache",
-                         "miss" if latest else "bypass")
-            chunk = self._read_snapshot_uncached(timestamp, stats)
-            span.add_tag("rows", chunk.row_count)
-            if latest and tablet_config().snapshot_cache_enabled:
-                if self._snapshot_cache is not None:
-                    _SNAP_EVICTIONS.increment()
-                    _snap_bytes_add(-_chunk_nbytes(self._snapshot_cache[1]))
-                self._snapshot_cache = (generation, chunk,
-                                        time.monotonic())
-                _snap_bytes_add(_chunk_nbytes(chunk))
-            return chunk
+        try:
+            with child_span("tablet.read_snapshot") as span, self._lock:
+                # The wait for the tablet's lock apart from the work under it.
+                span.add_tag("lock_wait_s",
+                             round(time.perf_counter() - t_lock, 6))
+                generation = self._generation()
+                latest = timestamp >= self._latest_ts_floor()
+                if latest:
+                    cached = self._snapshot_cache
+                    if cached is not None and cached[0] == generation:
+                        _SNAP_HITS.increment()
+                        span.add_tag("snapshot_cache", "hit")
+                        span.add_tag("rows", cached[1].row_count)
+                        return cached[1]
+                    _SNAP_MISSES.increment()
+                    if stats is not None:
+                        stats.snapshot_cache_misses += 1
+                span.add_tag("snapshot_cache",
+                             "miss" if latest else "bypass")
+                chunk = self._read_snapshot_uncached(timestamp, stats)
+                span.add_tag("rows", chunk.row_count)
+                if latest and tablet_config().snapshot_cache_enabled:
+                    if self._snapshot_cache is not None:
+                        _SNAP_EVICTIONS.increment()
+                        _snap_bytes_add(
+                            -_chunk_nbytes(self._snapshot_cache[1]))
+                    self._snapshot_cache = (generation, chunk,
+                                            time.monotonic())
+                    _snap_bytes_add(_chunk_nbytes(chunk))
+                return chunk
+        finally:
+            if stats is not None:
+                stats.snapshot_time += time.perf_counter() - t_lock
 
     def read_snapshot_bounded(self, timestamp: int = MAX_TIMESTAMP,
                               max_staleness: float = 0.0) \
