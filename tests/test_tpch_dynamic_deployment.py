@@ -240,13 +240,20 @@ def test_fan_in_counters_span_and_explain_line(deployment):
     profile = client.select_rows(q1(driver)["ql"], explain_analyze=True)
     stats = client.last_query_statistics
     assert {"snapshot_time", "snapshot_cache_misses", "coalesce_time",
-            "shards_coalesced"} <= set(stats.to_dict())
+            "shards_coalesced", "coalesce_columns",
+            "coalesce_columns_pruned"} <= set(stats.to_dict())
     (span,) = [s for s in get_collector().find(profile.trace_id)
                if s.name == "coordinator.coalesce"]
     assert span.tags["shards_in"] == 8 and span.tags["groups_out"] == 1
     assert span.tags["rows"] == driver.rows
-    assert span.tags["columns"] == 16 and span.tags["string_columns"] == 5
-    assert span.tags["vocab_entries"] > driver.rows    # l_comment, 8 x
+    # Q1 reads 7 of the 16 columns: l_returnflag and l_linestatus are its
+    # strings, l_comment and the rest stay out of the concatenation
+    assert span.tags["columns"] == 7 and span.tags["string_columns"] == 2
+    assert span.tags["columns_pruned"] == 9
+    assert stats.coalesce_columns == 7
+    assert stats.coalesce_columns_pruned == 9
+    assert span.tags["vocab_entries"] <= 8 * (3 + 2)   # A/N/R, F/O a tablet
+    assert span.tags["vocab_entries"] < driver.rows // 100
     assert span.duration == pytest.approx(stats.coalesce_time, abs=2e-3)
     reads = [s for s in get_collector().find(profile.trace_id)
              if s.name == "tablet.read_snapshot"]
@@ -255,6 +262,7 @@ def test_fan_in_counters_span_and_explain_line(deployment):
     (line,) = [line for line in profile.format().splitlines()
                if line.startswith("fan-in: ")]
     assert line.startswith("fan-in: 8 shards coalesced in ")
+    assert " (7 of 16 columns); tablet snapshots " in line
     assert line.endswith("(0 merged anew)")
 
 
@@ -266,6 +274,102 @@ def test_static_table_counts_no_fan_in(tmp_path):
     stats = client.last_query_statistics
     assert stats.snapshot_time == stats.coalesce_time == 0
     assert stats.snapshot_cache_misses == stats.shards_coalesced == 0
+
+
+# -- the fan-in's column cut ---------------------------------------------------
+
+CUT_COLUMNS = ["k", "g", "s", "c", "x", "y"]
+# 4 tablets of 40 rows; `g` has other values in every tablet, `s` and `c`
+# one a row, so every string column's dictionaries differ shard to shard
+CUT_ROWS = [{"k": k, "g": b"g%d.%d" % (k // 40, k % 3),
+             "s": None if k % 11 == 5 else b"s%03d" % k,
+             "c": b"comment %d" % (k * 7919 % 1000), "x": k / 8, "y": k % 5}
+            for k in range(160)]
+# (query, the FROM columns it reads, coalescing threshold: the client's
+# unless given, where the 4 tablets make one group)
+CUT_CASES = {
+    "star": ("* FROM [{t}]", CUT_COLUMNS, None),
+    "projection": ("s, x FROM [{t}]", ["s", "x"], None),
+    "group": ("g, sum(x) AS sx, count(*) AS n FROM [{t}] GROUP BY g",
+              ["g", "x"], None),
+    "where_not_projected": ("s FROM [{t}] WHERE y = 2", ["s", "y"], None),
+    # a LIMIT without a group stages lazily; with one it is eager
+    "group_order_limit": ("g, sum(y) AS sy FROM [{t}] GROUP BY g "
+                          "ORDER BY sum(y) DESC, g LIMIT 3", ["g", "y"], None),
+    # the join key is kept, and `s`, read only above the join
+    "join": ("s, name FROM [{t}] JOIN [//cut/dim] ON y = gk WHERE x > 3",
+             ["s", "x", "y"], None),
+    # two groups: the bottom query split off a distinct count drops the
+    # grouping and the projection, and still reads only what was kept
+    "join_two_groups": ("name, cardinality(s) AS n FROM [{t}] "
+                        "JOIN [//cut/dim] ON y = gk WHERE x > 3 GROUP BY name",
+                        ["s", "x", "y"], 50),
+}
+
+
+@pytest.fixture(scope="module")
+def cut_tables(tmp_path_factory):
+    """`//cut/dyn`, a sorted dynamic table over 4 tablets, its static
+    one-chunk copy `//cut/static`, and `//cut/dim` to join to."""
+    from ytsaurus_tpu.client import connect
+    from ytsaurus_tpu.schema import TableSchema
+    client = connect(str(tmp_path_factory.mktemp("cut")))
+    types = dict(k="int64", g="string", s="string", c="string", x="double",
+                 y="int64")
+    client.create("table", "//cut/dyn", recursive=True, attributes={
+        "schema": TableSchema.make(
+            [("k", "int64", "ascending")] +
+            [(name, types[name]) for name in CUT_COLUMNS[1:]],
+            unique_keys=True),
+        "dynamic": True, "pivot_keys": [[40], [80], [120]]})
+    client.mount_table("//cut/dyn")
+    client.insert_rows("//cut/dyn", CUT_ROWS)
+    client.create("table", "//cut/static", attributes={
+        "schema": TableSchema.make([(n, types[n]) for n in CUT_COLUMNS])})
+    client.write_table("//cut/static", CUT_ROWS)
+    client.create("table", "//cut/dim", attributes={
+        "schema": TableSchema.make([("gk", "int64"), ("name", "string")])})
+    client.write_table("//cut/dim",
+                       [{"gk": k, "name": b"n%d" % k} for k in range(4)])
+    assert len(client._mounted_tablets("//cut/dyn")) == 4
+    return client
+
+
+@pytest.mark.parametrize("case", sorted(CUT_CASES))
+def test_fan_in_concatenates_only_the_columns_the_plan_reads(
+        cut_tables, monkeypatch, case):
+    import ytsaurus_tpu.client as client_module
+    client = cut_tables
+    ql, kept, threshold = CUT_CASES[case]
+    want = client.select_rows(ql.format(t="//cut/static"))
+    assert want, "an empty answer compares nothing"
+    assert client.last_query_statistics.shards_coalesced == 0
+    if threshold is not None:
+        real = client_module.coordinate_and_execute
+
+        def coalescing_below(*args, **kwargs):
+            return real(*args, **dict(kwargs, merge_shards_below=threshold))
+        monkeypatch.setattr(client_module, "coordinate_and_execute",
+                            coalescing_below)
+    profile = client.select_rows(ql.format(t="//cut/dyn"),
+                                 explain_analyze=True)
+    if "ORDER BY" in ql:
+        assert profile.rows == want
+    else:
+        assert sorted(profile.rows, key=repr) == sorted(want, key=repr)
+    stats = client.last_query_statistics
+    assert stats.shards_coalesced == 4
+    assert stats.coalesce_columns == len(kept)
+    assert stats.coalesce_columns_pruned == len(CUT_COLUMNS) - len(kept)
+    (span,) = [s for s in get_collector().find(profile.trace_id)
+               if s.name == "coordinator.coalesce"]
+    assert span.tags["groups_out"] == (1 if threshold is None else 2)
+    assert span.tags["columns"] == len(kept)
+    assert span.tags["columns_pruned"] == len(CUT_COLUMNS) - len(kept)
+    assert span.tags["string_columns"] == len(set(kept) & {"g", "s", "c"})
+    (line,) = [line for line in profile.format().splitlines()
+               if line.startswith("fan-in: ")]
+    assert f" ({len(kept)} of {len(CUT_COLUMNS)} columns); " in line
 
 
 # -- the concatenation the fan-in stages with ----------------------------------

@@ -481,9 +481,10 @@ def cascade_intermediate(plan, chunk, foreign_chunks, stages):
     joins), made as `evaluator._dispatch_traced` makes it: the FROM
     chunk projected to `from_schema`, then one `execute_join` a stage."""
     from ytsaurus_tpu.query import ir
-    from ytsaurus_tpu.query.engine import evaluator, joins
+    from ytsaurus_tpu.chunks.columnar import project_chunk
+    from ytsaurus_tpu.query.engine import joins
     cascade = ir.join_cascade(plan)
-    current = evaluator._project_chunk(chunk, cascade.from_schema)
+    current = project_chunk(chunk, cascade.from_schema)
     for stage in cascade.stages[:stages]:
         current = joins.execute_join(
             current, stage.schema, stage.join,

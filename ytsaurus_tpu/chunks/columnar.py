@@ -970,6 +970,27 @@ def chunk_column_stats(chunk: ColumnarChunk) -> dict:
     return out
 
 
+def project_chunk(chunk: ColumnarChunk, schema: TableSchema) -> ColumnarChunk:
+    """View of `chunk` under `schema` (subset/reorder of columns)."""
+    columns = {}
+    for col_schema in schema:
+        col = chunk.columns.get(col_schema.name)
+        if col is None:
+            raise YtError(f"Chunk is missing column {col_schema.name!r}",
+                          code=EErrorCode.QueryExecutionError)
+        columns[col_schema.name] = col
+    # Column projection keeps row order; the sealed sort order survives
+    # for the longest key prefix whose columns are still present (rows
+    # sorted by (a, b) are NOT sorted by b alone once a is dropped).
+    sorted_by = []
+    for name in chunk.sorted_by:
+        if name not in columns:
+            break
+        sorted_by.append(name)
+    return ColumnarChunk(schema=schema, row_count=chunk.row_count,
+                         columns=columns, sorted_by=tuple(sorted_by))
+
+
 def concat_chunks(chunks: Sequence[ColumnarChunk]) -> ColumnarChunk:
     """Concatenate chunks of identical schema into one (device concat + repad).
     The device work is compiled per capacity bucket of the parts and of the

@@ -16,12 +16,16 @@ import time
 from dataclasses import replace
 from typing import Mapping, Optional, Sequence
 
-from ytsaurus_tpu.chunks.columnar import ColumnarChunk, concat_chunks
+from ytsaurus_tpu.chunks.columnar import (
+    ColumnarChunk,
+    concat_chunks,
+    project_chunk,
+)
 from ytsaurus_tpu.config import retry_policy
 from ytsaurus_tpu.errors import EErrorCode, YtError
 from ytsaurus_tpu.query import ir
 from ytsaurus_tpu.query.engine.evaluator import Evaluator, finish_all
-from ytsaurus_tpu.schema import EValueType
+from ytsaurus_tpu.schema import EValueType, TableSchema
 from ytsaurus_tpu.utils import failpoints
 from ytsaurus_tpu.utils.tracing import NULL_SPAN, child_span
 
@@ -395,7 +399,11 @@ def coordinate_and_execute(
     `merge_shards_below`: when > 0, shards are coalesced so no device
     program runs over fewer than this many rows — per-program dispatch
     overhead dominates small shards (ref analog: chunk slice grouping in
-    chunk pools).  0 preserves one program per shard.
+    chunk pools).  0 preserves one program per shard.  Eager shards are
+    coalesced over the FROM columns the plan reads (`ir.source_cut`:
+    those its clauses read and its joins' self keys; every column for a
+    bare select), and the plan runs cut to them; lazy shards are grouped
+    after staging, whole.
 
     `range_ordered_by`: key column names by which the SHARDS are range-
     ordered (tablet pivot order for sorted dynamic tables).  Lets ORDER
@@ -437,11 +445,16 @@ def coordinate_and_execute(
             if scan_direction is not None:
                 needed = plan.offset + plan.limit
     if merge_shards_below > 0 and len(chunks) > 1 and not lazy:
+        # The fan-in concatenates only the FROM columns the plan reads,
+        # and the plan is cut to them, so its prepare, bind and cache
+        # key see the chunk it gets.
+        columns, plan = ir.source_cut(plan)
         if scan_direction is None:
             # Bare LIMIT (or no early exit): full coalescing — a
             # selective WHERE may scan everything, so dispatch overhead
             # dominates and the early exit still skips whole groups.
-            chunks = _coalesce_shards(chunks, merge_shards_below, stats)
+            chunks = _coalesce_shards(chunks, merge_shards_below, columns,
+                                      stats)
         else:
             # Ordered exit: the scan is expected to stop after ~needed
             # rows, so a group only needs to hold the scan budget —
@@ -449,7 +462,8 @@ def coordinate_and_execute(
             # program and forfeit the skip.  (A selective WHERE on an
             # ordered scan pays per-shard dispatch; that is the price
             # of being able to stop at all.)
-            chunks = _coalesce_shards(chunks, max(needed, 1), stats)
+            chunks = _coalesce_shards(chunks, max(needed, 1), columns,
+                                      stats)
     if stats is not None:
         stats.shards_total += len(chunks)
         if not lazy:
@@ -600,15 +614,22 @@ def coordinate_and_execute(
     return result
 
 
-def _coalesce_shards(chunks: Sequence[ColumnarChunk],
-                     min_rows: int, stats=None) -> list[ColumnarChunk]:
-    """The fan-in: consecutive shards concatenated (planes and string
-    dictionaries, every column) until each group holds `min_rows`.
-    Its seconds go to `stats.coalesce_time`, the shards it concatenated
-    to `stats.shards_coalesced`, and a `coordinator.coalesce` span."""
+def _coalesce_shards(chunks: Sequence[ColumnarChunk], min_rows: int,
+                     columns: TableSchema,
+                     stats=None) -> list[ColumnarChunk]:
+    """The fan-in: each shard viewed under `columns` (the FROM columns
+    the plan reads, `ir.source_cut`), then consecutive shards
+    concatenated (planes and string dictionaries of those columns only)
+    until each group holds `min_rows`.  Its seconds go to
+    `stats.coalesce_time`, the shards it concatenated to
+    `stats.shards_coalesced`, the columns it kept and left out to
+    `stats.coalesce_columns` and `stats.coalesce_columns_pruned`, and a
+    `coordinator.coalesce` span."""
     t0 = time.perf_counter()
+    pruned = len(chunks[0].schema) - len(columns)
     with child_span("coordinator.coalesce",
                     shards_in=len(chunks)) as span:
+        chunks = [project_chunk(c, columns) for c in chunks]
         groups: list[list[ColumnarChunk]] = []
         current: list[ColumnarChunk] = []
         current_rows = 0
@@ -626,11 +647,12 @@ def _coalesce_shards(chunks: Sequence[ColumnarChunk],
         out = [concat_chunks(g) if len(g) > 1 else g[0] for g in groups]
         merged = [c for g in groups if len(g) > 1 for c in g]
         if span.sampled:
-            strings = [c.name for c in chunks[0].schema
+            strings = [c.name for c in columns
                        if c.type is EValueType.string]
             span.add_tag("groups_out", len(out))
             span.add_tag("rows", sum(c.row_count for c in chunks))
-            span.add_tag("columns", len(chunks[0].schema))
+            span.add_tag("columns", len(columns))
+            span.add_tag("columns_pruned", pruned)
             span.add_tag("string_columns", len(strings))
             span.add_tag("vocab_entries", sum(
                 len(c.columns[name].dictionary) for c in merged
@@ -639,4 +661,6 @@ def _coalesce_shards(chunks: Sequence[ColumnarChunk],
     if stats is not None:
         stats.coalesce_time += time.perf_counter() - t0
         stats.shards_coalesced += len(merged)
+        stats.coalesce_columns += len(columns)
+        stats.coalesce_columns_pruned += pruned
     return out

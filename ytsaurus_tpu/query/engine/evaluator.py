@@ -17,7 +17,12 @@ from typing import Mapping, Optional, Sequence
 import jax
 import numpy as np
 
-from ytsaurus_tpu.chunks.columnar import Column, ColumnarChunk, concat_chunks
+from ytsaurus_tpu.chunks.columnar import (
+    Column,
+    ColumnarChunk,
+    concat_chunks,
+    project_chunk,
+)
 from ytsaurus_tpu.errors import EErrorCode, YtError
 from ytsaurus_tpu.query import ir
 from ytsaurus_tpu.query.builder import build_query
@@ -792,7 +797,7 @@ class Evaluator:
             # estimate.
             cascade = ir.join_cascade(plan)
             plan = cascade.query
-            current = _project_chunk(chunk, cascade.from_schema)
+            current = project_chunk(chunk, cascade.from_schema)
             decisions = jplan.decisions if jplan is not None else None
             for pos, stage in enumerate(cascade.stages):
                 join = stage.join
@@ -844,7 +849,7 @@ class Evaluator:
         # eagerly.
         if plan.group is not None and plan.group.totals:
             if project:
-                chunk = _project_chunk(chunk, plan.schema)
+                chunk = project_chunk(chunk, plan.schema)
             main = self._dispatch(plan, chunk, stats, pool=pool)
             result = main.finish()
             totals_plan = _make_totals_plan(plan)
@@ -882,7 +887,7 @@ class Evaluator:
         with child_span("evaluator.prepare") as span:
             fp = plan_fingerprint(plan)
             if project:
-                chunk = _project_chunk(chunk, plan.schema)
+                chunk = project_chunk(chunk, plan.schema)
             prepared = prepare(plan, chunk)
             key = (fp, chunk.capacity, prepared.binding_shapes())
             if donate_columns:
@@ -1146,27 +1151,6 @@ class Evaluator:
             else:
                 stats.compile_new_fingerprint += 1
         return fn, compile_seconds, result
-
-
-def _project_chunk(chunk: ColumnarChunk, schema: TableSchema) -> ColumnarChunk:
-    """View of `chunk` under `schema` (subset/reorder of columns)."""
-    columns = {}
-    for col_schema in schema:
-        col = chunk.columns.get(col_schema.name)
-        if col is None:
-            raise YtError(f"Chunk is missing column {col_schema.name!r}",
-                          code=EErrorCode.QueryExecutionError)
-        columns[col_schema.name] = col
-    # Column projection keeps row order; the sealed sort order survives
-    # for the longest key prefix whose columns are still present (rows
-    # sorted by (a, b) are NOT sorted by b alone once a is dropped).
-    sorted_by = []
-    for name in chunk.sorted_by:
-        if name not in columns:
-            break
-        sorted_by.append(name)
-    return ColumnarChunk(schema=schema, row_count=chunk.row_count,
-                         columns=columns, sorted_by=tuple(sorted_by))
 
 
 def _typed_null(ty):

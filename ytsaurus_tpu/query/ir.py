@@ -318,9 +318,6 @@ def join_cascade(query: Query) -> JoinCascade:
     projection, no grouping: `referenced_columns` is None) reads every
     column and keeps every column.  Every schema is a cut of
     `query.schema`, in its order."""
-    def flat(join, name):
-        return f"{join.alias}.{name}" if join.alias else name
-
     reads = referenced_columns(replace(query, joins=()))
     # live[0] cuts the FROM chunk, live[k + 1] stage k's output.
     live = [reads]
@@ -329,8 +326,7 @@ def join_cascade(query: Query) -> JoinCascade:
                 for name in expr_references(eq)}
         live.append(None if reads is None else live[-1] | keys)
     live.reverse()
-    pulled = [{flat(join, name) for name in join.foreign_columns}
-              for join in query.joins]
+    pulled = _pulled_columns(query)
     present = {c.name for c in query.schema}.difference(*pulled)
 
     def cut(wanted) -> TableSchema:
@@ -346,12 +342,41 @@ def join_cascade(query: Query) -> JoinCascade:
         stages.append(JoinStage(
             join=replace(join, foreign_columns=tuple(
                 name for name in join.foreign_columns
-                if flat(join, name) in schema)),
+                if _flat(join, name) in schema)),
             schema=schema, columns_pruned=len(present) - len(schema)))
     return JoinCascade(
         from_schema=from_schema, stages=tuple(stages),
         query=replace(query, schema=schema,
                       joins=tuple(stage.join for stage in stages)))
+
+
+def _flat(join: JoinClause, name: str) -> str:
+    return f"{join.alias}.{name}" if join.alias else name
+
+
+def _pulled_columns(query: Query) -> list[set[str]]:
+    """Each join's foreign columns as `query.schema` names them."""
+    return [{_flat(join, name) for name in join.foreign_columns}
+            for join in query.joins]
+
+
+def source_cut(query: Query) -> tuple[TableSchema, Query]:
+    """The FROM columns `query` reads, and `query` over them.
+
+    The cut is `join_cascade(query).from_schema`: what the clauses read
+    plus every join's self keys, or every FROM column for a bare select.
+    The plan's schema loses the FROM columns outside it and keeps the
+    joined tables' columns, so its cascade materializes the same stages
+    (whose `columns_pruned` no longer count the FROM columns cut here),
+    and a bottom query split off it (`coordinator.split_plan`, which may
+    drop the grouping and the projection) still names only columns the
+    cut holds.  A cut that drops nothing hands `query` back as it was."""
+    from_schema = join_cascade(query).from_schema
+    names = {c.name for c in from_schema}.union(*_pulled_columns(query))
+    kept = [c for c in query.schema if c.name in names]
+    if len(kept) == len(query.schema):
+        return from_schema, query
+    return from_schema, replace(query, schema=TableSchema.make(kept))
 
 
 @dataclass(frozen=True)

@@ -139,9 +139,12 @@ def format_profile_dict(p: dict) -> str:
     # Tablet snapshots and the coordinator's fan-in: what a select over
     # a dynamic table pays on the host before its program runs.
     if stats.get("snapshot_time") or stats.get("shards_coalesced"):
+        kept = stats.get("coalesce_columns", 0)
         lines.append(
             f"fan-in: {stats.get('shards_coalesced', 0)} shards coalesced "
-            f"in {_ms(stats.get('coalesce_time', 0.0))}; tablet snapshots "
+            f"in {_ms(stats.get('coalesce_time', 0.0))} ({kept} of "
+            f"{kept + stats.get('coalesce_columns_pruned', 0)} columns); "
+            f"tablet snapshots "
             f"{_ms(stats.get('snapshot_time', 0.0))} "
             f"({stats.get('snapshot_cache_misses', 0)} merged anew)")
     # ISSUE 8: why those misses happened (new fingerprint vs new shape

@@ -47,7 +47,9 @@ class QueryStatistics:
     # join_columns_out the columns the stages materialized and
     # join_columns_pruned the columns of the plan's full namespace they
     # left behind because nothing reads them afterwards
-    # (ir.join_cascade), summed over the stages.
+    # (ir.join_cascade), summed over the stages; where the fan-in below
+    # cut the FROM columns first, those count in coalesce_columns_pruned
+    # and not here.
     join_time: float = 0.0
     join_sync_time: float = 0.0
     join_host_syncs: int = 0
@@ -62,11 +64,16 @@ class QueryStatistics:
     # that had to be merged anew; coalesce_time the seconds inside the
     # coordinator's fan-in (coordinator._coalesce_shards: concat_chunks
     # and its dictionary unions); shards_coalesced the shards that went
-    # into a concatenation there.
+    # into a concatenation there; coalesce_columns the columns it
+    # concatenated (the FROM columns the plan reads, ir.source_cut) and
+    # coalesce_columns_pruned the columns of the shards' schema it left
+    # out.
     snapshot_time: float = 0.0
     snapshot_cache_misses: int = 0
     coalesce_time: float = 0.0
     shards_coalesced: int = 0
+    coalesce_columns: int = 0
+    coalesce_columns_pruned: int = 0
     # Whole-plan SPMD execution (ISSUE 12): 1 when the query was served
     # by the fused one-program rung (parallel/whole_plan.py); retries
     # count exchange-quota overflow re-runs (each a fresh pow2 rung of
