@@ -22,6 +22,7 @@ _T_PROCESS = time.perf_counter()
 
 import argparse
 import contextlib
+import gc
 import importlib
 import json
 import os
@@ -32,6 +33,12 @@ BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
 sys.path.insert(0, BENCH_DIR)
 sys.path.insert(1, ROOT)
+
+
+# The program's span ring in a traced run: a 51-s window of ~20 spans a
+# select down to a 3.9-ms select (~138 MB of host memory at ~527 B a span).
+# Untraced runs keep the program's own ring.
+TRACED_RING_SPANS = 262144
 
 
 def load_json(*parts):
@@ -218,9 +225,17 @@ def run_cell(bench, args, jax, t_start, with_control=False):
         yt = connect(root, fresh=True)
         driver.warm(yt)
         ctx.phase("warm")
+        # Set-up's garbage (the loading cluster and what it was written
+        # from: ~600,000 objects in cycles in the dynamic cell) is freed
+        # here, not by the first full collection inside the window.
+        gc.collect()
+        ctx.phase("collect")
         record.setup_s = time.perf_counter() - t_start
 
         if args.trace:
+            # the program's spans of the whole window, for the span reader
+            from ytsaurus_tpu.utils import tracing
+            tracing.get_collector().set_capacity(TRACED_RING_SPANS)
             options = jax.profiler.ProfileOptions()
             options.python_tracer_level = 0
             jax.profiler.start_trace(trace_dir, profiler_options=options)

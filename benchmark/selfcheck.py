@@ -3,8 +3,9 @@
 
     python3 benchmark/selfcheck.py
 
-- `trace_reduce` on a trace written out by hand (busy, idle, time per
-  program and the booking of idle gaps can be worked out on paper) and on
+- `trace_reduce` on traces written out by hand (busy, idle, time per
+  program and operation, and the booking of idle gaps to the innermost
+  span of the client's host line can be worked out on paper) and on
   one small trace recorded on the chip (`data/q1_tiny.xplane.pb.gz`: Q1 over
   60,000 rows, a window of 0.2 s), against the numbers in
   `data/q1_tiny.expected.json`;
@@ -29,8 +30,9 @@ from readers import trace as trace_reader  # noqa: E402
 from reference import ql_spec  # noqa: E402
 
 # Device ops cover [0,20) and [30,40) ns of a 50 ns window of two client
-# calls [0,25) and [25,50): busy 30 ns, idle 40%; the gap [20,30) has its
-# midpoint in the second call, the gap [40,50) too.
+# calls [0,25) and [25,50): busy 30 ns, idle 40%; the gap [20,30) is split
+# at the calls' boundary (5 ns to the first, 5 to the second), the gap
+# [40,50) lies in the second call.
 HAND_TRACE = """
 planes { id: 1 name: "/device:TPU:0"
   lines { id: 1 name: "XLA Ops" timestamp_ns: 1000
@@ -50,6 +52,59 @@ planes { id: 2 name: "/host:CPU"
   event_metadata { key: 1 value { id: 1 name: "bench.select.q1" } }
   event_metadata { key: 2 value { id: 2 name: "bench.insert" } } }
 """
+
+
+# Program spans (`yt.*`) nested in the client's calls on its host line
+# `python3`, times in ns: bench.select.q1 [0,100) > yt.query.select [2,90) >
+# yt.query.plan [2,10), yt.coordinator.shard [10,60) > yt.evaluator.launch
+# [10,12), yt.evaluator.sync [12,58); then yt.query.decode [60,75),
+# yt.query.record [75,80); a second call bench.select.q1 [110,130) opens no
+# span.  A prefetch thread's yt.query.stage [40,55) is on another line, and
+# JAX's own PjitFunction(run) [30,33) is no annotation of ours: neither owns
+# anything.  The device runs a %while [0,40) whose body ops take [5,15)
+# and [20,30), and a copy [60,70): busy 50 ns of 130.  Its idle [40,60) and
+# [70,130) go to the innermost span on `python3`: sync 18 (40-58), shard 2
+# (58-60), decode 5, record 5, query.select's own 10 (80-90), the client's
+# own 10 (90-100) + 20 (110-130), between the calls 10 (100-110).
+NESTED_TRACE = """
+planes { id: 1 name: "/device:TPU:0"
+  lines { id: 1 name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 40000 }
+    events { metadata_id: 2 offset_ps: 5000 duration_ps: 10000 }
+    events { metadata_id: 2 offset_ps: 20000 duration_ps: 10000 }
+    events { metadata_id: 3 offset_ps: 60000 duration_ps: 10000 } }
+  event_metadata { key: 1 value { id: 1 name: "%while.1 = (s32[], u32[8]{0}) while((s32[], u32[8]{0}) %t), body=%body" } }
+  event_metadata { key: 2 value { id: 2 name: "%fusion.2 = u32[8]{0} fusion(u32[8]{0} %p), kind=kLoop" } }
+  event_metadata { key: 3 value { id: 3 name: "%copy.3 = f32[8]{0} copy(f32[8]{0} %p)" } } }
+planes { id: 2 name: "/host:CPU"
+  lines { id: 1 name: "python3" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 100000 }
+    events { metadata_id: 2 offset_ps: 2000 duration_ps: 88000 }
+    events { metadata_id: 3 offset_ps: 2000 duration_ps: 8000 }
+    events { metadata_id: 4 offset_ps: 10000 duration_ps: 50000 }
+    events { metadata_id: 5 offset_ps: 10000 duration_ps: 2000 }
+    events { metadata_id: 6 offset_ps: 12000 duration_ps: 46000 }
+    events { metadata_id: 7 offset_ps: 60000 duration_ps: 15000 }
+    events { metadata_id: 8 offset_ps: 75000 duration_ps: 5000 }
+    events { metadata_id: 9 offset_ps: 30000 duration_ps: 3000 }
+    events { metadata_id: 1 offset_ps: 110000 duration_ps: 20000 } }
+  lines { id: 2 name: "shard-prefetch_0" timestamp_ns: 1000
+    events { metadata_id: 10 offset_ps: 40000 duration_ps: 15000 } }
+  event_metadata { key: 1 value { id: 1 name: "bench.select.q1" } }
+  event_metadata { key: 2 value { id: 2 name: "yt.query.select" } }
+  event_metadata { key: 3 value { id: 3 name: "yt.query.plan" } }
+  event_metadata { key: 4 value { id: 4 name: "yt.coordinator.shard" } }
+  event_metadata { key: 5 value { id: 5 name: "yt.evaluator.launch" } }
+  event_metadata { key: 6 value { id: 6 name: "yt.evaluator.sync" } }
+  event_metadata { key: 7 value { id: 7 name: "yt.query.decode" } }
+  event_metadata { key: 8 value { id: 8 name: "yt.query.record" } }
+  event_metadata { key: 9 value { id: 9 name: "PjitFunction(run)" } }
+  event_metadata { key: 10 value { id: 10 name: "yt.query.stage" } } }
+"""
+NESTED_IDLE_NS = {
+    "yt.evaluator.sync": 18, "yt.coordinator.shard": 2,
+    "yt.query.decode": 5, "yt.query.record": 5, "yt.query.select": 10,
+    "bench.select.q1": 30, "between_calls": 10}
 
 
 def close(a, b, tol=1e-12):
@@ -76,10 +131,48 @@ def check_hand_trace():
     check(close(ops["%fusion.1 fusion f32[8]"], 20e-9) and
           close(ops["%copy.2 copy f32[8]"], 15e-9),
           "hand trace: time per operation, names shortened")
-    check(got["idle_gaps"][0][0] == "bench.insert" and
-          close(got["idle_gaps"][0][1], 20e-9) and len(got["idle_gaps"]) == 1,
-          "hand trace: 20 ns of idle gaps, booked to the call over their "
-          "midpoints")
+    check(idle_ns(got) == {"bench.insert": 15, "bench.select.q1": 5},
+          "hand trace: 20 ns of idle gaps, split at the calls' boundary: "
+          "15 ns to bench.insert, 5 ns to bench.select.q1")
+
+
+def idle_ns(reduced):
+    """The idle booking in whole nanoseconds, where it is exact."""
+    out = {name: round(seconds * 1e9) for name, seconds in
+           reduced["idle_gaps"]}
+    for name, seconds in reduced["idle_gaps"]:
+        if abs(seconds * 1e9 - out[name]) > 1e-6:
+            raise AssertionError(f"{name}: {seconds} s is no whole ns")
+    return out
+
+
+def check_nested_trace():
+    from types import SimpleNamespace
+
+    from jax.profiler import ProfileData
+    got = trace_reduce.reduce_planes(
+        ProfileData.from_text_proto(NESTED_TRACE).planes)
+    check(close(got["busy_s"], 50e-9) and close(got["window_s"], 130e-9),
+          f"nested trace: busy 50 ns of 130 ns (got {got['busy_s']}, "
+          f"{got['window_s']})")
+    check(idle_ns(got) == NESTED_IDLE_NS,
+          f"nested trace: idle booked to the innermost span of the "
+          f"client's line, to the ns ({idle_ns(got)})")
+    check([name for name, _ in got["idle_gaps"]][0] == "bench.select.q1",
+          "nested trace: idle_gaps sorted by seconds")
+    ops = {name: round(seconds * 1e9) for name, seconds in got["device_ops"]}
+    check(ops == {"%while.1 while (s32[], u32[8])": 20,
+                  "%fusion.2 fusion u32[8]": 20, "%copy.3 copy f32[8]": 10},
+          f"nested trace: a %while keeps only what its body leaves "
+          f"uncovered ({ops})")
+    check(sum(ops.values()) == 50, "nested trace: self times add up to busy")
+    ctx = SimpleNamespace(record=SimpleNamespace(trace=got))
+    unspanned = trace_reader.read(
+        {"kind": "trace", "stat": "idle_unspanned", "root": "yt.query.select"},
+        ctx)
+    check(close(unspanned, 50 / 130 * 100),
+          f"nested trace: idle_unspanned = (10 + 30 + 10) / 130 "
+          f"({unspanned}%)")
 
 
 def check_recorded_trace():
@@ -206,6 +299,7 @@ def check_spec_evaluator(host, vocabs):
 
 def main():
     check_hand_trace()
+    check_nested_trace()
     check_peaks()
     check_spec_evaluator(*check_generator())
     check_recorded_trace()

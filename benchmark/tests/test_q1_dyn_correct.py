@@ -39,7 +39,11 @@ DYN_METRICS = {
     "snapshot_ms_per_select.dyn", "coalesce_ms_per_select.dyn",
     "snapshot_misses.dyn", "host_ms_per_select.dyn",
     "execute_ms_per_select.dyn", "window_compiles.dyn", "device_idle.dyn",
-    "query_hbm_roofline.dyn"}
+    "query_hbm_roofline.dyn", "idle_unspanned.dyn"}
+DYN_SPANS = {stem + ".dyn" for stem in (
+    "admission_ms_per_select", "plan_ms_per_select", "stage_ms_per_select",
+    "prepare_ms_per_select", "launch_ms_per_select", "sync_ms_per_select",
+    "decode_ms_per_select", "record_ms_per_select", "select_unspanned_ms")}
 COUNTERS = ("snapshot_ms_per_select", "coalesce_ms_per_select",
             "snapshot_misses")
 
@@ -174,8 +178,9 @@ def test_cell_reports_its_bytes_rows_and_metrics(bench, jax):
         full["orders"] // 1000
     mine = {entry["name"]: definition
             for entry, definition in ctx.metric_defs("per_layer")}
-    assert set(mine) == DYN_METRICS
-    assert all(d["kind"] != "span" for d in mine.values())
+    assert set(mine) == DYN_METRICS | DYN_SPANS
+    assert {name for name, d in mine.items() if d["kind"] == "span"} == \
+        DYN_SPANS
     assert {entry["name"] for entry, _ in ctx.metric_defs("end_to_end")} \
         == {"scan_rows_per_s", "setup_s"}
 

@@ -14,10 +14,20 @@ Busy is the union of the intervals of `XLA Ops` (of `XLA Modules` where a
 plane has no ops line), averaged over the device planes.  The window runs
 from the first to the last host annotation of the benchmark (`bench.*`),
 or over the device events where there is none.
+
+An operation's time is its self time: a control-flow op (`%while`) on the
+ops line encloses the ops of its body on the same line, and keeps only
+what they leave uncovered.  The first device's idle stretches are split
+across the host annotations that cover them: each part goes to the
+innermost `bench.*` or program (`yt.*`, `utils/tracing.py`) annotation
+open at that time on the host line that carries the covering `bench.*`
+call, and to `between_calls` outside every call.  The parts add up to
+each stretch to the nanosecond.
 """
 
 import bisect
 import glob
+import heapq
 import os
 import re
 
@@ -26,6 +36,8 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 ANNOTATION_PREFIX = "bench."
+PROGRAM_PREFIX = "yt."
+BETWEEN_CALLS = "between_calls"
 
 
 def _intervals(line):
@@ -66,6 +78,79 @@ def _gaps(intervals, lo, hi):
     return [(a, b) for a, b in out if b > a]
 
 
+def _self_seconds(intervals):
+    """Nanoseconds per name, each event counted less what the events that
+    lie inside it on the same line cover: an op's own time, never its
+    body's too."""
+    events = sorted(intervals, key=lambda iv: (iv[0], -iv[1]))
+    covered = [0] * len(events)
+    open_ = []                      # indices of the enclosing events
+    for i, (start, end, _) in enumerate(events):
+        while open_ and events[open_[-1]][1] <= start:
+            open_.pop()
+        if open_ and end <= events[open_[-1]][1]:
+            covered[open_[-1]] += end - start
+        open_.append(i)
+    out = {}
+    for (start, end, name), cover in zip(events, covered):
+        out[name] = out.get(name, 0) + (end - start - cover)
+    return out
+
+
+def _innermost(intervals):
+    """(start, end, name) pieces of the time the intervals cover, in order,
+    each owned by the interval that began last among those open over it
+    (the innermost, for intervals that nest)."""
+    intervals = sorted(intervals, key=lambda iv: (iv[0], -iv[1]))
+    cuts = sorted({t for start, end, _ in intervals for t in (start, end)})
+    out, heap, i = [], [], 0
+    for a, b in zip(cuts, cuts[1:]):
+        while i < len(intervals) and intervals[i][0] <= a:
+            start, end, name = intervals[i]
+            heapq.heappush(heap, (-start, end, i, name))
+            i += 1
+        while heap and heap[0][1] <= a:
+            heapq.heappop(heap)
+        if not heap:
+            continue
+        name = heap[0][3]
+        if out and out[-1][1] == a and out[-1][2] == name:
+            out[-1] = (out[-1][0], b, name)
+        else:
+            out.append((a, b, name))
+    return out
+
+
+def _owners(host_lines, lo, hi):
+    """(start, end, name) pieces that tile [lo, hi]: in each of the
+    benchmark's calls the innermost `bench.*` / `yt.*` annotation open on
+    the call's own host line, `between_calls` outside every call."""
+    owned = [(lo, hi, BETWEEN_CALLS)]
+    for line in host_lines:
+        calls = sorted(iv for iv in line if iv[2].startswith(ANNOTATION_PREFIX))
+        starts = [start for start, _, _ in calls]
+
+        def in_a_call(iv):
+            i = bisect.bisect_right(starts, iv[0]) - 1
+            return i >= 0 and iv[1] <= calls[i][1]
+        owned += [iv for iv in line if in_a_call(iv)]
+    return _innermost(owned)
+
+
+def _book(gaps, pieces):
+    """Nanoseconds of the gaps per owner, `pieces` tiling every gap."""
+    out, j = {}, 0
+    for a, b in gaps:
+        while pieces[j][1] <= a:
+            j += 1
+        k = j
+        while k < len(pieces) and pieces[k][0] < b:
+            start, end, name = pieces[k]
+            out[name] = out.get(name, 0) + min(end, b) - max(start, a)
+            k += 1
+    return out
+
+
 def _strip_fingerprint(name):
     return re.sub(r"\(\d+\)$", "", name)
 
@@ -85,15 +170,18 @@ def short_op_name(name):
 
 def reduce_planes(planes):
     """`planes`: iterable of profiler planes (name, lines -> events)."""
-    device_lines, annotations = [], []
+    device_lines, host_lines = [], []
     for plane in planes:
         if DEVICE_PLANE.match(plane.name):
             lines = {line.name: line for line in plane.lines}
             device_lines.append(lines)
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
-                annotations += [iv for iv in _intervals(line)
-                                if iv[2].startswith(ANNOTATION_PREFIX)]
+                host_lines.append([
+                    iv for iv in _intervals(line)
+                    if iv[2].startswith((ANNOTATION_PREFIX, PROGRAM_PREFIX))])
+    annotations = [iv for line in host_lines for iv in line
+                   if iv[2].startswith(ANNOTATION_PREFIX)]
     per_device = []
     for lines in device_lines:
         busy_line = lines.get(OPS_LINE) or lines.get(MODULES_LINE)
@@ -118,9 +206,9 @@ def reduce_planes(planes):
 
     op_seconds, program_seconds, program_calls = {}, {}, {}
     for d in per_device:
-        for start, end, name in d["ops"]:
+        for name, ns in _self_seconds(d["ops"]).items():
             name = short_op_name(name)
-            op_seconds[name] = op_seconds.get(name, 0.0) + (end - start) / 1e9
+            op_seconds[name] = op_seconds.get(name, 0.0) + ns / 1e9
         for start, end, name in d["modules"]:
             name = _strip_fingerprint(name)
             program_seconds[name] = program_seconds.get(name, 0.0) + \
@@ -128,18 +216,11 @@ def reduce_planes(planes):
             program_calls[name] = program_calls.get(name, 0) + 1
     n = len(per_device)
 
-    # Idle gaps of the first device, each booked to the benchmark's host
-    # annotation that covers its midpoint (none: the host was between calls).
-    # The benchmark's annotations are one client's calls: they do not overlap.
-    gap_seconds = {}
-    spans = sorted(annotations)
-    starts = [s for s, _, _ in spans]
-    for a, b in _gaps(per_device[0]["ops"], lo, hi):
-        mid = (a + b) / 2
-        i = bisect.bisect_right(starts, mid) - 1
-        owner = spans[i][2] if i >= 0 and mid < spans[i][1] \
-            else "between_calls"
-        gap_seconds[owner] = gap_seconds.get(owner, 0.0) + (b - a) / 1e9
+    # Idle stretches of the first device, split across what the client's
+    # host line was in over them.
+    booked = _book(_gaps(per_device[0]["ops"], lo, hi),
+                   _owners(host_lines, lo, hi))
+    gap_seconds = {name: ns / 1e9 for name, ns in booked.items()}
 
     def top(table, scale=1.0):
         return [[name, seconds * scale] for name, seconds in
