@@ -16,7 +16,11 @@ from ytsaurus_tpu.utils.profiler import (
     TraceExporter,
     jsonl_sink,
 )
-from ytsaurus_tpu.utils.tracing import TraceContext, get_collector
+from ytsaurus_tpu.utils.tracing import (
+    TraceContext,
+    get_collector,
+    is_instrument,
+)
 
 
 def _busy_function_alpha(stop):
@@ -63,13 +67,15 @@ def test_trace_exporter_flushes_batches(tmp_path):
         with parent.create_child("op.child"):
             time.sleep(0.01)
     n = exporter.flush_once()
-    assert n == 2
     lines = [json.loads(line) for line in open(path)]
+    assert n == len(lines) >= 2
+    # a collection or a stall may land among them (gc.*, host.*)
+    lines = [line for line in lines if not is_instrument(line["name"])]
     names = {line["name"] for line in lines}
     assert names == {"op.parent", "op.child"}
     traces = {line["trace_id"] for line in lines}
-    assert len(traces) == 1                 # one trace, two spans
-    assert exporter.stats == {"batches": 1, "spans": 2}
+    assert len(traces) == 1 and len(lines) == 2   # one trace, two spans
+    assert exporter.stats == {"batches": 1, "spans": n}
     # Nothing new → no batch.
     assert exporter.flush_once() == 0
 

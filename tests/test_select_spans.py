@@ -17,6 +17,7 @@ from ytsaurus_tpu.utils.tracing import (
     child_span,
     current_trace,
     get_collector,
+    is_instrument,
     span_tree,
 )
 
@@ -26,6 +27,14 @@ def _by_name(trace_id):
     for span in get_collector().find(trace_id):
         out.setdefault(span.name, []).append(span)
     return out
+
+
+def _covered(trace_id, span_id):
+    """What a span's children covered: a collection may land among them
+    (a `gc.collect` child); the `host.*` overlays cover nothing."""
+    return sum(s.duration for s in get_collector().find(trace_id)
+               if s.parent_span_id == span_id and
+               not s.name.startswith("host."))
 
 
 # -- self time, start_mono ----------------------------------------------------
@@ -41,16 +50,21 @@ def test_self_time_of_nested_and_sibling_spans():
         time.sleep(0.002)
     spans = {name: s[0] for name, s in _by_name(root.trace_id).items()}
     a, inner, b, top = (spans[n] for n in ("a", "a.inner", "b", "root"))
-    assert inner.self_time == inner.duration          # a leaf
-    assert a.self_time == pytest.approx(a.duration - inner.duration)
+    # a leaf, unless a collection landed in it
+    assert inner.self_time == pytest.approx(
+        inner.duration - _covered(root.trace_id, inner.span_id))
+    assert _covered(root.trace_id, a.span_id) >= inner.duration
+    assert a.self_time == pytest.approx(
+        a.duration - _covered(root.trace_id, a.span_id))
     assert a.self_time >= 0.004
     # siblings both count against the parent; the grandchild only once
+    assert _covered(root.trace_id, root.span_id) >= a.duration + b.duration
     assert top.self_time == pytest.approx(
-        top.duration - a.duration - b.duration)
+        top.duration - _covered(root.trace_id, root.span_id))
     assert top.self_time >= 0.002
     # self times partition the root
-    assert sum(s.self_time for s in spans.values()) == \
-        pytest.approx(top.duration)
+    assert sum(s.self_time for s in get_collector().find(root.trace_id)) \
+        == pytest.approx(top.duration)
 
 
 def test_self_time_clamped_when_children_overlap():
@@ -85,7 +99,7 @@ def test_span_tree_and_renderer_show_self_time():
             time.sleep(0.001)
     (node,) = span_tree(root.trace_id)
     assert node["self_time"] == pytest.approx(
-        node["duration"] - node["children"][0]["duration"])
+        node["duration"] - _covered(root.trace_id, root.span_id))
     lines = format_span_tree([node])
     assert "(self " in lines[0]           # a span with children
     assert "(self " not in lines[1]       # a leaf: self is its duration
@@ -173,7 +187,8 @@ def test_tracing_never_imports_jax(monkeypatch):
         with child_span("child") as child:
             assert child is not NULL_SPAN and child._annotation is None
     assert child_span("orphan") is NULL_SPAN
-    assert len(get_collector().find(root.trace_id)) == 2
+    assert [s.name for s in get_collector().find(root.trace_id)
+            if not is_instrument(s.name)] == ["child", "root"]
     assert "jax" not in sys.modules, "tracing imported jax"
     with open(tracing.__file__) as f:
         source = f.read()
@@ -216,7 +231,10 @@ def test_select_rows_leaves_the_span_table(static_client):
     profile = static_client.select_rows(QUERY, explain_analyze=True)
     assert len(profile.rows) == 3
     spans = _by_name(profile.trace_id)
-    assert {n: len(s) for n, s in spans.items()} == SELECT_SPANS
+    assert {n: len(s) for n, s in spans.items()
+            if not is_instrument(n)} == SELECT_SPANS
+    # a collection or a stall may land in any trace: a few at most
+    assert sum(len(s) for n, s in spans.items() if is_instrument(n)) <= 4
     by_id = {s.span_id: s for group in spans.values() for s in group}
     root = spans["query.select"][0]
     assert root.parent_span_id is None
@@ -278,8 +296,9 @@ def test_decode_span_says_what_crossed(static_client):
 
 def test_default_ring_keeps_2600_selects(static_client):
     """A Q1 window of the benchmark at half the call time: 2,600 selects
-    of 12 spans each stay in a ring of the default capacity, none
-    dropped (at 16,384 the window's first 1,235 selects would be)."""
+    of 12 spans each, and the collections and stalls that land among
+    them, stay in a ring of the default capacity, none dropped (at
+    16,384 the window's first 1,235 selects would be)."""
     static_client.select_rows(QUERY)              # compile
     ring = SpanCollector()
     real = tracing._collector
@@ -290,9 +309,12 @@ def test_default_ring_keeps_2600_selects(static_client):
     finally:
         tracing._collector = real
     spans = ring.snapshot()
-    assert ring.dropped == 0
-    assert len(spans) == 2600 * sum(SELECT_SPANS.values()) <= ring.capacity
-    assert len({s.trace_id for s in spans}) == 2600
+    assert ring.dropped == 0 and len(spans) <= ring.capacity
+    path = [s for s in spans if not is_instrument(s.name)]
+    assert len(path) == 2600 * sum(SELECT_SPANS.values())
+    # ~0.07 a select with the CPU backend (gc.collect, generation 0)
+    assert len(spans) - len(path) <= 2600
+    assert len({s.trace_id for s in path}) == 2600
     assert sum(s.name == "query.decode" and s.tags["fetch"] == "whole"
                for s in spans) == 2600
 

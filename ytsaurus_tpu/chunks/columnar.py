@@ -32,6 +32,7 @@ import numpy as np
 
 from ytsaurus_tpu.errors import EErrorCode, YtError
 from ytsaurus_tpu.utils.invariants import check as _invariant_check
+from ytsaurus_tpu.utils.tracing import child_span
 from ytsaurus_tpu.schema import (
     EValueType,
     TableSchema,
@@ -1009,23 +1010,38 @@ def concat_chunks(chunks: Sequence[ColumnarChunk]) -> ColumnarChunk:
     cap = pad_capacity(max(total, 1))
     offsets = np.cumsum([0] + [c.row_count for c in chunks[:-1]],
                         dtype=np.int32)
+    # Two spans a concatenation, not one a column: every string column's
+    # dictionary union first, then every column's plane dispatch.
+    unified: dict[str, tuple[list[Column], np.ndarray]] = {}
+    with child_span("chunk.concat.unify") as span:
+        for col_schema in schema:
+            if col_schema.type is EValueType.string:
+                unified[col_schema.name] = unify_dictionaries(
+                    [c.column(col_schema.name) for c in chunks])
+        if span.sampled:
+            span.add_tag("columns", len(unified))
+            span.add_tag("vocab_entries",
+                         sum(len(vocab) for _, vocab in unified.values()))
     columns: dict[str, Column] = {}
-    for col_schema in schema:
-        name = col_schema.name
-        cols = [c.column(name) for c in chunks]
-        vocab = None
-        if col_schema.type is EValueType.string:
-            cols, vocab = unify_dictionaries(cols)
-        data, valid = _concat_planes(
-            tuple(col.data for col in cols), tuple(col.valid for col in cols),
-            offsets, np.int32(total), capacity=cap,
-            dtype=_plane_dtype(col_schema.type))
-        host_values = None
-        if col_schema.type is EValueType.any:
-            host_values = []
-            for chunk, col in zip(chunks, cols):
-                host_values.extend((col.host_values or [])[: chunk.row_count])
-            host_values += [None] * (cap - total)
-        columns[name] = Column(type=col_schema.type, data=data, valid=valid,
-                               dictionary=vocab, host_values=host_values)
+    with child_span("chunk.concat.planes", columns=len(schema),
+                    capacity=cap):
+        for col_schema in schema:
+            name = col_schema.name
+            cols, vocab = unified.get(name) or (
+                [c.column(name) for c in chunks], None)
+            data, valid = _concat_planes(
+                tuple(col.data for col in cols),
+                tuple(col.valid for col in cols),
+                offsets, np.int32(total), capacity=cap,
+                dtype=_plane_dtype(col_schema.type))
+            host_values = None
+            if col_schema.type is EValueType.any:
+                host_values = []
+                for chunk, col in zip(chunks, cols):
+                    host_values.extend(
+                        (col.host_values or [])[: chunk.row_count])
+                host_values += [None] * (cap - total)
+            columns[name] = Column(type=col_schema.type, data=data,
+                                   valid=valid, dictionary=vocab,
+                                   host_values=host_values)
     return ColumnarChunk(schema=schema, row_count=total, columns=columns)

@@ -40,15 +40,43 @@ span's `self_time` is its duration less what its children covered.  Where
 `jax.profiler.TraceAnnotation("yt." + name)`, so a profiler trace shows
 the program's spans on the device lines' clock; this module never imports
 `jax` itself (the RPC daemons must not load it through tracing).
+
+Host-stall instruments (armed while tracing is enabled; they ride the same
+ring, `tracing_span_seconds` histogram and slow-query log as every span):
+
+  gc.collect              every collection (`gc.callbacks`): a live child of
+                          the thread's innermost sampled span (`generation`,
+                          `collected`, `uncollectable`).
+  jax.trace, jax.lower,   every JAX trace, lowering and backend compile
+  jax.compile             anywhere in the process (`jax.monitoring`
+                          listeners, registered once `jax` is loaded):
+                          live children (`fun_name`, the parent's `cause`);
+  jax.cache_read          a persistent-cache read, placed afterwards.
+  host.stall              the stall watch woke `_STALL_THRESHOLD` late: no
+                          Python thread could run (`cpu_ms`, `nivcsw`,
+                          `majflt`, `gc`, `compile`, `stacks`).
+  host.overrun            a root stayed open past the median of its last
+                          64 of the same name and query, plus the threshold,
+                          less the stalls inside it (`median_ms`, `stacks`
+                          sampled every period, with counts).
+
+The two `host.*` are overlays: `self_time` 0 and nothing added to any
+parent's covered time, so every other span's self time stays what it was.
+A record made where the collector's lock may be held (a collection starts
+under it) or on the watch's thread waits in `_pending` for the next span
+exit or ring read.
 """
 
 from __future__ import annotations
 
 import contextvars
+import gc
 import itertools
 import os
 import random
+import resource
 import sys
+import threading
 import time
 from collections import deque
 from typing import Any, Optional
@@ -81,18 +109,28 @@ _ENABLED = True
 _SAMPLE_RATE = 1.0
 _RING_CAPACITY = 65536      # a Q1 window of the benchmark down to a 9.4 ms
                             # call (PERF.md §7; config.TracingConfig)
+# The stall watch wakes every quarter of this and books a wake this late
+# (`host.stall`), or a root open this long past its running median
+# (`host.overrun`); 0 starts no watch.  Tests patch it.
+_STALL_THRESHOLD = 0.05
 
 
 def configure(config) -> None:
-    """Apply a config.TracingConfig process-wide (None → defaults)."""
+    """Apply a config.TracingConfig process-wide (None → defaults): the
+    fast-path mirrors, the ring's capacity and the host-stall instruments
+    (armed when enabled, taken down when not)."""
     global _ENABLED, _SAMPLE_RATE
     if config is None:
         _ENABLED, _SAMPLE_RATE = True, 1.0
         _collector.set_capacity(_RING_CAPACITY)
-        return
-    _ENABLED = bool(config.enabled)
-    _SAMPLE_RATE = float(config.sample_rate)
-    _collector.set_capacity(int(config.ring_capacity))
+    else:
+        _ENABLED = bool(config.enabled)
+        _SAMPLE_RATE = float(config.sample_rate)
+        _collector.set_capacity(int(config.ring_capacity))
+    if _ENABLED:
+        _arm()
+    else:
+        _disarm()
 
 
 def tracing_enabled() -> bool:
@@ -119,6 +157,25 @@ class SpanRecord:
         self.tags = dict(ctx.tags)
         self.baggage = dict(ctx.baggage)
         self.seq = 0                    # stamped by the collector
+
+    @classmethod
+    def closed(cls, parent: "TraceContext", name: str, start_mono: float,
+               duration: float, self_time: float, tags: dict) -> "SpanRecord":
+        """A record of `parent`'s trace made after the fact: an instrument's
+        closed interval (`jax.cache_read`) or an overlay (`host.*`)."""
+        record = cls.__new__(cls)
+        record.trace_id = parent.trace_id
+        record.span_id = _new_span_id()
+        record.parent_span_id = parent.span_id
+        record.name = name
+        record.start = parent.start_time + (start_mono - parent._t0)
+        record.start_mono = start_mono
+        record.duration = duration
+        record.self_time = self_time
+        record.tags = tags
+        record.baggage = {}
+        record.seq = 0
+        return record
 
     def to_dict(self) -> dict:
         return {k: getattr(self, k) for k in self.__slots__ if k != "seq"}
@@ -184,12 +241,14 @@ class SpanCollector:
 
     def drain(self) -> list[SpanRecord]:
         """Spans added since the previous drain (cursor advance)."""
+        _flush_pending()
         with self._lock:
             fresh = [s for s in self._spans if s.seq > self._drained]
             self._drained = self._seq
             return fresh
 
     def snapshot(self) -> list[SpanRecord]:
+        _flush_pending()
         with self._lock:
             return list(self._spans)
 
@@ -198,6 +257,20 @@ class SpanCollector:
 
 
 _collector = SpanCollector()
+
+# Records made where `_collector._lock` may be held by this very thread (a
+# collection can start inside `add`, `snapshot` or `set_capacity`) or on
+# the stall watch's thread: deque appends and pops need no lock.
+_pending: "deque[SpanRecord]" = deque()
+
+
+def _flush_pending() -> None:
+    while _pending:
+        try:
+            record = _pending.popleft()
+        except IndexError:          # another thread took the last one
+            return
+        _collector.add(record)
 
 
 def get_collector() -> SpanCollector:
@@ -256,9 +329,14 @@ class TraceContext:
             self._annotation = _profiler_annotation(self.name)
             if self._annotation is not None:
                 self._annotation.__enter__()
+        # The clock starts after `set`: a collection that its allocation
+        # triggers still sees the outer span as current, and is booked
+        # there, outside this span's interval.
+        self._token = _current.set(self)
         self.start_time = time.time()
         self._t0 = time.perf_counter()
-        self._token = _current.set(self)
+        if self._parent is None and self.sampled:
+            _root_opened(self)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
@@ -270,9 +348,13 @@ class TraceContext:
                 self._annotation = None
             if self._parent is not None:
                 self._parent._covered += duration
+            else:
+                _root_closed(self, duration)
             if exc is not None and "error" not in self.tags:
                 self.tags["error"] = repr(exc)[:200]
             _collector.add(SpanRecord(self, duration))
+            if _pending:
+                _flush_pending()
         return False
 
     # -- wire ------------------------------------------------------------------
@@ -372,6 +454,314 @@ def start_query_span(name: str, force: bool = False,
     ctx = TraceContext(name, trace_id=trace_id)
     ctx.tags.update(tags)
     return ctx
+
+
+# -- host-stall instruments ----------------------------------------------------
+
+INSTRUMENT_PREFIXES = ("gc.", "jax.", "host.")
+
+
+def is_instrument(name: str) -> bool:
+    """Whether `name` is a host-stall instrument's span (`gc.*`, `jax.*`,
+    `host.*`): they land in a trace wherever a collection, a compile or a
+    stall happens to fall, not where the traced code opens a span."""
+    return name.startswith(INSTRUMENT_PREFIXES)
+
+
+_instruments_on = False     # the hook and the listeners record
+_armed = False              # everything the current config asks for is up
+_jax_listening = False      # listeners registered (once a process)
+_watch: "Optional[_StallWatch]" = None
+
+# thread id -> the sampled root open on it (roots nest: each keeps the one
+# it replaced); read by the stall watch
+_open_roots: "dict[int, TraceContext]" = {}
+# (root name, `query` tag) -> durations of the last 64 such roots
+_root_history: "dict[tuple, deque]" = {}
+_ROOT_HISTORY_KEYS = 1024
+_OVERRUN_MIN_ROOTS = 8
+
+
+def _root_opened(ctx: "TraceContext") -> None:
+    if not _armed:
+        _arm()
+    tid = threading.get_ident()
+    ctx._outer_root = _open_roots.get(tid)
+    ctx._stall_s = 0.0
+    ctx._overrun = None
+    _open_roots[tid] = ctx
+
+
+def _root_closed(ctx: "TraceContext", duration: float) -> None:
+    tid = threading.get_ident()
+    outer = ctx._outer_root
+    if outer is None:
+        _open_roots.pop(tid, None)
+    else:
+        _open_roots[tid] = outer
+    key = (ctx.name, ctx.tags.get("query"))
+    history = _root_history.get(key)
+    if history is None:
+        if len(_root_history) >= _ROOT_HISTORY_KEYS:
+            _root_history.clear()
+        history = _root_history[key] = deque(maxlen=64)
+    history.append(duration)
+    overrun = ctx._overrun
+    if overrun is None:
+        return
+    start, median, stacks = overrun
+    over = ctx._t0 + duration - start - ctx._stall_s
+    if over > 0:
+        ranked = sorted(stacks.items(), key=lambda item: -item[1])
+        _pending.append(SpanRecord.closed(
+            ctx, "host.overrun", start, over, 0.0,
+            {"median_ms": median * 1e3, "samples": sum(stacks.values()),
+             "stacks": [{"frames": list(frames), "count": count}
+                        for frames, count in ranked[:8]]}))
+
+
+def _arm() -> None:
+    """Put up what the config asks for: the collection hook, the stall
+    watch and (once `jax` is loaded) the compile listeners."""
+    global _instruments_on, _armed, _watch, _jax_listening
+    if not _ENABLED:
+        return
+    _instruments_on = True
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    watch = _watch
+    if watch is not None and (watch.threshold != _STALL_THRESHOLD
+                              or not watch.is_alive()):
+        watch.stop()
+        watch = _watch = None
+    if watch is None and _STALL_THRESHOLD > 0:
+        _watch = _StallWatch(_STALL_THRESHOLD)
+        _watch.start()
+    jax = sys.modules.get("jax")
+    if not _jax_listening and jax is not None:
+        monitoring = jax.monitoring
+        monitoring.register_scalar_listener(_on_jax_start)
+        monitoring.register_event_time_span_listener(_on_jax_end)
+        monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        _jax_listening = True
+    _armed = _jax_listening
+
+
+def _disarm() -> None:
+    """Take the instruments down: no hook, no watch; the listeners (JAX
+    cannot be asked to forget them everywhere) return at once."""
+    global _instruments_on, _armed, _watch
+    _instruments_on = _armed = False
+    while _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+    if _watch is not None:
+        _watch.stop()
+        _watch = None
+
+
+def _after_fork_in_child() -> None:
+    global _armed, _watch
+    _armed, _watch = False, None      # the watch thread stayed behind
+    _open_roots.clear()
+    _jax_open.clear()
+
+
+os.register_at_fork(after_in_child=_after_fork_in_child)
+
+
+# -- gc.collect: one collection at a time in a process
+
+_gc_t0: Optional[float] = None       # the collection in progress began
+_gc_span: "Optional[TraceContext]" = None
+_gc_last_end = 0.0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """`gc.callbacks` hook.  Never takes a lock: a collection can start
+    while this thread holds `_collector._lock`, so the finished span waits
+    in `_pending`."""
+    global _gc_t0, _gc_span, _gc_last_end
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+        parent = _current.get() if _instruments_on else None
+        if parent is None or not parent.sampled:
+            _gc_span = None
+            return
+        span = parent.create_child("gc.collect")
+        span.tags["generation"] = info["generation"]
+        span._annotation = _profiler_annotation(span.name)
+        if span._annotation is not None:
+            span._annotation.__enter__()
+        span.start_time = time.time()
+        span._t0 = time.perf_counter()
+        _gc_span = span
+        return
+    now = time.perf_counter()
+    span, _gc_span, _gc_t0 = _gc_span, None, None
+    _gc_last_end = now
+    if span is None:
+        return
+    duration = now - span._t0
+    if span._annotation is not None:
+        span._annotation.__exit__(None, None, None)
+        span._annotation = None
+    span._parent._covered += duration
+    span.tags["collected"] = info["collected"]
+    span.tags["uncollectable"] = info["uncollectable"]
+    _pending.append(SpanRecord(span, duration))
+
+
+# -- jax.*: `jax._src.dispatch.log_elapsed_time` reports a start through
+# `record_scalar(event, start)` and the end through
+# `record_event_time_span(event, start, end)`
+
+_JAX_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+# thread id -> [(event, open span)], innermost last
+_jax_open: "dict[int, list]" = {}
+_jax_last_end = 0.0
+
+
+def _on_jax_start(event: str, value, **kwargs) -> None:
+    name = _JAX_SPANS.get(event)
+    if name is None or not _instruments_on:
+        return
+    parent = _current.get()
+    if parent is None or not parent.sampled:
+        return
+    span = parent.create_child(name)
+    if "fun_name" in kwargs:
+        span.tags["fun_name"] = kwargs["fun_name"]
+    cause = parent.tags.get("cause")
+    if cause is not None:
+        span.tags["cause"] = cause
+    span.__enter__()
+    _jax_open.setdefault(threading.get_ident(), []).append((event, span))
+
+
+def _on_jax_end(event: str, start, end, **kwargs) -> None:
+    global _jax_last_end
+    stack = _jax_open.get(threading.get_ident())
+    if not stack or event not in _JAX_SPANS:
+        return
+    for depth in range(len(stack) - 1, -1, -1):
+        if stack[depth][0] == event:
+            break
+    else:
+        return
+    while len(stack) > depth:       # anything left open inside closes too
+        stack.pop()[1].__exit__(None, None, None)
+    _jax_last_end = time.perf_counter()
+
+
+def _on_jax_duration(event: str, duration: float, **kwargs) -> None:
+    if event != _CACHE_READ or not _instruments_on:
+        return
+    parent = _current.get()
+    if parent is None or not parent.sampled:
+        return
+    parent._covered += duration
+    tags = {"fun_name": parent.tags["fun_name"]} \
+        if "fun_name" in parent.tags else {}
+    _pending.append(SpanRecord.closed(
+        parent, "jax.cache_read", time.perf_counter() - duration, duration,
+        duration, tags))
+
+
+# -- host.stall and host.overrun
+
+def _frames(frame, depth: int = 4) -> tuple:
+    """The innermost `depth` frames as `dir/file.py:line`."""
+    out = []
+    while frame is not None and len(out) < depth:
+        code = frame.f_code.co_filename
+        out.append(f"{os.path.basename(os.path.dirname(code))}/"
+                   f"{os.path.basename(code)}:{frame.f_lineno}")
+        frame = frame.f_back
+    return tuple(out)
+
+
+class _StallWatch(threading.Thread):
+    """Wakes every `threshold / 4`.  A wake `threshold` late means no
+    Python thread ran meanwhile (a C call held the GIL, or the process was
+    not scheduled): a `host.stall` in every open root's trace.  A root open
+    past its running median plus `threshold` has its thread's stack
+    sampled every period until it closes (`host.overrun`): a block with the
+    GIL released, which a late wake cannot see."""
+
+    def __init__(self, threshold: float):
+        super().__init__(name="yt-stall-watch", daemon=True)
+        self.threshold = threshold
+        self.period = threshold / 4
+        self._stop_event = threading.Event()
+
+    def stop(self) -> None:
+        self._stop_event.set()
+        if self.is_alive() and threading.current_thread() is not self:
+            self.join(timeout=5.0)
+
+    def run(self) -> None:
+        period, threshold = self.period, self.threshold
+        wait = self._stop_event.wait
+        clock, cpu_clock = time.perf_counter, time.process_time
+        cpu, usage = cpu_clock(), resource.getrusage(resource.RUSAGE_SELF)
+        planned = clock() + period
+        while not wait(max(planned - clock(), 0.0)):
+            now = clock()
+            cpu_now = cpu_clock()
+            usage_now = resource.getrusage(resource.RUSAGE_SELF)
+            if now - planned >= threshold:
+                self._stall(planned, now, cpu_now - cpu,
+                            usage_now.ru_nivcsw - usage.ru_nivcsw,
+                            usage_now.ru_majflt - usage.ru_majflt)
+            if _open_roots:
+                self._overruns(now)
+            cpu, usage = cpu_now, usage_now
+            planned = now + period
+
+    def _stall(self, planned: float, now: float, cpu_s: float,
+               nivcsw: int, majflt: int) -> None:
+        roots = list(_open_roots.values())
+        if not roots:
+            return
+        names = {t.ident: t.name for t in threading.enumerate()}
+        stacks = {names.get(tid, str(tid)): list(_frames(frame))
+                  for tid, frame in sys._current_frames().items()
+                  if tid != self.ident}
+        gc_busy = _gc_t0 is not None or _gc_last_end >= planned
+        compiling = any(_jax_open.values()) or _jax_last_end >= planned
+        for root in roots:
+            start = max(planned, root._t0)
+            duration = now - start
+            if duration <= 0:
+                continue
+            root._stall_s += duration
+            _pending.append(SpanRecord.closed(
+                root, "host.stall", start, duration, 0.0,
+                {"cpu_ms": cpu_s * 1e3, "nivcsw": nivcsw, "majflt": majflt,
+                 "gc": gc_busy, "compile": compiling, "stacks": stacks}))
+
+    def _overruns(self, now: float) -> None:
+        frames = None
+        for tid, root in list(_open_roots.items()):
+            overrun = root._overrun
+            if overrun is None:
+                history = _root_history.get(
+                    (root.name, root.tags.get("query")))
+                if history is None or len(history) < _OVERRUN_MIN_ROOTS:
+                    continue
+                median = sorted(history)[len(history) // 2]
+                if now - root._t0 < median + self.threshold:
+                    continue
+                overrun = root._overrun = (root._t0 + median, median, {})
+            if frames is None:
+                frames = sys._current_frames()
+            stack = _frames(frames.get(tid))
+            overrun[2][stack] = overrun[2].get(stack, 0) + 1
 
 
 # -- flight-recorder views -----------------------------------------------------
